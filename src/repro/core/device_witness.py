@@ -25,9 +25,10 @@ single [n_lanes*S, W] array, so a routed cross-shard batch records at every
 target lane in ONE dispatch (repro.kernels.ops.gang_fastpath_batch) and a
 sync round gc's every witness of a shard in ONE dispatch (``gc_many``).
 
-Set placement differs from the Python witness (keyhash2x32-mixed low lane
-masked by S-1, vs ``kh % n_sets`` on the raw 64-bit hash), so occupancy
-patterns differ between backends; accept/reject *semantics* do not.
+Set placement equals the Python witness's: ``kh % n_sets`` on the raw 64-bit
+key hash (its low lane masked by S-1), so both backends fill the same sets
+and even capacity rejects agree.  The stored key lanes are the
+keyhash2x32-mixed ones.
 
 Multi-key ops resolve all-or-nothing through the grouped record kernel
 (repro.kernels.gang_record_groups): every key's conflict/capacity verdict is
@@ -99,8 +100,8 @@ class WitnessGang:
     Owns the single :class:`repro.kernels.GangTable` that every attached
     ``DeviceWitness`` records into; lanes are allocated on ``start`` and
     recycled on ``end``.  The lane count grows by doubling (a host-side
-    concat of zero rows) so the flattened row space stays a power of two —
-    the set-parallel kernel's tiling requirement.
+    concat of zero rows) so the row space stays a power of two — the gang
+    kernels' row tiling requirement.
     """
 
     def __init__(self, n_sets: int = 1024, n_ways: int = 4,
@@ -154,13 +155,15 @@ class WitnessGang:
     def _grow(self) -> None:
         import jax.numpy as jnp
 
-        from repro.kernels import GangTable
+        from repro.kernels import GangTable, gang_rows
 
         old = self.n_lanes
         self.n_lanes = old * 2
-        pad = ((0, old * self.n_sets), (0, 0))
+        rows = gang_rows(self.n_lanes * self.n_sets)
         self.table = GangTable(*(
-            jnp.asarray(np.pad(np.asarray(a), pad)) for a in self.table
+            jnp.asarray(np.pad(np.asarray(a),
+                               ((0, 0), (0, rows - a.shape[1]))))
+            for a in self.table
         ))
         self.counters = jnp.asarray(
             np.pad(np.asarray(self.counters), ((0, old), (0, 0)))
@@ -175,8 +178,8 @@ class WitnessGang:
         occ = np.asarray(self.table.occ).copy()
         age = np.asarray(self.table.age).copy()
         rows = slice(lane * self.n_sets, (lane + 1) * self.n_sets)
-        occ[rows] = 0
-        age[rows] = 0
+        occ[:, rows] = 0
+        age[:, rows] = 0
         self.table = self.table._replace(
             occ=jnp.asarray(occ), age=jnp.asarray(age)
         )
@@ -252,10 +255,10 @@ class DeviceWitness:
         """Whole-batch record, ONE kernel dispatch, any mix of group sizes.
 
         All-single-key batches (the batched client path's common case) go
-        through the set-parallel kernel; batches containing multi-key ops go
-        through the grouped all-or-nothing kernel.  Batch order is preserved
-        exactly in both (the set-parallel prep keeps per-set order; the
-        grouped kernel is sequential in group index)."""
+        through ``gang_record`` as groups of one key; batches containing
+        multi-key ops go through ``gang_record_groups``.  Both run the same
+        gang record kernel, which keeps batch order exactly within every
+        set row."""
         if self.mode is not WitnessMode.NORMAL or master_id != self.master_id:
             self.stats["rejects_mode"] += len(ops)
             return [RecordStatus.REJECTED] * len(ops)
@@ -377,7 +380,7 @@ class DeviceWitness:
                               request: Op) -> RecordStatus:
         """Pre-refactor record-then-rollback scheme, kept only for the
         old-vs-new dispatch comparison in benchmarks/fig_txn.py: the keys
-        record individually (set-parallel dispatch) and any accepted prefix
+        record individually (one gang_record dispatch) and any accepted prefix
         is rolled back by a second gc dispatch when the op rejects."""
         from repro.kernels import gang_gc, gang_record
 
@@ -408,7 +411,7 @@ class DeviceWitness:
         if ins:
             _clr, table = gang_gc(
                 table, self.n_sets,
-                qh[ins], ql[ins], rhi[ins], rlo[ins], lanes[ins],
+                hi[ins], lo[ins], rhi[ins], rlo[ins], lanes[ins],
                 np.zeros(self.gang.n_lanes, np.int32), do_age=False,
             )
         self.gang.table = table
@@ -514,8 +517,8 @@ def gc_many(witnesses: Sequence[DeviceWitness],
     qh, ql = np_keyhash2x32(hi, lo)
     rhi, rlo = _rpc_lanes([rpc for _kh, rpc in uniq])
     E, L = len(uniq), len(witnesses)
-    g_qh = np.tile(qh, L)
-    g_ql = np.tile(ql, L)
+    g_hi = np.tile(hi, L)
+    g_lo = np.tile(lo, L)
     g_rh = np.tile(rhi, L)
     g_rl = np.tile(rlo, L)
     g_lane = np.repeat(
@@ -525,7 +528,7 @@ def gc_many(witnesses: Sequence[DeviceWitness],
     for w in witnesses:
         aged[w.lane] = 1
     cleared, table = gang_gc(
-        gang.table, gang.n_sets, g_qh, g_ql, g_rh, g_rl, g_lane, aged
+        gang.table, gang.n_sets, g_hi, g_lo, g_rh, g_rl, g_lane, aged
     )
     gang.table = table
     for w in witnesses:

@@ -371,7 +371,7 @@ class ShardGroup:
                      now: float = 0.0) -> List["OpOutcome"]:
         """Batched CURP updates: one master round (ops executed in order) +
         ONE record invocation per witness for the whole batch (a single
-        set-parallel kernel dispatch on the device backend).
+        gang record kernel dispatch on the device backend).
 
         Per-op accept/reject and fast/slow-path accounting are preserved —
         op j's witness statuses see exactly the accepts of ops < j, as the

@@ -213,7 +213,7 @@ class Witness:
         """One witness invocation for a whole update batch (the batched
         client path): per-op accept/reject with the same in-order semantics
         as issuing ``record`` once per op.  The kernel-backed DeviceWitness
-        overrides this with a single set-parallel kernel call."""
+        overrides this with a single gang record kernel call."""
         return [
             self.record(master_id, op.key_hashes(), op.rpc_id, op)
             for op in ops

@@ -36,6 +36,7 @@ from .ops import (
     fastpath_batch,
     gang_fastpath_batch,
     gang_gc,
+    gang_rows,
     gang_record,
     gang_record_groups,
     keyhash2x32,
@@ -65,6 +66,7 @@ __all__ = [
     "GangTable", "GangRecordResult", "GangFastPathResult",
     "N_REASON_CODES",
     "gang_record", "gang_record_groups", "gang_gc", "gang_fastpath_batch",
+    "gang_rows",
     "np_keyhash2x32", "ref_gang_record", "ref_gang_gc",
     "matrix_rows", "conflict_matrix_np",
 ]

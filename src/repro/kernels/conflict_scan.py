@@ -60,7 +60,7 @@ def _conflict_kernel(whi_ref, wlo_ref, wval_ref, qhi_ref, qlo_ref, qcls_ref,
 def conflict_scan_pallas(
     w_hi: jnp.ndarray, w_lo: jnp.ndarray, w_valid: jnp.ndarray,
     q_hi: jnp.ndarray, q_lo: jnp.ndarray, q_cls: jnp.ndarray,
-    *, block_b: int = 256, block_u: int = 512, interpret: bool = True,
+    *, block_b: int = 256, block_u: int = 512, interpret: bool,
 ):
     (U,) = w_hi.shape
     (B,) = q_hi.shape

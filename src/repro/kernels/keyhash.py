@@ -37,7 +37,7 @@ def _keyhash_kernel(hi_ref, lo_ref, out_hi_ref, out_lo_ref):
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def keyhash2x32_pallas(
     hi: jnp.ndarray, lo: jnp.ndarray, *, block: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """[N]-shaped (hi, lo) -> mixed (hi', lo').  N must be a multiple of
     ``block``; callers pad (ops.py handles it)."""

@@ -41,6 +41,7 @@ from .ref import (
     GangTable,
     WitnessTable,
     conflict_matrix_np,
+    gang_rows,
     matrix_rows,
     np_keyhash2x32,
     ref_conflict_scan,
@@ -54,10 +55,10 @@ from .ref import (
 from .witness_record import (
     DEFAULT_TILE_SETS,
     N_REASON_CODES,
+    _unsort,
     fastpath_record_scan_pallas,
     gang_gc_pallas,
-    gang_record_groups_pallas,
-    gang_record_setpar_pallas,
+    gang_record_pallas,
     reason_counts_update,
     witness_gc_pallas,
     witness_record_seq_pallas,
@@ -145,8 +146,7 @@ def _pad_to(x: jnp.ndarray, m: int, fill=0) -> Tuple[jnp.ndarray, int]:
 # surrounding jit)
 # ---------------------------------------------------------------------------
 def _setpar_prep(n_sets: int, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-                 q_valid: jnp.ndarray | None = None,
-                 sets: jnp.ndarray | None = None):
+                 q_valid: jnp.ndarray | None = None):
     """Sort a query batch into round-contiguous set-parallel order.
 
     Returns (qhi_f, qlo_f, sets_f, round_start, n_rounds, perm) where
@@ -158,18 +158,11 @@ def _setpar_prep(n_sets: int, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
     ``q_valid`` marks bucket-padding lanes: invalid queries get the
     out-of-range set id ``n_sets`` and rank B, so they sort to the tail,
     fall beyond ``n_rounds``, and are never touched by the kernel (their
-    accept bit stays 0).
-
-    ``sets`` optionally supplies precomputed set ids (the gang path derives
-    GLOBAL rows ``lane * S + (lo & (S-1))`` over the stacked table, with
-    ``n_sets`` = total rows); by default the ids come from the low lane.
-    Permute any additional per-query arrays with the returned ``perm``.
+    accept bit stays 0).  Permute any additional per-query arrays with the
+    returned ``perm``.
     """
     (B,) = q_hi.shape
-    if sets is None:
-        sets = (q_lo & jnp.uint32(n_sets - 1)).astype(jnp.int32)   # [B]
-    else:
-        sets = sets.astype(jnp.int32)
+    sets = (q_lo & jnp.uint32(n_sets - 1)).astype(jnp.int32)       # [B]
     if q_valid is None:
         valid = jnp.ones((B,), jnp.int32)
     else:
@@ -199,10 +192,6 @@ def _setpar_prep(n_sets: int, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
         jnp.max(jnp.where(rank_f >= B, jnp.int32(-1), rank_f)) + 1
     ).reshape((1,))
     return q_hi[perm], q_lo[perm], sets_s[order2], round_start, n_rounds, perm
-
-
-def _unsort(perm: jnp.ndarray, x_sorted: jnp.ndarray) -> jnp.ndarray:
-    return jnp.zeros_like(x_sorted).at[perm].set(x_sorted)
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -552,8 +541,9 @@ def txn_probe(table: WitnessTable, key_hi, key_lo, own=None,
 # Gang ops: stacked witness lanes with kernel-held RIFL/gc state
 # ---------------------------------------------------------------------------
 # A gang stacks L witness instances (all shards x all witnesses) into one
-# [L*S, W] device table whose slots carry rpc identity and gc age alongside
-# the keyhash lanes (repro.kernels.ref.GangTable).  The ops below keep the
+# device table of [W, L*S] planes (sets along the vector lanes) whose slots
+# carry rpc identity and gc age alongside the keyhash lanes
+# (repro.kernels.ref.GangTable).  The ops below keep the
 # whole serving hot loop at ONE dispatch per *cluster* batch: reason codes
 # (1 insert / 2 dup / 3 conflict / 4 full) come back per op so the host
 # updates stats/mirrors without consulting device state, and all outputs are
@@ -576,6 +566,15 @@ def _dummy_counters() -> jnp.ndarray:
     return jnp.zeros((1, N_REASON_CODES), jnp.int32)
 
 
+def _key_rows(lanes, raw_lo, n_sets: int):
+    """Global gang row of each key: ``lane * S`` plus the set picked by the
+    RAW low key lane, i.e. the Python witness's ``kh % n_sets`` placement.
+    (The MIXED low lane also picks the key's shard slot, so a set taken
+    from it would confine each shard's keys to 1/n_shards of the sets.)"""
+    return lanes * n_sets + (raw_lo & jnp.uint32(n_sets - 1)).astype(
+        jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("n_sets", "track", "interpret"))
 def _gang_groups_impl(table, k_hi, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
                       g_valid, counters, n_sets: int, track: bool,
@@ -584,13 +583,10 @@ def _gang_groups_impl(table, k_hi, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
     qh, ql = ref_keyhash2x32(k_hi.reshape(-1), k_lo.reshape(-1))
     qh = qh.reshape(G, K)
     ql = ql.reshape(G, K)
-    rows = (
-        lanes[:, None] * n_sets
-        + (ql & jnp.uint32(n_sets - 1)).astype(jnp.int32)
-    )
-    rsn, new_table = gang_record_groups_pallas(
+    rows = _key_rows(lanes[:, None], k_lo, n_sets)
+    rsn, new_table = gang_record_pallas(
         table, qh, ql, rows, k_valid, k_cls, r_hi, r_lo, g_valid,
-        interpret=interpret,
+        n_sets=n_sets, interpret=interpret,
     )
     if track:
         # One count per GROUP (the host settles grouped ops group-wise).
@@ -651,24 +647,25 @@ def gang_record_groups(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("n_sets", "track", "interpret",
-                                             "tile_sets"))
+def _single_key_record(table, qh, ql, raw_lo, k_cls, valid, lanes, r_hi,
+                       r_lo, n_sets: int, interpret: bool):
+    """[B] single-key records as B groups of one key (traced helper)."""
+    rows = _key_rows(lanes, raw_lo, n_sets)
+    col = lambda x: x[:, None]
+    return gang_record_pallas(
+        table, col(qh), col(ql), col(rows), col(valid), col(k_cls),
+        r_hi, r_lo, valid, n_sets=n_sets, interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("n_sets", "track", "interpret"))
 def _gang_record_impl(table, k_hi, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
-                      counters, n_sets: int, track: bool, interpret: bool,
-                      tile_sets: int):
-    R, _W = table.occ.shape
+                      counters, n_sets: int, track: bool, interpret: bool):
     qh, ql = ref_keyhash2x32(k_hi, k_lo)
-    rows = (
-        lanes * n_sets + (ql & jnp.uint32(n_sets - 1)).astype(jnp.int32)
+    rsn, new_table = _single_key_record(
+        table, qh, ql, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
+        n_sets, interpret,
     )
-    qhi_f, qlo_f, sets_f, rstart, n_rounds, perm = _setpar_prep(
-        R, qh, ql, k_valid, sets=rows
-    )
-    rsn_f, new_table = gang_record_setpar_pallas(
-        table, qhi_f, qlo_f, r_hi[perm], r_lo[perm], k_cls[perm], sets_f,
-        rstart, n_rounds, tile_sets=tile_sets, interpret=interpret,
-    )
-    rsn = _unsort(perm, rsn_f)
     if track:
         # One count per ROW, mirroring the host's per-op settle accounting.
         counters = reason_counts_update(counters, lanes, rsn, k_valid)
@@ -679,10 +676,9 @@ def gang_record(
     table: GangTable, n_sets: int, key_hi, key_lo, lanes, rpc_hi, rpc_lo,
     key_cls=None,
     *, counters=None, interpret: bool | None = None,
-    tile_sets: int = DEFAULT_TILE_SETS,
 ):
-    """Set-parallel single-key record over the gang: ONE dispatch for a
-    batch of [B] single-key ops (each with its own lane + rpc identity).
+    """Single-key record over the gang: ONE dispatch for a batch of [B]
+    single-key ops (each with its own lane + rpc identity).
     ``key_cls`` is the optional [B] merge-lattice class lane (default SET).
 
     Returns (reasons [B], q_hi [B], q_lo [B], table) — numpy outputs,
@@ -708,40 +704,38 @@ def gang_record(
     rsn, qh, ql, new_table, new_counters = _gang_record_impl(
         table, key_hi, key_lo, jnp.asarray(key_cls), valid, lanes,
         rpc_hi, rpc_lo, counters if track else _dummy_counters(),
-        n_sets, track, interpret, tile_sets,
+        n_sets, track, interpret,
     )
     out = (np.asarray(rsn)[:B], np.asarray(qh)[:B], np.asarray(ql)[:B],
            new_table)
     return out + (new_counters,) if track else out
 
 
-@functools.partial(jax.jit, static_argnames=("n_sets", "do_age", "interpret",
-                                             "tile_sets"))
+@functools.partial(jax.jit, static_argnames=("n_sets", "do_age", "interpret"))
 def _gang_gc_impl(table, g_hi, g_lo, g_rh, g_rl, g_lane, g_valid, aged_lanes,
-                  n_sets: int, do_age: bool, interpret: bool, tile_sets: int):
-    rows = (
-        g_lane * n_sets + (g_lo & jnp.uint32(n_sets - 1)).astype(jnp.int32)
-    )
+                  n_sets: int, do_age: bool, interpret: bool):
+    rows = _key_rows(g_lane, g_lo, n_sets)
+    g_hi, g_lo = ref_keyhash2x32(g_hi, g_lo)
     aged_rows = jnp.repeat(aged_lanes.astype(jnp.int32), n_sets)
-    clr, new_table = gang_gc_pallas(
+    R = table.occ.shape[1]
+    aged_rows = jnp.pad(aged_rows, (0, R - aged_rows.shape[0]))
+    return gang_gc_pallas(
         table, g_hi, g_lo, g_rh, g_rl, rows, g_valid, aged_rows,
-        do_age=do_age, tile_sets=tile_sets, interpret=interpret,
+        n_sets=n_sets, do_age=do_age, interpret=interpret,
     )
-    return clr, new_table
 
 
 def gang_gc(
     table: GangTable, n_sets: int,
     g_hi, g_lo, g_rpc_hi, g_rpc_lo, g_lane, aged_lanes,
-    *, do_age: bool = True,
-    interpret: bool | None = None, tile_sets: int = DEFAULT_TILE_SETS,
+    *, do_age: bool = True, interpret: bool | None = None,
 ):
     """Gang gc, ONE dispatch: rpc-matched clears + in-kernel aging.
 
-    Entry lanes are MIXED key lanes (as returned by the record ops) plus
-    the recording rpc identity and target lane; a slot clears only on a
-    full (key, rpc, lane) match, so a stale gc entry never drops a newer
-    same-key record.  ``aged_lanes`` is an [L] 0/1 mask of lanes whose
+    Entry lanes are RAW key lanes (split 64-bit key hashes, as the record
+    ops take them) plus the recording rpc identity and target lane; a slot
+    clears only on a full (key, rpc, lane) match, so a stale gc entry never
+    drops a newer same-key record.  ``aged_lanes`` is an [L] 0/1 mask of lanes whose
     survivors age this round (§4.5); ``do_age=False`` is the rollback
     variant.  Returns (cleared [G] numpy bit per entry, new table).
     """
@@ -758,7 +752,7 @@ def gang_gc(
     clr, new_table = _gang_gc_impl(
         table, g_hi, g_lo, g_rh, g_rl, g_lane, valid,
         jnp.asarray(np.asarray(aged_lanes, np.int32)),
-        n_sets, do_age, interpret, tile_sets,
+        n_sets, do_age, interpret,
     )
     return np.asarray(clr)[:G], new_table
 
@@ -782,15 +776,13 @@ class GangFastPathResult(NamedTuple):
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "n_sets", "f",
-                                             "track", "interpret",
-                                             "tile_sets"))
+                                             "track", "interpret"))
 def _gang_fastpath_impl(table, k_hi, k_lo, k_cls, k_valid, r_hi, r_lo,
                         exec_pred, slot_map, lane_map, ring_hi, ring_lo,
                         ring_cls, tail_slot, count, counters,
                         n_slots: int, n_sets: int, f: int, track: bool,
-                        interpret: bool, tile_sets: int):
+                        interpret: bool):
     (B,) = k_hi.shape
-    R, _W = table.occ.shape
     NS, CAP = ring_hi.shape
     qh, ql = ref_keyhash2x32(k_hi, k_lo)
     slots = (ql % jnp.uint32(n_slots)).astype(jnp.int32)
@@ -837,19 +829,10 @@ def _gang_fastpath_impl(table, k_hi, k_lo, k_cls, k_valid, r_hi, r_lo,
     # --- witness record, expanded to every shard's f witness lanes ---------
     lanes_e = lane_map[shard].reshape(-1)                          # [B*f]
     rep = lambda x: jnp.repeat(x, f)
-    qh_e, ql_e = rep(qh), rep(ql)
-    rows_e = lanes_e * n_sets + (ql_e & jnp.uint32(n_sets - 1)).astype(
-        jnp.int32
+    rsn_flat, new_table = _single_key_record(                      # [B*f]
+        table, rep(qh), rep(ql), rep(k_lo), rep(qcls), rep(valid), lanes_e,
+        rep(r_hi), rep(r_lo), n_sets, interpret,
     )
-    qhi_f, qlo_f, sets_f, rstart, n_rounds, perm = _setpar_prep(
-        R, qh_e, ql_e, rep(valid), sets=rows_e
-    )
-    rsn_f, new_table = gang_record_setpar_pallas(
-        table, qhi_f, qlo_f, rep(r_hi)[perm], rep(r_lo)[perm],
-        rep(qcls)[perm], sets_f, rstart, n_rounds,
-        tile_sets=tile_sets, interpret=interpret,
-    )
-    rsn_flat = _unsort(perm, rsn_f)                                # [B*f]
     if track:
         # One count per (op, witness copy) at the copy's lane — the same
         # granularity the host settles at (FusedBatchDriver settles every
@@ -868,7 +851,6 @@ def gang_fastpath_batch(
     ring_hi, ring_lo, tail_slot, count,
     *, key_cls=None, ring_cls=None, counters=None,
     interpret: bool | None = None,
-    tile_sets: int = DEFAULT_TILE_SETS,
 ) -> GangFastPathResult:
     """The whole cluster-batch hot loop in ONE device dispatch:
 
@@ -918,7 +900,7 @@ def gang_fastpath_batch(
         jnp.asarray(np.asarray(tail_slot, np.int32)),
         jnp.asarray(np.asarray(count, np.int32)),
         counters if track else _dummy_counters(),
-        n_slots, n_sets, f, track, interpret, tile_sets,
+        n_slots, n_sets, f, track, interpret,
     )
     (reasons, conflicts, shard, qh, ql, new_table, rh, rl, new_count,
      rcls, new_counters) = out
@@ -939,6 +921,6 @@ __all__ = [
     "ref_witness_gc", "ref_conflict_scan", "ref_witness_record_txn",
     "GangTable", "GangRecordResult", "GangFastPathResult", "N_REASON_CODES",
     "gang_record", "gang_record_groups", "gang_gc", "gang_fastpath_batch",
-    "np_keyhash2x32", "ref_gang_record", "ref_gang_gc",
+    "np_keyhash2x32", "ref_gang_record", "ref_gang_gc", "gang_rows",
     "matrix_rows", "conflict_matrix_np",
 ]

@@ -317,47 +317,55 @@ REASON_FULL = 4        # rejected: probed set is out of ways
 
 
 class GangTable(NamedTuple):
-    """L stacked witness tables, flattened to [L*S, W] so the set-parallel
-    record kernel runs unchanged over the union of all lanes' sets (global
-    set row = lane * S + (q_lo & (S-1))).
+    """L stacked witness tables sharing ONE row axis (global set row =
+    lane * S + (q_lo & (S-1))), every plane laid out ``[W, R]`` — ways along
+    sublanes, rows (sets) along the 128 vector lanes, so a TPU vreg holds 128
+    sets instead of one padded 4-way row.  ``R`` is ``L*S`` rounded up to a
+    whole 128-lane chunk (``gang_rows``); rows past ``L*S`` stay empty.
 
     Beyond the key lanes of :class:`WitnessTable`, every slot carries the
     recording op's RIFL identity (rpc_hi = client id, rpc_lo = seq) and a
     §4.5 gc-age counter, so duplicate-retry acceptance, stale-gc
     suppression, and garbage suspicion resolve in-kernel.
     """
-    keys_hi: jnp.ndarray   # [L*S, W] uint32
-    keys_lo: jnp.ndarray   # [L*S, W] uint32
-    occ: jnp.ndarray       # [L*S, W] int32 (0 = empty, else 1 + op class)
-    rpc_hi: jnp.ndarray    # [L*S, W] uint32 (client id)
-    rpc_lo: jnp.ndarray    # [L*S, W] uint32 (sequence number)
-    age: jnp.ndarray       # [L*S, W] int32 (gc rounds survived)
+    keys_hi: jnp.ndarray   # [W, R] uint32
+    keys_lo: jnp.ndarray   # [W, R] uint32
+    occ: jnp.ndarray       # [W, R] int32 (0 = empty, else 1 + op class)
+    rpc_hi: jnp.ndarray    # [W, R] uint32 (client id)
+    rpc_lo: jnp.ndarray    # [W, R] uint32 (sequence number)
+    age: jnp.ndarray       # [W, R] int32 (gc rounds survived)
 
     @staticmethod
     def empty(n_sets: int, n_ways: int, n_lanes: int = 1) -> "GangTable":
         assert n_sets & (n_sets - 1) == 0, "n_sets must be a power of two"
-        R = n_lanes * n_sets
-        return GangTable(
-            keys_hi=jnp.zeros((R, n_ways), U32),
-            keys_lo=jnp.zeros((R, n_ways), U32),
-            occ=jnp.zeros((R, n_ways), jnp.int32),
-            rpc_hi=jnp.zeros((R, n_ways), U32),
-            rpc_lo=jnp.zeros((R, n_ways), U32),
-            age=jnp.zeros((R, n_ways), jnp.int32),
-        )
+        shape = (n_ways, gang_rows(n_lanes * n_sets))
+        return GangTable(*(jnp.zeros(shape, U32 if dt == "u" else jnp.int32)
+                           for dt in "uuiuui"))
+
+
+def gang_rows(n_rows: int) -> int:
+    """Row extent of a gang plane: ``n_rows`` padded to whole 128-lane
+    chunks."""
+    return max(128, -(-n_rows // 128) * 128)
 
 
 def _gang_np(table: GangTable):
-    return tuple(np.array(np.asarray(a)) for a in table)
+    """Host copies of the planes, row-major ``[R, W]`` for the oracles."""
+    return tuple(np.array(np.asarray(a).T) for a in table)
+
+
+def _gang_jnp(planes) -> GangTable:
+    return GangTable(*(jnp.asarray(np.ascontiguousarray(a.T)) for a in planes))
 
 
 def ref_gang_record(table: GangTable, n_sets: int, groups):
     """Pure-Python oracle for the gang record kernels.
 
     ``groups`` is a sequence of ``(lane, (rpc_hi, rpc_lo), keys)`` where
-    ``keys`` is a list of ``(q_hi, q_lo)`` or ``(q_hi, q_lo, cls)`` lane
+    ``keys`` is a list of RAW ``(k_hi, k_lo)`` or ``(k_hi, k_lo, cls)`` lane
     triples (``cls`` defaults to SET) — ONE group is one op (single-key ops
-    are groups of size 1).  Semantics transcribe
+    are groups of size 1).  A key sits at row ``lane * n_sets + (k_lo &
+    (n_sets-1))`` and the table holds its keyhash2x32-mixed lanes.  Semantics transcribe
     ``repro.core.witness.Witness.record`` exactly: a same-key hit under a
     foreign rpc conflicts only when the merge lattice says the classes
     conflict, and free ways are RESERVED as the placement loop claims them,
@@ -375,9 +383,9 @@ def ref_gang_record(table: GangTable, n_sets: int, groups):
         claimed = set()
         reason = None
         for entry in keys:
-            qh, ql, cls = entry if len(entry) == 3 else (*entry, 0)
-            qh, ql = np.uint32(qh), np.uint32(ql)
-            row = lane * n_sets + (int(ql) & (n_sets - 1))
+            kh, kl, cls = entry if len(entry) == 3 else (*entry, 0)
+            row = lane * n_sets + (int(kl) & (n_sets - 1))
+            qh, ql = (x[0] for x in np_keyhash2x32([kh], [kl]))
             free_way = None
             conflicted = False
             for w in range(W):
@@ -411,14 +419,14 @@ def ref_gang_record(table: GangTable, n_sets: int, groups):
                 rlo[row, w] = rs
                 age[row, w] = 0
         reasons.append(reason)
-    return reasons, GangTable(*(jnp.asarray(a) for a in
-                                (khi, klo, occ, rhi, rlo, age)))
+    return reasons, _gang_jnp((khi, klo, occ, rhi, rlo, age))
 
 
 def ref_gang_gc(table: GangTable, n_sets: int, entries, aged_lanes):
     """Oracle for the gang gc kernel.
 
-    ``entries`` is a sequence of ``(lane, (q_hi, q_lo), (rpc_hi, rpc_lo))``;
+    ``entries`` is a sequence of ``(lane, (k_hi, k_lo), (rpc_hi, rpc_lo))``
+    with RAW key lanes (placed and mixed as in ``ref_gang_record``);
     a slot is cleared only when key AND rpc match (stale-gc suppression
     in-kernel).  Every occupied survivor in ``aged_lanes`` then ages by one
     round (§4.5).  Returns (cleared bit per entry, new GangTable).
@@ -426,9 +434,9 @@ def ref_gang_gc(table: GangTable, n_sets: int, entries, aged_lanes):
     khi, klo, occ, rhi, rlo, age = _gang_np(table)
     W = occ.shape[1]
     cleared = []
-    for lane, (qh, ql), (rc, rs) in entries:
-        qh, ql = np.uint32(qh), np.uint32(ql)
-        row = lane * n_sets + (int(ql) & (n_sets - 1))
+    for lane, (kh, kl), (rc, rs) in entries:
+        row = lane * n_sets + (int(kl) & (n_sets - 1))
+        qh, ql = (x[0] for x in np_keyhash2x32([kh], [kl]))
         hit = False
         for w in range(W):
             if (occ[row, w] > 0 and khi[row, w] == qh and klo[row, w] == ql
@@ -441,5 +449,4 @@ def ref_gang_gc(table: GangTable, n_sets: int, entries, aged_lanes):
     for lane in aged_lanes:
         rows = slice(lane * n_sets, (lane + 1) * n_sets)
         age[rows] = np.where(occ[rows] > 0, age[rows] + 1, 0)
-    return cleared, GangTable(*(jnp.asarray(a) for a in
-                                (khi, klo, occ, rhi, rlo, age)))
+    return cleared, _gang_jnp((khi, klo, occ, rhi, rlo, age))

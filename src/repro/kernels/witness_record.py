@@ -1,7 +1,20 @@
-"""Pallas TPU kernels: set-parallel batched witness record (§4.2) + gc.
+"""Pallas TPU kernels: the witness record (§4.2) and gc kernels.
 
-Fast-path pipeline (DESIGN.md §4, this PR's layout)
----------------------------------------------------
+Two families live here.
+
+* **The served gang kernels** (``gang_record_pallas``, ``gang_gc_pallas``;
+  bottom of the file) back every ``DeviceWitness`` and the fused cluster
+  batch.  They compile as Mosaic kernels for the TPU; see "Gang kernels"
+  below for their layout.
+* **Single-table comparison kernels** (the set-parallel
+  ``witness_record_setpar_pallas`` / ``fastpath_record_scan_pallas``, the
+  sequential ``witness_record_seq_pallas``, ``witness_record_txn_pallas``
+  and ``witness_gc_pallas``) serve old-vs-new benchmarks and differential
+  tests only.  They gather and scatter table rows by index vectors inside
+  the kernel, which runs in interpret mode but which Mosaic refuses.
+
+Set-parallel comparison kernel
+------------------------------
 The witness table is S sets x W ways of 2x32-bit keyhash slots.  Records are
 order-dependent *within one set* (an accepted record occupies a way that later
 same-key records must conflict with) but **commute across sets** — two records
@@ -16,21 +29,42 @@ The set-parallel kernel exploits exactly that independence:
   2. The kernel runs a grid over set-tiles (TILE_S rows of the table per grid
      cell).  Each cell loops over rounds; one round loads a contiguous query
      chunk (dynamic start, static size), masks it to this cell's sets, and
-     resolves up to TILE_S sets **simultaneously** with pure VPU work — every
-     set in the round probes, conflict-checks, and fills its first free way in
-     the same vectorized step.  The per-cell loop length is the longest run in
-     the batch (≈ B/S for hashed keys), not B: the old kernel's O(B)
-     sequential ``fori_loop`` becomes O(max-run) with S-wide parallelism.
+     resolves up to TILE_S sets **simultaneously** — every set in the round
+     probes, conflict-checks, and fills its first free way in the same
+     vectorized step.
   3. Accept bits are written round-chunk-contiguously into a [B] output that
      all grid cells revisit (accumulate-on-revisit, same pattern as
      conflict_scan); ops.py unsorts them back to caller order.
 
-VMEM budget: the table tile is 3 x TILE_S x W x 4 B (48 KiB at the default
-1024x4 tile = the paper's full geometry), the reordered query batch is
-3 x B x 4 B (48 KiB at B=4096) plus the [B+1] round index — far under the
-~16 MiB budget at every supported geometry; ``WitnessGeometry.vmem_bytes``
-(repro.core.config) computes the whole-table figure used to sanity-check
-configured geometries.
+Gang kernels: memory budget
+---------------------------
+VMEM per grid cell (T = TILE_ROWS = 8192, W = 4): each
+plane block is [4, 8192] int32, 128 KiB, held as 256 KiB because VMEM pads
+4 sublanes to 8.  The record kernel keeps 6 input and 6 output blocks, each
+double-buffered: 24 x 256 KiB = 6 MiB.  The gc kernel keeps 6 + 1 input
+blocks ([1, 8192] aged-row mask, also padded to 8 sublanes) and 2 outputs:
+18 x 256 KiB = 4.5 MiB.  Both fit v5e's 16 MiB scoped VMEM and do not grow
+with the gang: more lanes mean more grid cells.  SMEM holds the prefetched
+work items: 4 words per key + 3 per group, plus 1 reason per group — the
+fused batch at B = 1024, f = 3 (3072 groups of one key) is 96 KiB of 1 MiB.
+Each SMEM array is rounded up to 1024 words, so the record kernel holds at
+most 31744 single-key groups (B = 10581 at f = 3) or 21504 two-key groups,
+and the gc kernel 43008 entries; the wrappers raise before a compile that
+would overflow.  The TPU
+compiler's ``memory_analysis()`` for a described v5e at the smoke's gang
+(64 lanes x 1024 sets: six [4, 65536] planes) reports, in HBM:
+
+  =====================  ==============  ============  ==========
+  jitted impl            arguments (B)   outputs (B)   temps (B)
+  =====================  ==============  ============  ==========
+  _gang_record_impl          6,324,224     6,308,352           0
+  _gang_groups_impl          6,344,704     6,316,544           0
+  _gang_gc_impl              6,316,544     6,296,064           0
+  _gang_fastpath_impl        6,524,928     6,525,952   3,580,416
+  =====================  ==============  ============  ==========
+
+(B = 1024, two-key groups, 16 rings of 1024; ``tests/test_tpu_compile.py``
+repeats these compiles.)  The six planes are 6,291,456 B of each.
 
 Donation / aliasing contract
 ----------------------------
@@ -74,6 +108,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import U32, GangTable, WitnessTable, matrix_rows
 
@@ -112,10 +147,11 @@ def _setpar_kernel_body(
         # Static-size window clamped into range; the valid mask trims it to
         # the round's true [start, end) span.
         base = jnp.minimum(start, B - r_blk)
-        qhi_c = pl.load(qhi_ref, (pl.ds(base, r_blk),))
-        qlo_c = pl.load(qlo_ref, (pl.ds(base, r_blk),))
-        sets_c = pl.load(sets_ref, (pl.ds(base, r_blk),))
-        qcls_c = pl.load(qcls_ref, (pl.ds(base, r_blk),))
+        win = pl.ds(base, r_blk)
+        qhi_c = qhi_ref[win]
+        qlo_c = qlo_ref[win]
+        sets_c = sets_ref[win]
+        qcls_c = qcls_ref[win]
         pos = base + jax.lax.iota(jnp.int32, r_blk)
         valid = (pos >= start) & (pos < end)
         row = sets_c - tile_lo
@@ -152,9 +188,7 @@ def _setpar_kernel_body(
         khi = khi.at[srow].set(new_hi, mode="drop")
         klo = klo.at[srow].set(new_lo, mode="drop")
         occ = occ.at[srow].set(new_occ, mode="drop")
-        old_acc = pl.load(acc_ref, (pl.ds(base, r_blk),))
-        pl.store(acc_ref, (pl.ds(base, r_blk),),
-                 jnp.where(m, accq.astype(jnp.int32), old_acc))
+        acc_ref[win] = jnp.where(m, accq.astype(jnp.int32), acc_ref[win])
         return khi, klo, occ
 
     khi, klo, occ = jax.lax.fori_loop(
@@ -239,7 +273,7 @@ def witness_record_setpar_pallas(
     table: WitnessTable,
     qhi_f: jnp.ndarray, qlo_f: jnp.ndarray, sets_f: jnp.ndarray,
     qcls_f: jnp.ndarray, round_start: jnp.ndarray, n_rounds: jnp.ndarray,
-    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool = True,
+    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool,
 ):
     """Set-parallel batched record over prep-sorted queries.
 
@@ -286,7 +320,7 @@ def fastpath_record_scan_pallas(
     qhi_f: jnp.ndarray, qlo_f: jnp.ndarray, sets_f: jnp.ndarray,
     qcls_f: jnp.ndarray, round_start: jnp.ndarray, n_rounds: jnp.ndarray,
     w_hi: jnp.ndarray, w_lo: jnp.ndarray, w_valid: jnp.ndarray,
-    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool = True,
+    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool,
 ):
     """Fused fast-path kernel: set-parallel record + conflict scan in ONE
     pallas_call.  Same prep contract as witness_record_setpar_pallas; the
@@ -345,12 +379,13 @@ def _record_seq_kernel(qhi_ref, qlo_ref, khi_in, klo_in, occ_in,
     way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
 
     def body(b, _):
-        qhi = pl.load(qhi_ref, (pl.ds(b, 1),))           # [1]
-        qlo = pl.load(qlo_ref, (pl.ds(b, 1),))
+        qhi = qhi_ref[pl.ds(b, 1)]                       # [1]
+        qlo = qlo_ref[pl.ds(b, 1)]
         s = (qlo[0] & set_mask).astype(jnp.int32)
-        row_hi = pl.load(khi_ref, (pl.ds(s, 1), slice(None)))   # [1, W]
-        row_lo = pl.load(klo_ref, (pl.ds(s, 1), slice(None)))
-        row_occ = pl.load(occ_ref, (pl.ds(s, 1), slice(None)))
+        row = (pl.ds(s, 1), slice(None))
+        row_hi = khi_ref[row]                            # [1, W]
+        row_lo = klo_ref[row]
+        row_occ = occ_ref[row]
         conflict = jnp.any(
             (row_occ == 1) & (row_hi == qhi[0]) & (row_lo == qlo[0])
         )
@@ -359,14 +394,10 @@ def _record_seq_kernel(qhi_ref, qlo_ref, khi_in, klo_in, occ_in,
         way = jnp.argmax(free)           # first free way
         acc = jnp.logical_and(~conflict, has_free)
         sel = (way_iota == way) & acc
-        pl.store(khi_ref, (pl.ds(s, 1), slice(None)),
-                 jnp.where(sel, qhi[0], row_hi))
-        pl.store(klo_ref, (pl.ds(s, 1), slice(None)),
-                 jnp.where(sel, qlo[0], row_lo))
-        pl.store(occ_ref, (pl.ds(s, 1), slice(None)),
-                 jnp.where(sel, 1, row_occ))
-        pl.store(acc_ref, (pl.ds(b, 1),),
-                 acc.astype(jnp.int32).reshape((1,)))
+        khi_ref[row] = jnp.where(sel, qhi[0], row_hi)
+        klo_ref[row] = jnp.where(sel, qlo[0], row_lo)
+        occ_ref[row] = jnp.where(sel, 1, row_occ)
+        acc_ref[pl.ds(b, 1)] = acc.astype(jnp.int32).reshape((1,))
         return 0
 
     jax.lax.fori_loop(0, B, body, 0)
@@ -375,7 +406,7 @@ def _record_seq_kernel(qhi_ref, qlo_ref, khi_in, klo_in, occ_in,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def witness_record_seq_pallas(
     table: WitnessTable, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-    *, interpret: bool = True,
+    *, interpret: bool,
 ):
     """Pre-refactor sequential kernel: the whole batch is one ordered
     fori_loop over a single grid cell.  O(B) serial steps — the throughput
@@ -462,15 +493,10 @@ def _record_txn_kernel(qhi_ref, qlo_ref, own_ref, valid_ref,
     def body(k, _):
         s = sets[k]
         sel = (way_iota == way[k]) & write[k]                  # [1, W]
-        row_hi_k = pl.load(khi_ref, (pl.ds(s, 1), slice(None)))
-        row_lo_k = pl.load(klo_ref, (pl.ds(s, 1), slice(None)))
-        row_occ_k = pl.load(occ_ref, (pl.ds(s, 1), slice(None)))
-        pl.store(khi_ref, (pl.ds(s, 1), slice(None)),
-                 jnp.where(sel, qhi[k], row_hi_k))
-        pl.store(klo_ref, (pl.ds(s, 1), slice(None)),
-                 jnp.where(sel, qlo[k], row_lo_k))
-        pl.store(occ_ref, (pl.ds(s, 1), slice(None)),
-                 jnp.where(sel, 1, row_occ_k))
+        row = (pl.ds(s, 1), slice(None))
+        khi_ref[row] = jnp.where(sel, qhi[k], khi_ref[row])
+        klo_ref[row] = jnp.where(sel, qlo[k], klo_ref[row])
+        occ_ref[row] = jnp.where(sel, 1, occ_ref[row])
         return 0
 
     jax.lax.fori_loop(0, qhi.shape[0], body, 0)
@@ -481,7 +507,7 @@ def witness_record_txn_pallas(
     table: WitnessTable,
     q_hi: jnp.ndarray, q_lo: jnp.ndarray,
     own: jnp.ndarray, valid: jnp.ndarray,
-    *, interpret: bool = True,
+    *, interpret: bool,
 ):
     """One-dispatch all-or-nothing record of one op's K (mixed-lane) keys.
 
@@ -530,447 +556,10 @@ def _gc_kernel(ghi_ref, glo_ref, khi_in, klo_in, occ_in, occ_ref):
     occ_ref[...] = jnp.where(jnp.any(m, axis=-1), 0, occ)
 
 
-# ---------------------------------------------------------------------------
-# Gang kernels: stacked lanes + kernel-held RIFL identity and gc-age state
-# ---------------------------------------------------------------------------
-# A GangTable is L witness tables flattened to [L*S, W]; queries arrive with
-# *global* set rows (lane * S + (q_lo & (S-1))) so the existing set-parallel
-# round machinery runs unchanged over the union of all lanes.  Every slot
-# additionally holds the recording op's rpc identity and a gc-age counter:
-# duplicate-retry acceptance (same key + same rpc), stale-gc suppression
-# (clear only on key AND rpc match) and §4.5 age bumping all resolve inside
-# the dispatch.  Reason codes (see repro.kernels.ref): 1 insert / 2 dup /
-# 3 conflict / 4 set-full / 0 padding.
-
-
-def _gang_setpar_body(
-    tile_lo, r_blk, nrounds_ref,
-    qhi_ref, qlo_ref, qrh_ref, qrl_ref, qcls_ref, sets_ref, rstart_ref,
-    khi_in, klo_in, occ_in, rh_in, rl_in, age_in,
-    rsn_ref, khi_ref, klo_ref, occ_ref, rh_ref, rl_ref, age_ref,
-):
-    """Set-parallel gang record for one table tile: _setpar_kernel_body
-    extended with rpc/age lanes and a per-query reason output."""
-    TILE_S, W = khi_in.shape
-    B = qhi_ref.shape[0]
-    way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-    rstart = rstart_ref[...]
-    n_rounds = nrounds_ref[0]
-
-    def round_body(r, carry):
-        khi, klo, occ, rh, rl, age = carry
-        start = rstart[r]
-        end = rstart[r + 1]
-        base = jnp.minimum(start, B - r_blk)
-        qhi_c = pl.load(qhi_ref, (pl.ds(base, r_blk),))
-        qlo_c = pl.load(qlo_ref, (pl.ds(base, r_blk),))
-        qrh_c = pl.load(qrh_ref, (pl.ds(base, r_blk),))
-        qrl_c = pl.load(qrl_ref, (pl.ds(base, r_blk),))
-        qcls_c = pl.load(qcls_ref, (pl.ds(base, r_blk),))
-        sets_c = pl.load(sets_ref, (pl.ds(base, r_blk),))
-        pos = base + jax.lax.iota(jnp.int32, r_blk)
-        valid = (pos >= start) & (pos < end)
-        row = sets_c - tile_lo
-        in_tile = (row >= 0) & (row < TILE_S)
-        m = valid & in_tile
-        rowc = jnp.clip(row, 0, TILE_S - 1)
-        row_hi = khi[rowc]                            # [r_blk, W] gathers
-        row_lo = klo[rowc]
-        row_occ = occ[rowc]
-        row_rh = rh[rowc]
-        row_rl = rl[rowc]
-        row_age = age[rowc]
-        keym = (
-            (row_occ > 0)
-            & (row_hi == qhi_c[:, None])
-            & (row_lo == qlo_c[:, None])
-        )
-        rpcm = (row_rh == qrh_c[:, None]) & (row_rl == qrl_c[:, None])
-        dupm = keym & rpcm                            # idempotent retry hit
-        # Foreign-rpc same-key hit conflicts only when the merge lattice
-        # says so (occ packs 1 + class; matrix bit test as in the plain
-        # record kernel) — commuting classes stack in sibling ways.
-        mrow = matrix_rows(qcls_c)
-        wcls = jnp.maximum(row_occ - 1, 0)
-        confm = keym & ~rpcm & (((mrow[:, None] >> wcls) & 1) == 1)
-        is_dup = jnp.any(dupm, axis=1)
-        is_conf = jnp.any(confm, axis=1)
-        free = row_occ == 0
-        has_free = jnp.any(free, axis=1)
-        way = jnp.where(is_dup, jnp.argmax(dupm, axis=1),
-                        jnp.argmax(free, axis=1))
-        acc = ~is_conf & (is_dup | has_free)
-        reason = jnp.where(
-            is_conf, 3, jnp.where(is_dup, 2, jnp.where(has_free, 1, 4))
-        ).astype(jnp.int32)
-        accq = m & acc
-        sel = (way_iota == way[:, None]) & accq[:, None]
-        new_hi = jnp.where(sel, qhi_c[:, None], row_hi)
-        new_lo = jnp.where(sel, qlo_c[:, None], row_lo)
-        new_occ = jnp.where(sel, 1 + qcls_c[:, None], row_occ)
-        new_rh = jnp.where(sel, qrh_c[:, None], row_rh)
-        new_rl = jnp.where(sel, qrl_c[:, None], row_rl)
-        new_age = jnp.where(sel, 0, row_age)          # accept resets age
-        srow = jnp.where(accq, rowc, TILE_S)
-        khi = khi.at[srow].set(new_hi, mode="drop")
-        klo = klo.at[srow].set(new_lo, mode="drop")
-        occ = occ.at[srow].set(new_occ, mode="drop")
-        rh = rh.at[srow].set(new_rh, mode="drop")
-        rl = rl.at[srow].set(new_rl, mode="drop")
-        age = age.at[srow].set(new_age, mode="drop")
-        old_rsn = pl.load(rsn_ref, (pl.ds(base, r_blk),))
-        pl.store(rsn_ref, (pl.ds(base, r_blk),),
-                 jnp.where(m, reason, old_rsn))
-        return khi, klo, occ, rh, rl, age
-
-    khi, klo, occ, rh, rl, age = jax.lax.fori_loop(
-        0, n_rounds, round_body,
-        (khi_in[...], klo_in[...], occ_in[...],
-         rh_in[...], rl_in[...], age_in[...]),
-    )
-    khi_ref[...] = khi
-    klo_ref[...] = klo
-    occ_ref[...] = occ
-    rh_ref[...] = rh
-    rl_ref[...] = rl
-    age_ref[...] = age
-
-
-def _make_gang_record_kernel(r_blk: int, tile_s: int):
-    def kernel(nrounds_ref, qhi_ref, qlo_ref, qrh_ref, qrl_ref, qcls_ref,
-               sets_ref, rstart_ref,
-               khi_in, klo_in, occ_in, rh_in, rl_in, age_in,
-               rsn_ref, khi_ref, klo_ref, occ_ref, rh_ref, rl_ref, age_ref):
-        g = pl.program_id(0)
-
-        @pl.when(g == 0)
-        def _init():
-            rsn_ref[...] = jnp.zeros_like(rsn_ref)
-
-        _gang_setpar_body(
-            g * tile_s, r_blk, nrounds_ref,
-            qhi_ref, qlo_ref, qrh_ref, qrl_ref, qcls_ref, sets_ref,
-            rstart_ref,
-            khi_in, klo_in, occ_in, rh_in, rl_in, age_in,
-            rsn_ref, khi_ref, klo_ref, occ_ref, rh_ref, rl_ref, age_ref,
-        )
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("tile_sets", "interpret"))
-def gang_record_setpar_pallas(
-    table: GangTable,
-    qhi_f: jnp.ndarray, qlo_f: jnp.ndarray,
-    qrh_f: jnp.ndarray, qrl_f: jnp.ndarray, qcls_f: jnp.ndarray,
-    sets_f: jnp.ndarray, round_start: jnp.ndarray, n_rounds: jnp.ndarray,
-    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool = True,
-):
-    """Set-parallel single-key record over a stacked gang table.
-
-    Same prep contract as ``witness_record_setpar_pallas`` except the set
-    ids are *global* rows (lane * S + local set) and each query carries its
-    rpc identity plus its merge-lattice op class (``qcls_f``).  Returns
-    (reasons-in-sorted-order [B], new gang table); all six table buffers
-    alias their outputs.
-    """
-    R, W = table.occ.shape
-    (B,) = qhi_f.shape
-    tile_s = min(tile_sets, R)
-    r_blk = min(B, R)
-    grid, full, tile = _grid_and_specs(R, W, B, tile_s)
-    out = pl.pallas_call(
-        _make_gang_record_kernel(r_blk, tile_s),
-        grid=grid,
-        in_specs=[
-            full((1,)), full((B,)), full((B,)), full((B,)), full((B,)),
-            full((B,)), full((B,)), full((B + 1,)),
-            tile, tile, tile, tile, tile, tile,
-        ],
-        out_specs=[full((B,)), tile, tile, tile, tile, tile, tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), jnp.int32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), jnp.int32),
-        ],
-        input_output_aliases={8: 1, 9: 2, 10: 3, 11: 4, 12: 5, 13: 6},
-        interpret=interpret,
-    )(n_rounds, qhi_f, qlo_f, qrh_f, qrl_f, qcls_f.astype(jnp.int32),
-      sets_f, round_start,
-      table.keys_hi, table.keys_lo, table.occ,
-      table.rpc_hi, table.rpc_lo, table.age)
-    rsn = out[0]
-    return rsn, GangTable(*out[1:])
-
-
-def _make_gang_groups_kernel(K: int):
-    """Sequential per-group all-or-nothing record: one fori_loop over G
-    groups; each group's K (padded) keys decide together against the
-    current table and, on accept, write sequentially in key order.  Free
-    ways are RESERVED in key order (the k-th same-row inserter takes the
-    row's (rank+1)-th free way), matching the fixed Python placement loop —
-    same-row keys of one group land in distinct ways instead of aliasing."""
-    def kernel(qhi_ref, qlo_ref, qrow_ref, qval_ref, qcls_ref,
-               grh_ref, grl_ref, gval_ref,
-               khi_in, klo_in, occ_in, rh_in, rl_in, age_in,
-               rsn_ref, khi_ref, klo_ref, occ_ref, rh_ref, rl_ref, age_ref):
-        W = khi_in.shape[1]
-        G = qhi_ref.shape[0]
-        khi_ref[...] = khi_in[...]
-        klo_ref[...] = klo_in[...]
-        occ_ref[...] = occ_in[...]
-        rh_ref[...] = rh_in[...]
-        rl_ref[...] = rl_in[...]
-        age_ref[...] = age_in[...]
-        way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-        k_iota = jax.lax.iota(jnp.int32, K)
-        earlier = k_iota[None, :] < k_iota[:, None]            # [K, K] j < k
-
-        def body(g, _):
-            qhi_g = pl.load(qhi_ref, (pl.ds(g, 1), slice(None)))[0]   # [K]
-            qlo_g = pl.load(qlo_ref, (pl.ds(g, 1), slice(None)))[0]
-            qrow_g = pl.load(qrow_ref, (pl.ds(g, 1), slice(None)))[0]
-            qval_g = pl.load(qval_ref, (pl.ds(g, 1), slice(None)))[0]
-            qcls_g = pl.load(qcls_ref, (pl.ds(g, 1), slice(None)))[0]
-            rc = pl.load(grh_ref, (pl.ds(g, 1),))[0]
-            rs = pl.load(grl_ref, (pl.ds(g, 1),))[0]
-            gv = pl.load(gval_ref, (pl.ds(g, 1),))[0]
-            # Decision pass: every key probes the table as left by previous
-            # groups (K-row gather, statically unrolled — K is tiny).
-            rows = [
-                (pl.load(khi_ref, (pl.ds(qrow_g[k], 1), slice(None))),
-                 pl.load(klo_ref, (pl.ds(qrow_g[k], 1), slice(None))),
-                 pl.load(occ_ref, (pl.ds(qrow_g[k], 1), slice(None))),
-                 pl.load(rh_ref, (pl.ds(qrow_g[k], 1), slice(None))),
-                 pl.load(rl_ref, (pl.ds(qrow_g[k], 1), slice(None))))
-                for k in range(K)
-            ]
-            row_hi = jnp.concatenate([r[0] for r in rows], axis=0)     # [K, W]
-            row_lo = jnp.concatenate([r[1] for r in rows], axis=0)
-            row_occ = jnp.concatenate([r[2] for r in rows], axis=0)
-            row_rh = jnp.concatenate([r[3] for r in rows], axis=0)
-            row_rl = jnp.concatenate([r[4] for r in rows], axis=0)
-            keym = (
-                (row_occ > 0)
-                & (row_hi == qhi_g[:, None])
-                & (row_lo == qlo_g[:, None])
-            )
-            rpcm = (row_rh == rc) & (row_rl == rs)
-            dupm = keym & rpcm
-            # Merge-lattice consult, same bit test as the setpar kernels.
-            mrow = matrix_rows(qcls_g)
-            wcls = jnp.maximum(row_occ - 1, 0)
-            confm = keym & ~rpcm & (((mrow[:, None] >> wcls) & 1) == 1)
-            dup_k = jnp.any(dupm, axis=1)
-            conf_k = jnp.any(confm, axis=1)
-            free = row_occ == 0
-            # Way reservation: rank each inserting key among the group's
-            # earlier same-row inserters; it seats iff free ways remain
-            # and takes the (rank+1)-th free way.
-            claim = (qval_g == 1) & ~dup_k
-            rank = jnp.sum(
-                ((qrow_g[:, None] == qrow_g[None, :]) & earlier
-                 & claim[None, :]).astype(jnp.int32),
-                axis=1,
-            )
-            n_free = jnp.sum(free.astype(jnp.int32), axis=1)
-            seat = n_free > rank
-            cfree = jnp.cumsum(free.astype(jnp.int32), axis=1)
-            selw = free & (cfree == (rank + 1)[:, None])
-            way_k = jnp.where(dup_k, jnp.argmax(dupm, axis=1),
-                              jnp.argmax(selw, axis=1))
-            ok_k = ~conf_k & (dup_k | seat)
-            vk = qval_g == 1
-            acc = jnp.all(ok_k | ~vk) & (gv == 1)
-            all_dup = jnp.all(dup_k | ~vk) & jnp.any(vk)
-            # Reject reason comes from the FIRST failing key, like the
-            # Python loop that returns at the first conflict/full key.
-            fail = vk & ~ok_k
-            fail_conf = conf_k[jnp.argmax(fail)]
-            reason = jnp.where(
-                acc, jnp.where(all_dup, 2, 1), jnp.where(fail_conf, 3, 4)
-            )
-            reason = jnp.where(gv == 1, reason, 0).astype(jnp.int32)
-            pl.store(rsn_ref, (pl.ds(g, 1),), reason.reshape((1,)))
-            # Write pass: sequential in key order; ways are pre-reserved so
-            # same-row keys never alias.  Rows reload because an earlier
-            # key of this group may share the row.
-            for k in range(K):
-                r = qrow_g[k]
-                sel = (way_iota == way_k[k]) & (acc & vk[k])
-                hi_k = pl.load(khi_ref, (pl.ds(r, 1), slice(None)))
-                lo_k = pl.load(klo_ref, (pl.ds(r, 1), slice(None)))
-                oc_k = pl.load(occ_ref, (pl.ds(r, 1), slice(None)))
-                rh_k = pl.load(rh_ref, (pl.ds(r, 1), slice(None)))
-                rl_k = pl.load(rl_ref, (pl.ds(r, 1), slice(None)))
-                ag_k = pl.load(age_ref, (pl.ds(r, 1), slice(None)))
-                pl.store(khi_ref, (pl.ds(r, 1), slice(None)),
-                         jnp.where(sel, qhi_g[k], hi_k))
-                pl.store(klo_ref, (pl.ds(r, 1), slice(None)),
-                         jnp.where(sel, qlo_g[k], lo_k))
-                pl.store(occ_ref, (pl.ds(r, 1), slice(None)),
-                         jnp.where(sel, 1 + qcls_g[k], oc_k))
-                pl.store(rh_ref, (pl.ds(r, 1), slice(None)),
-                         jnp.where(sel, rc, rh_k))
-                pl.store(rl_ref, (pl.ds(r, 1), slice(None)),
-                         jnp.where(sel, rs, rl_k))
-                pl.store(age_ref, (pl.ds(r, 1), slice(None)),
-                         jnp.where(sel, 0, ag_k))
-            return 0
-
-        jax.lax.fori_loop(0, G, body, 0)
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gang_record_groups_pallas(
-    table: GangTable,
-    qhi: jnp.ndarray, qlo: jnp.ndarray,
-    qrow: jnp.ndarray, qval: jnp.ndarray, qcls: jnp.ndarray,
-    grh: jnp.ndarray, grl: jnp.ndarray, gval: jnp.ndarray,
-    *, interpret: bool = True,
-):
-    """One-dispatch batch of per-group all-or-nothing records.
-
-    ``qhi/qlo/qrow/qval/qcls`` are [G, K] padded key arrays (mixed lanes,
-    global rows, merge-lattice classes); ``grh/grl/gval`` are the per-group
-    rpc identity and validity.  Groups resolve sequentially in index order —
-    single-key ops are groups of size 1, bit-exact with ``Witness.record``.
-    Returns (reason per group [G], new gang table).
-    """
-    R, W = table.occ.shape
-    G, K = qhi.shape
-    out = pl.pallas_call(
-        _make_gang_groups_kernel(K),
-        out_shape=[
-            jax.ShapeDtypeStruct((G,), jnp.int32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), jnp.int32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), U32),
-            jax.ShapeDtypeStruct((R, W), jnp.int32),
-        ],
-        input_output_aliases={8: 1, 9: 2, 10: 3, 11: 4, 12: 5, 13: 6},
-        interpret=interpret,
-    )(qhi.astype(U32), qlo.astype(U32),
-      qrow.astype(jnp.int32), qval.astype(jnp.int32), qcls.astype(jnp.int32),
-      grh.astype(U32), grl.astype(U32), gval.astype(jnp.int32),
-      table.keys_hi, table.keys_lo, table.occ,
-      table.rpc_hi, table.rpc_lo, table.age)
-    rsn = out[0]
-    return rsn, GangTable(*out[1:])
-
-
-def _make_gang_gc_kernel(tile_s: int, do_age: bool):
-    def kernel(ghi_ref, glo_ref, grh_ref, grl_ref, grow_ref, gval_ref,
-               aged_ref,
-               khi_in, klo_in, occ_in, rh_in, rl_in, age_in,
-               clr_ref, occ_ref, age_ref):
-        g = pl.program_id(0)
-        tile_lo = g * tile_s
-        khi = khi_in[...]                          # [T, W]
-        klo = klo_in[...]
-        occ = occ_in[...]
-        rh = rh_in[...]
-        rl = rl_in[...]
-        age = age_in[...]
-        rows = tile_lo + jax.lax.iota(jnp.int32, tile_s)
-        # [T, W, G] cube: clear only where key AND rpc AND row all match —
-        # a newer record under a different rpc survives a stale gc entry.
-        m = (
-            (khi[:, :, None] == ghi_ref[...][None, None, :])
-            & (klo[:, :, None] == glo_ref[...][None, None, :])
-            & (rh[:, :, None] == grh_ref[...][None, None, :])
-            & (rl[:, :, None] == grl_ref[...][None, None, :])
-            & (occ[:, :, None] > 0)
-            & (rows[:, None, None] == grow_ref[...][None, None, :])
-            & (gval_ref[...][None, None, :] == 1)
-        )
-        clr = jnp.any(m, axis=-1)
-        occ_new = jnp.where(clr, 0, occ)
-        age_new = jnp.where(clr, 0, age)
-        if do_age:
-            aged_t = aged_ref[...]                 # [T] per-row age mask
-            age_new = jnp.where(
-                aged_t[:, None] == 1,
-                jnp.where(occ_new > 0, age_new + 1, 0),
-                age_new,
-            )
-        occ_ref[...] = occ_new
-        age_ref[...] = age_new
-        mine = jnp.any(m, axis=(0, 1)).astype(jnp.int32)   # [G]
-
-        @pl.when(g == 0)
-        def _init():
-            clr_ref[...] = mine
-
-        @pl.when(g != 0)
-        def _accum():
-            clr_ref[...] = jnp.maximum(clr_ref[...], mine)
-    return kernel
-
-
-@functools.partial(
-    jax.jit, static_argnames=("do_age", "tile_sets", "interpret")
-)
-def gang_gc_pallas(
-    table: GangTable,
-    g_hi: jnp.ndarray, g_lo: jnp.ndarray,
-    g_rh: jnp.ndarray, g_rl: jnp.ndarray,
-    g_row: jnp.ndarray, g_valid: jnp.ndarray,
-    aged_rows: jnp.ndarray,
-    *, do_age: bool = True,
-    tile_sets: int = DEFAULT_TILE_SETS, interpret: bool = True,
-):
-    """Gang gc: rpc-matched clears + in-kernel §4.5 aging, ONE dispatch.
-
-    Entries carry (key lanes, rpc lanes, global row); a slot clears only on
-    a full match, so stale entries never drop a newer same-key record.
-    Survivors in rows flagged by ``aged_rows`` age by one round (cleared /
-    empty slots reset to 0); ``do_age=False`` is the rollback variant.
-    Returns (cleared bit per entry [G], new gang table); occ and age alias
-    their outputs, key/rpc lanes are untouched.
-    """
-    R, W = table.occ.shape
-    (G,) = g_hi.shape
-    tile_s = min(tile_sets, R)
-    grid, full, tile = _grid_and_specs(R, W, G, tile_s)
-    row_tile = pl.BlockSpec((tile_s,), lambda g: (g,))
-    out = pl.pallas_call(
-        _make_gang_gc_kernel(tile_s, do_age),
-        grid=grid,
-        in_specs=[
-            full((G,)), full((G,)), full((G,)), full((G,)),
-            full((G,)), full((G,)), row_tile,
-            tile, tile, tile, tile, tile, tile,
-        ],
-        out_specs=[full((G,)), tile, tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((G,), jnp.int32),
-            jax.ShapeDtypeStruct((R, W), jnp.int32),
-            jax.ShapeDtypeStruct((R, W), jnp.int32),
-        ],
-        input_output_aliases={9: 1, 12: 2},
-        interpret=interpret,
-    )(g_hi.astype(U32), g_lo.astype(U32),
-      g_rh.astype(U32), g_rl.astype(U32),
-      g_row.astype(jnp.int32), g_valid.astype(jnp.int32),
-      aged_rows.astype(jnp.int32),
-      table.keys_hi, table.keys_lo, table.occ,
-      table.rpc_hi, table.rpc_lo, table.age)
-    clr, occ, age = out
-    return clr, GangTable(table.keys_hi, table.keys_lo, occ,
-                          table.rpc_hi, table.rpc_lo, age)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def witness_gc_pallas(
     table: WitnessTable, g_hi: jnp.ndarray, g_lo: jnp.ndarray,
-    *, interpret: bool = True,
+    *, interpret: bool,
 ):
     """Clear synced entries.  The occupancy buffer is aliased in-program
     (input_output_aliases: occ in -> occ out), so the dispatch mutates one
@@ -985,6 +574,343 @@ def witness_gc_pallas(
     )(g_hi.astype(U32), g_lo.astype(U32),
       table.keys_hi, table.keys_lo, table.occ)
     return WitnessTable(table.keys_hi, table.keys_lo, occ)
+
+
+# ---------------------------------------------------------------------------
+# Gang kernels: stacked lanes + kernel-held RIFL identity and gc-age state
+# ---------------------------------------------------------------------------
+# A GangTable is L witness tables stacked along ONE row axis, every plane laid
+# out [W, R] with the rows (sets) along the 128 vector lanes (ref.GangTable).
+# Every slot also holds the recording op's rpc identity and a gc-age counter:
+# duplicate-retry acceptance (same key + same rpc), stale-gc suppression
+# (clear only on key AND rpc match) and §4.5 age bumping all resolve inside
+# the dispatch.  Reason codes (see repro.kernels.ref): 1 insert / 2 dup /
+# 3 conflict / 4 set-full / 0 padding.
+#
+# Mosaic layout.  The grid walks the table in row tiles of T rows ([W, T]
+# blocks, pipelined HBM <-> VMEM; the table outputs alias the inputs).  The
+# work items (record groups / gc entries) arrive as flat int32 arrays in SMEM
+# via scalar prefetch, stably sorted by tile, with ``tstart[t]`` the first
+# item of tile t — so a tile walks exactly its own items, in caller order, and
+# rows of different tiles never interact.  One item touches one row, i.e.
+# one lane of one 128-lane column chunk of each plane: the kernel loads that
+# aligned [W, 128] chunk, decides with lane-masked vector compares reduced to
+# scalars, and writes the chunk back.  No gather, no scatter, no cumsum.
+
+LANES = 128         # rows per vector chunk (TPU lane count)
+TILE_ROWS = 8192    # rows per grid cell: 6 planes x [4, 8192] blocks
+# v5e scalar memory, in int32 words.  Mosaic rounds each SMEM array up to
+# SMEM_PAD words; the tile starts and the compiler's own scalars take less
+# than SMEM_RESERVED.  A described-v5e compile matches this model exactly:
+# record K=1 fits G = 31744 groups and not 32000, K=2 fits 21504 and not
+# 21505, gc fits 43008 entries and not 43009.
+SMEM_WORDS = 1 << 18
+SMEM_PAD = 1024
+SMEM_RESERVED = 1024
+
+
+def check_smem(kernel: str, lengths) -> None:
+    """Raise before the compile if the flat int32 SMEM arrays of
+    ``lengths`` (prefetched work items and per-item outputs) overflow v5e's
+    SMEM, where Mosaic would refuse with RESOURCE_EXHAUSTED."""
+    words = sum(-(-n // SMEM_PAD) * SMEM_PAD for n in lengths)
+    if words > SMEM_WORDS - SMEM_RESERVED:
+        raise ValueError(
+            f"{kernel}: {words} SMEM words of work items exceed the "
+            f"{SMEM_WORDS - SMEM_RESERVED} that fit on v5e; split the batch")
+
+
+def gang_tile_rows(n_rows: int, n_sets: int) -> int:
+    """Rows per grid cell for a gang of ``n_rows`` (padded) rows: TILE_ROWS,
+    but at least one witness's ``n_sets`` rows (so a multi-key group never
+    spans two tiles) and at most the table.  All are powers of two, so the
+    tile divides the table."""
+    t = min(n_rows, max(TILE_ROWS, n_sets))
+    assert n_rows % t == 0, (n_rows, t)
+    return t
+
+
+def _chunk(r):
+    """Aligned [W, 128] column window holding local row ``r`` + its lane."""
+    return (slice(None), pl.ds(pl.multiple_of(r - r % LANES, LANES), LANES)), \
+        r % LANES
+
+
+def _bitmask(mask, way_iota):
+    """[W, 128] lane-masked bool -> scalar bitmask over ways (bit w = way w).
+    At most one lane is set per way, so the sum is an OR."""
+    return jnp.sum(jnp.where(mask, jnp.left_shift(1, way_iota), 0))
+
+
+def _lowest(bits, W: int):
+    """Index of the lowest set bit of a scalar way bitmask (W if none)."""
+    low = jnp.int32(W)
+    for w in range(W - 1, -1, -1):
+        low = jnp.where((bits >> w) & 1 == 1, w, low)
+    return low
+
+
+def _make_gang_record_kernel(K: int, W: int, T: int):
+    """Per-group all-or-nothing record, sequential in caller order within a
+    tile.  Each group's K (padded) keys decide together against the table as
+    earlier groups left it and, on accept, write in key order.  Free ways are
+    RESERVED in key order (the k-th same-row inserter takes the row's
+    (rank+1)-th free way), matching the Python placement loop — same-row keys
+    of one group land in distinct ways.  A single-key op is a group of K=1."""
+    def kernel(tstart, krow, khi_s, klo_s, kcv, grh, grl, gval,
+               khi_in, klo_in, occ_in, rh_in, rl_in, age_in,
+               rsn, khi, klo, occ, rh, rl, age):
+        t = pl.program_id(0)
+        planes = (khi, klo, occ, rh, rl, age)
+        for dst, src in zip(planes, (khi_in, klo_in, occ_in, rh_in, rl_in,
+                                     age_in)):
+            dst[...] = src[...]
+        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (W, LANES), 1)
+        way_iota = jax.lax.broadcasted_iota(jnp.int32, (W, LANES), 0)
+        base = t * T
+
+        def group(g, carry):
+            rc, rs = grh[g], grl[g]
+            keys = []
+            # Decision pass: every key probes the pre-group table.
+            for k in range(K):
+                i = g * K + k
+                r = jnp.clip(krow[i] - base, 0, T - 1)
+                win, ln = _chunk(r)
+                m = lane_iota == ln
+                o = occ[win]
+                qh, ql, cv = khi_s[i], klo_s[i], kcv[i]
+                cls = jnp.maximum(cv - 1, 0)
+                keym = m & (o > 0) & (khi[win] == qh) & (klo[win] == ql)
+                rpcm = (rh[win] == rc) & (rl[win] == rs)
+                # Foreign-rpc same-key hit conflicts only when the merge
+                # lattice says so (occ packs 1 + class) — commuting classes
+                # stack in sibling ways.
+                confm = (keym & ~rpcm
+                         & (((matrix_rows(cls) >> jnp.maximum(o - 1, 0)) & 1)
+                            == 1))
+                # The Python witness scans ways in order and stops at the
+                # first same-key way that is a dup (same rpc) or conflicts:
+                # whichever comes first decides.
+                dway = _lowest(_bitmask(keym & rpcm, way_iota), W)
+                cway = _lowest(_bitmask(confm, way_iota), W)
+                keys.append(dict(
+                    r=r, win=win, m=m, qh=qh, ql=ql, cls=cls, vk=cv > 0,
+                    free=_bitmask(m & (o == 0), way_iota),
+                    dway=dway, is_dup=dway < cway, conf=cway < dway,
+                ))
+            # Way reservation, in scalars: rank each inserting key among the
+            # group's earlier same-row inserters; it seats iff free ways
+            # remain and takes the (rank+1)-th free way.
+            for k, key in enumerate(keys):
+                is_dup = key["is_dup"]
+                rank = jnp.int32(0)
+                for j in range(k):
+                    claim_j = keys[j]["vk"] & ~keys[j]["is_dup"]
+                    rank += (claim_j & (keys[j]["r"] == key["r"])).astype(
+                        jnp.int32)
+                n_free = jnp.int32(0)
+                way = jnp.int32(0)
+                for w in range(W):
+                    bit = (key["free"] >> w) & 1
+                    n_free += bit
+                    way = jnp.where((bit == 1) & (n_free == rank + 1), w, way)
+                key["way"] = jnp.where(is_dup, key["dway"], way)
+                key["ok"] = ~key["conf"] & (is_dup | (n_free > rank))
+            acc = gval[g] == 1
+            all_dup = jnp.bool_(True)
+            any_vk = jnp.bool_(False)
+            failed = jnp.bool_(False)
+            fail_conf = jnp.bool_(False)
+            for key in keys:
+                acc &= key["ok"] | ~key["vk"]
+                all_dup &= key["is_dup"] | ~key["vk"]
+                any_vk |= key["vk"]
+                # Reject reason comes from the FIRST failing key, like the
+                # Python loop that returns at the first conflict/full key.
+                fail = key["vk"] & ~key["ok"]
+                fail_conf = jnp.where(fail & ~failed, key["conf"], fail_conf)
+                failed |= fail
+            reason = jnp.where(acc, jnp.where(all_dup & any_vk, 2, 1),
+                               jnp.where(fail_conf, 3, 4))
+            rsn[g] = jnp.where(gval[g] == 1, reason, 0).astype(jnp.int32)
+            # Write pass, in key order; ways are pre-reserved so same-row
+            # keys never alias.  Chunks reload: an earlier key of this group
+            # may share the row.
+            for key in keys:
+                sel = key["m"] & (way_iota == key["way"]) & (acc & key["vk"])
+                win = key["win"]
+                for ref, v in zip(planes, (key["qh"], key["ql"],
+                                           1 + key["cls"], rc, rs, 0)):
+                    ref[win] = jnp.where(sel, v, ref[win])
+            return carry
+
+        jax.lax.fori_loop(tstart[t], tstart[t + 1], group, 0)
+    return kernel
+
+
+def _i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32) \
+        if x.dtype == U32 else x.astype(jnp.int32)
+
+
+def _tile_order(tiles: jnp.ndarray, n_tiles: int):
+    """Stable sort of work items by tile (invalid items carry ``n_tiles``
+    and sort past the end): returns (perm, tstart [n_tiles + 1])."""
+    perm = jnp.argsort(tiles, stable=True)
+    tstart = jnp.searchsorted(
+        tiles[perm], jnp.arange(n_tiles + 1, dtype=jnp.int32)
+    ).astype(jnp.int32)
+    return perm, tstart
+
+
+def _unsort(perm: jnp.ndarray, x_sorted: jnp.ndarray) -> jnp.ndarray:
+    return jnp.zeros_like(x_sorted).at[perm].set(x_sorted)
+
+
+def _plane_specs(W: int, T: int):
+    tile = pl.BlockSpec((W, T), lambda t, *_: (0, t))
+    return tile, pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+@functools.partial(jax.jit, static_argnames=("n_sets", "interpret"))
+def gang_record_pallas(
+    table: GangTable,
+    qhi: jnp.ndarray, qlo: jnp.ndarray,
+    qrow: jnp.ndarray, qval: jnp.ndarray, qcls: jnp.ndarray,
+    grh: jnp.ndarray, grl: jnp.ndarray, gval: jnp.ndarray,
+    *, n_sets: int, interpret: bool,
+):
+    """One-dispatch batch of per-group all-or-nothing records.
+
+    ``qhi/qlo/qrow/qval/qcls`` are [G, K] padded key arrays (MIXED lanes,
+    global rows ``lane * n_sets + set``, validity, merge-lattice classes);
+    every key of a group must sit in one lane.  ``grh/grl/gval`` are the
+    per-group rpc identity and validity.  Groups resolve sequentially in
+    index order per row — single-key ops are groups of size 1, bit-exact
+    with ``Witness.record``.  Returns (reason per group [G], new gang table);
+    all six planes alias their outputs.
+
+    SMEM holds the prefetched work items and the reasons: 4 words per key
+    + 4 per group (the fused cluster batch at B=1024, f=3 is 96 KiB of
+    v5e's 1 MiB).  The most that fits is G = 31744 single-key groups (a
+    fused batch of B = 10581 at f = 3) or G = 21504 two-key groups; a
+    larger batch raises ValueError here (see ``check_smem``).
+    """
+    W, R = table.occ.shape
+    G, K = qhi.shape
+    check_smem("gang_record", [G * K] * 4 + [G] * 4)
+    T = gang_tile_rows(R, n_sets)
+    n_tiles = R // T
+    tiles = jnp.where(gval == 1, qrow[:, 0] // T, n_tiles).astype(jnp.int32)
+    perm, tstart = _tile_order(tiles, n_tiles)
+    kcv = jnp.where(qval == 1, 1 + qcls.astype(jnp.int32), 0)
+    items = [_i32(a[perm]).reshape(-1) for a in (qrow, qhi, qlo, kcv)]
+    items += [_i32(a[perm]) for a in (grh, grl, gval)]
+    tile, smem = _plane_specs(W, T)
+    out = pl.pallas_call(
+        _make_gang_record_kernel(K, W, T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=8, grid=(n_tiles,),
+            in_specs=[tile] * 6, out_specs=[smem] + [tile] * 6,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((G,), jnp.int32)]
+        + [jax.ShapeDtypeStruct((W, R), jnp.int32)] * 6,
+        input_output_aliases={8 + p: 1 + p for p in range(6)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(tstart, *items, *(_i32(a) for a in table))
+    rsn = jnp.where(gval == 1, _unsort(perm, out[0]), 0)
+    return rsn, GangTable(*(
+        jax.lax.bitcast_convert_type(o, a.dtype) if a.dtype == U32 else o
+        for o, a in zip(out[1:], table)
+    ))
+
+
+def _make_gang_gc_kernel(W: int, T: int, do_age: bool):
+    def kernel(tstart, erow, ehi, elo, erh, erl,
+               khi, klo, occ_in, rh, rl, age_in, aged,
+               clr, occ, age):
+        t = pl.program_id(0)
+        occ[...] = occ_in[...]
+        age[...] = age_in[...]
+        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (W, LANES), 1)
+
+        def entry(e, carry):
+            # Clear only where key AND rpc AND row all match — a newer
+            # record under a different rpc survives a stale gc entry.
+            win, ln = _chunk(jnp.clip(erow[e] - t * T, 0, T - 1))
+            o = occ[win]
+            hit = ((lane_iota == ln) & (o > 0)
+                   & (khi[win] == ehi[e]) & (klo[win] == elo[e])
+                   & (rh[win] == erh[e]) & (rl[win] == erl[e]))
+            clr[e] = jnp.max(jnp.where(hit, 1, 0))
+            occ[win] = jnp.where(hit, 0, o)
+            age[win] = jnp.where(hit, 0, age[win])
+            return carry
+
+        jax.lax.fori_loop(tstart[t], tstart[t + 1], entry, 0)
+        if do_age:
+            # §4.5: survivors in aged rows age one round; empty slots reset.
+            o = occ[...]
+            age[...] = jnp.where(aged[...] == 1,
+                                 jnp.where(o > 0, age[...] + 1, 0), age[...])
+    return kernel
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_sets", "do_age", "interpret")
+)
+def gang_gc_pallas(
+    table: GangTable,
+    g_hi: jnp.ndarray, g_lo: jnp.ndarray,
+    g_rh: jnp.ndarray, g_rl: jnp.ndarray,
+    g_row: jnp.ndarray, g_valid: jnp.ndarray,
+    aged_rows: jnp.ndarray,
+    *, n_sets: int, do_age: bool = True, interpret: bool,
+):
+    """Gang gc: rpc-matched clears + in-kernel §4.5 aging, ONE dispatch.
+
+    Entries carry (key lanes, rpc lanes, global row); a slot clears only on
+    a full match, so stale entries never drop a newer same-key record.
+    Entries apply in index order per row (a repeated entry clears once).
+    Survivors in rows flagged by ``aged_rows`` ([R] 0/1) age by one round
+    (cleared / empty slots reset to 0); ``do_age=False`` is the rollback
+    variant.  Returns (cleared bit per entry [G], new gang table); occ and
+    age alias their outputs, key/rpc lanes are untouched.
+
+    SMEM holds 5 words per entry plus the cleared bit: at most G = 43008
+    entries fit on v5e; more raise ValueError (see ``check_smem``).
+    """
+    W, R = table.occ.shape
+    check_smem("gang_gc", [g_hi.shape[0]] * 6)
+    T = gang_tile_rows(R, n_sets)
+    n_tiles = R // T
+    tiles = jnp.where(g_valid == 1, g_row // T, n_tiles).astype(jnp.int32)
+    perm, tstart = _tile_order(tiles, n_tiles)
+    items = [_i32(a[perm]) for a in (g_row, g_hi, g_lo, g_rh, g_rl)]
+    tile, smem = _plane_specs(W, T)
+    out = pl.pallas_call(
+        _make_gang_gc_kernel(W, T, do_age),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(n_tiles,),
+            in_specs=[tile] * 6 + [pl.BlockSpec((1, T), lambda t, *_: (0, t))],
+            out_specs=[smem, tile, tile],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(g_hi.shape, jnp.int32),
+            jax.ShapeDtypeStruct((W, R), jnp.int32),
+            jax.ShapeDtypeStruct((W, R), jnp.int32),
+        ],
+        input_output_aliases={8: 1, 11: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(tstart, *items, *(_i32(a) for a in table),
+      aged_rows.astype(jnp.int32).reshape(1, R))
+    clr = jnp.where(g_valid == 1, _unsort(perm, out[0]), 0)
+    return clr, table._replace(occ=out[1], age=out[2])
 
 
 # ---------------------------------------------------------------------------
@@ -1010,8 +936,8 @@ def reason_counts_update(
     dispatches.  ``mode="drop"`` discards padding rows that carry an
     out-of-range lane.
 
-    VMEM cost: N_REASON_CODES x 4 B per lane (20 B) — noise next to the six
-    [L*S, W] table planes (kernels/README.md has the full budget table).
+    Memory cost: N_REASON_CODES x 4 B per lane (20 B) in HBM — noise next
+    to the six [W, L*S] table planes (kernels/README.md has the budget).
     """
     return counters.at[lanes, reasons].add(
         valid.astype(jnp.int32), mode="drop")

@@ -40,7 +40,7 @@ class ServeConfig:
     n_slots: int = 256
     # Witness table shape (S x W), threaded down to the Pallas kernels.
     witness_geometry: WitnessGeometry = field(default_factory=WitnessGeometry)
-    # "python" (protocol-reference slot walk) or "device" (set-parallel
+    # "python" (protocol-reference slot walk) or "device" (gang record
     # kernel; one dispatch per commit batch).
     witness_backend: str = "python"
     # Commit each decode step's sessions as ONE atomic cross-shard

@@ -1419,7 +1419,7 @@ def run_batched_throughput(
     """Drive a real ShardedCluster through the batched client path
     (update_batch) with a BatchedWorkload and measure wall-clock throughput
     + fast-path ratio.  With ``witness_backend="device"`` each shard's
-    witnesses resolve every batch in one set-parallel kernel dispatch.
+    witnesses resolve every batch in one gang record kernel dispatch.
 
     ``workload`` must follow the BatchedWorkload interface — a ``batch(
     session) -> list[Op]`` method and a ``batch_size`` attribute.  The

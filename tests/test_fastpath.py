@@ -771,6 +771,75 @@ class TestGangKernelState:
         # most {16, 32, 64, 128} -> O(log B), not O(B).
         assert grown <= 4, f"jit cache grew by {grown} entries"
 
+    @pytest.mark.parametrize("n_lanes", [4, 256])
+    def test_gang_kernels_match_oracles_across_row_tiles(self, n_lanes):
+        """Single-key records, multi-key groups and gc, with colliding keys,
+        rpc retries and mixed merge classes, stay bit-exact with the
+        ``ref_gang_record``/``ref_gang_gc`` oracles — on one row tile
+        (4 lanes x 64 sets) and on a grid of two (256 lanes = 16384 rows)."""
+        from repro.kernels import (
+            GangTable,
+            gang_gc,
+            gang_record,
+            gang_record_groups,
+            ref_gang_gc,
+            ref_gang_record,
+        )
+
+        r = rng(n_lanes)
+        S, W, L, G, K = 64, 4, n_lanes, 40, 3
+        table = oracle = GangTable.empty(S, W, L)
+        reasons_seen = set()
+        for rnd in range(6):
+            base = r.integers(0, 2 ** 32, size=(12, 2), dtype=np.uint32)
+            pick = r.integers(0, 12, size=(G, K))
+            khi, klo = base[pick, 0], base[pick, 1]
+            kval = (r.random((G, K)) < 0.8).astype(np.int32)
+            kval[:, 0] = 1
+            kcls = r.choice(np.array([0, 2, 2], np.int32), size=(G, K))
+            # Few lanes, at both ends of the row space (both tiles): keys
+            # and rpcs collide often enough to hit every reason code.
+            lanes = r.choice(np.array([0, 1, L - 2, L - 1], np.int32), G)
+            rh = r.integers(0, 3, size=G).astype(np.uint32)
+            rl = r.integers(0, 3, size=G).astype(np.uint32)
+            if rnd % 2:
+                res = gang_record_groups(table, S, khi, klo, kval, lanes, rh,
+                                         rl, kcls)
+                reasons, table = list(res.reasons), res.table
+                n_keys = kval.sum(axis=1)
+            else:
+                reasons, _qh, _ql, table = gang_record(
+                    table, S, khi[:, 0], klo[:, 0], lanes, rh, rl,
+                    kcls[:, 0])
+                reasons = list(reasons)
+                n_keys = np.ones(G, np.int64)
+            groups = [
+                (int(lanes[g]), (int(rh[g]), int(rl[g])),
+                 [(int(khi[g, k]), int(klo[g, k]), int(kcls[g, k]))
+                  for k in range(K) if kval[g, k]][:n_keys[g]])
+                for g in range(G)
+            ]
+            want, oracle = ref_gang_record(oracle, S, groups)
+            assert reasons == want
+            reasons_seen.update(want)
+            for a, b in zip(table, oracle):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            ents = list(dict.fromkeys(
+                (int(lanes[g]), (int(khi[g, 0]), int(klo[g, 0])),
+                 (int(rh[g]), int(rl[g])))
+                for g in r.integers(0, G, size=10)))
+            aged = (r.random(L) < 0.5).astype(np.int32)
+            cleared, table = gang_gc(
+                table, S, *(np.array([e[1][i] for e in ents]) for i in (0, 1)),
+                *(np.array([e[2][i] for e in ents]) for i in (0, 1)),
+                np.array([e[0] for e in ents]), aged)
+            want_clr, oracle = ref_gang_gc(
+                oracle, S, ents, [i for i in range(L) if aged[i]])
+            assert [bool(c) for c in cleared] == want_clr
+            for a, b in zip(table, oracle):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert reasons_seen >= {1, 2, 3}
+
 
 class TestFusedClusterBatch:
     """The fused multi-shard driver (core/fastbatch.py): one dispatch per

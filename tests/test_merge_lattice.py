@@ -37,7 +37,6 @@ from repro.kernels import (
     conflict_matrix_np,
     gang_record_groups,
     matrix_rows,
-    np_keyhash2x32,
     ref_witness_record,
     witness_record,
 )
@@ -115,15 +114,15 @@ def test_mset_same_set_keys_both_survive_recovery():
 
 def test_gang_kernel_reserves_ways_for_same_set_group():
     """Kernel side of the same regression: one group carrying two DISTINCT
-    keys whose mixed placement collides into one set must occupy two ways."""
+    keys whose placement collides into one set must occupy two ways."""
     n_sets = 8
-    # brute-force two raw keyhashes that mix into the same set row
+    # brute-force two raw keyhashes placed in the same set row (the gang
+    # places by the raw low lane, like the Python witness's kh % n_sets)
     target = None
     seen = {}
     for raw in range(1, 4096):
         hi, lo = np.uint32(raw * 2654435761 % 2 ** 32), np.uint32(raw)
-        mh, ml = np_keyhash2x32(np.array([hi]), np.array([lo]))
-        srow = int(ml[0]) & (n_sets - 1)
+        srow = int(lo) & (n_sets - 1)
         if srow in seen and seen[srow][:2] != (int(hi), int(lo)):
             target = (seen[srow], (int(hi), int(lo), srow))
             break
@@ -139,13 +138,13 @@ def test_gang_kernel_reserves_ways_for_same_set_group():
         lanes=[0], rpc_hi=[7], rpc_lo=[1], key_cls=[[CLS_SET, CLS_SET]],
     )
     assert int(res.reasons[0]) == 1  # REASON_INSERT: accepted
-    occ_row = np.asarray(res.table.occ)[srow]
+    occ_row = np.asarray(res.table.occ)[:, srow]      # planes are [W, R]
     assert int((occ_row > 0).sum()) == 2, (
         "same-set keys of one group must reserve distinct ways"
     )
     held_keys = {
-        (int(np.asarray(res.table.keys_hi)[srow, wy]),
-         int(np.asarray(res.table.keys_lo)[srow, wy]))
+        (int(np.asarray(res.table.keys_hi)[wy, srow]),
+         int(np.asarray(res.table.keys_lo)[wy, srow]))
         for wy in range(4) if occ_row[wy] > 0
     }
     assert held_keys == {(int(res.q_hi[0, 0]), int(res.q_lo[0, 0])),
