@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import telemetry
 from .merge import CLS_OTHER, conflicts
 from .types import GcResp, Op, RecordStatus, RpcId, WitnessMode
 
@@ -106,38 +107,15 @@ class WitnessGang:
 
     def __init__(self, n_sets: int = 1024, n_ways: int = 4,
                  n_lanes: int = 4) -> None:
-        import jax.numpy as jnp
-
-        from repro.kernels import (  # deferred: keeps jax import lazy
-            N_REASON_CODES,
-            GangTable,
-        )
+        from repro.kernels import GangTable  # deferred: keeps jax import lazy
 
         assert n_lanes & (n_lanes - 1) == 0, "n_lanes must be a power of two"
         self.n_sets = n_sets
         self.n_ways = n_ways
         self.n_lanes = n_lanes
         self.table = GangTable.empty(n_sets, n_ways, n_lanes)
-        # In-dispatch telemetry plane: [n_lanes, 5] reason-code counters the
-        # record kernels scatter-accumulate into (flight recorder).  Drained
-        # and zeroed host-side by ``drain_counters``.
-        self.counters = jnp.zeros((n_lanes, N_REASON_CODES), jnp.int32)
         self._free = list(range(n_lanes - 1, -1, -1))
         self._dirty: set = set()
-
-    def drain_counters(self) -> np.ndarray:
-        """Materialize the per-lane reason-code counters and zero the plane.
-
-        Returns an [n_lanes, 5] int32 numpy array (columns indexed by the
-        kernel reason codes; column 0 is unused).  Bit-exact with the host
-        ``DeviceWitness.stats["reason_*"]`` accounting over the same drain
-        interval — tests assert the parity.
-        """
-        import jax.numpy as jnp
-
-        out = np.asarray(self.counters)
-        self.counters = jnp.zeros_like(self.counters)
-        return out
 
     def alloc(self) -> int:
         if not self._free:
@@ -165,9 +143,6 @@ class WitnessGang:
                                ((0, 0), (0, rows - a.shape[1]))))
             for a in self.table
         ))
-        self.counters = jnp.asarray(
-            np.pad(np.asarray(self.counters), ((0, old), (0, 0)))
-        )
         self._free.extend(range(self.n_lanes - 1, old - 1, -1))
 
     def _zero(self, lane: int) -> None:
@@ -183,11 +158,6 @@ class WitnessGang:
         self.table = self.table._replace(
             occ=jnp.asarray(occ), age=jnp.asarray(age)
         )
-        # A recycled lane starts its telemetry from zero too, so per-lane
-        # counters always describe the CURRENT tenant.
-        cnt = np.asarray(self.counters).copy()
-        cnt[lane] = 0
-        self.counters = jnp.asarray(cnt)
 
 
 class DeviceWitness:
@@ -210,11 +180,7 @@ class DeviceWitness:
         self._held: Dict[Tuple[int, int], Dict[RpcId, _Held]] = {}
         self.stats = {"accepts": 0, "rejects_conflict": 0, "rejects_full": 0,
                       "rejects_mode": 0, "gc_drops": 0, "kernel_batches": 0,
-                      # Host-side mirror of the device reason-counter plane
-                      # (same granularity as the kernel's accumulation: one
-                      # count per settled outcome).  Parity-asserted against
-                      # ``WitnessGang.drain_counters`` by the telemetry
-                      # tests.
+                      # One count per settled outcome, by kernel reason code.
                       "reason_insert": 0, "reason_dup": 0,
                       "reason_conflict": 0, "reason_full": 0}
 
@@ -258,7 +224,8 @@ class DeviceWitness:
         through ``gang_record`` as groups of one key; batches containing
         multi-key ops go through ``gang_record_groups``.  Both run the same
         gang record kernel, which keeps batch order exactly within every
-        set row."""
+        set row.  Packing through the kernel's results is one ``record``
+        span, the fold into statuses one ``settle`` span."""
         if self.mode is not WitnessMode.NORMAL or master_id != self.master_id:
             self.stats["rejects_mode"] += len(ops)
             return [RecordStatus.REJECTED] * len(ops)
@@ -269,23 +236,23 @@ class DeviceWitness:
         pairs = [op.hash_classes() for op in ops]
         if any(len(p) != 1 for p in pairs):
             return self._record_groups(ops, pairs)
-        khs = [p[0][0] for p in pairs]
-        kcls = np.fromiter((p[0][1] for p in pairs), np.int32, len(pairs))
-        hi, lo = _lanes(khs)
-        rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
-        lanes = np.full(len(ops), self.lane, np.int32)
-        rsn, qh, ql, table, counters = gang_record(
-            self.gang.table, self.n_sets, hi, lo, lanes, rhi, rlo, kcls,
-            counters=self.gang.counters,
-        )
-        self.gang.table = table
-        self.gang.counters = counters
-        self.stats["kernel_batches"] += 1
-        return [
-            self._settle(int(rsn[i]), [(int(qh[i]), int(ql[i]))],
-                         ops[i].rpc_id, ops[i], [int(kcls[i])])
-            for i in range(len(ops))
-        ]
+        with telemetry.span("record"):
+            khs = [p[0][0] for p in pairs]
+            kcls = np.fromiter((p[0][1] for p in pairs), np.int32, len(pairs))
+            hi, lo = _lanes(khs)
+            rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
+            lanes = np.full(len(ops), self.lane, np.int32)
+            rsn, qh, ql, table = gang_record(
+                self.gang.table, self.n_sets, hi, lo, lanes, rhi, rlo, kcls,
+            )
+            self.gang.table = table
+            self.stats["kernel_batches"] += 1
+        with telemetry.span("settle"):
+            return [
+                self._settle(int(rsn[i]), [(int(qh[i]), int(ql[i]))],
+                             ops[i].rpc_id, ops[i], [int(kcls[i])])
+                for i in range(len(ops))
+            ]
 
     def _record_groups(self, ops: List[Op], pairs=None) -> List[RecordStatus]:
         """Batch of (possibly multi-pair) ops via the grouped kernel: every
@@ -296,34 +263,35 @@ class DeviceWitness:
 
         if pairs is None:
             pairs = [op.hash_classes() for op in ops]
-        G = len(pairs)
-        K = max(len(p) for p in pairs)
-        khi = np.zeros((G, K), np.uint32)
-        klo = np.zeros((G, K), np.uint32)
-        kval = np.zeros((G, K), np.int32)
-        kcls = np.zeros((G, K), np.int32)
-        for g, p in enumerate(pairs):
-            hi, lo = _lanes([kh for kh, _c in p])
-            khi[g, :len(p)] = hi
-            klo[g, :len(p)] = lo
-            kval[g, :len(p)] = 1
-            kcls[g, :len(p)] = [c for _kh, c in p]
-        rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
-        lanes = np.full(G, self.lane, np.int32)
-        res = gang_record_groups(
-            self.gang.table, self.n_sets, khi, klo, kval, lanes, rhi, rlo,
-            kcls, counters=self.gang.counters,
-        )
-        self.gang.table = res.table
-        self.gang.counters = res.counters
-        self.stats["kernel_batches"] += 1
+        with telemetry.span("record"):
+            G = len(pairs)
+            K = max(len(p) for p in pairs)
+            khi = np.zeros((G, K), np.uint32)
+            klo = np.zeros((G, K), np.uint32)
+            kval = np.zeros((G, K), np.int32)
+            kcls = np.zeros((G, K), np.int32)
+            for g, p in enumerate(pairs):
+                hi, lo = _lanes([kh for kh, _c in p])
+                khi[g, :len(p)] = hi
+                klo[g, :len(p)] = lo
+                kval[g, :len(p)] = 1
+                kcls[g, :len(p)] = [c for _kh, c in p]
+            rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
+            lanes = np.full(G, self.lane, np.int32)
+            res = gang_record_groups(
+                self.gang.table, self.n_sets, khi, klo, kval, lanes, rhi, rlo,
+                kcls,
+            )
+            self.gang.table = res.table
+            self.stats["kernel_batches"] += 1
         out = []
-        for g, op in enumerate(ops):
-            keys = [(int(res.q_hi[g, k]), int(res.q_lo[g, k]))
-                    for k in range(len(pairs[g]))]
-            out.append(self._settle(int(res.reasons[g]), keys,
-                                    op.rpc_id, op,
-                                    [c for _kh, c in pairs[g]]))
+        with telemetry.span("settle"):
+            for g, op in enumerate(ops):
+                keys = [(int(res.q_hi[g, k]), int(res.q_lo[g, k]))
+                        for k in range(len(pairs[g]))]
+                out.append(self._settle(int(res.reasons[g]), keys,
+                                        op.rpc_id, op,
+                                        [c for _kh, c in pairs[g]]))
         return out
 
     def _settle(self, reason: int, keys: List[Tuple[int, int]],
@@ -366,10 +334,9 @@ class DeviceWitness:
             np.array([self.lane], np.int32),
             np.array([rpc_id[0] & _M32], np.uint32),
             np.array([rpc_id[1] & _M32], np.uint32),
-            kcls[None, :], counters=self.gang.counters,
+            kcls[None, :],
         )
         self.gang.table = res.table
-        self.gang.counters = res.counters
         self.stats["kernel_batches"] += 1
         keys = [(int(res.q_hi[0, k]), int(res.q_lo[0, k]))
                 for k in range(len(pairs))]

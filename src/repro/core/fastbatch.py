@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set
 
 import numpy as np
 
+from . import telemetry
 from .client import Decision
 from .master import DUP, ERROR, SYNCED
 from .types import Op, OpType, RecordStatus, WitnessMode
@@ -216,12 +217,16 @@ class FusedBatchDriver:
         """Run the batch through the fused kernel; None = not eligible (the
         caller falls back to the per-shard path).  Raises SlotMoving for
         mid-handover slots exactly like the unfused route."""
-        out = self._try(session, ops, now)
-        if out is None:
+        with telemetry.span("preflight"):
+            plan = self._preflight(session, ops)
+        if plan is None:
             self.stats["declined"] += 1
-        return out
+            return None
+        return self._run(session, ops, now, *plan)
 
-    def _try(self, session, ops: Sequence[Op], now: float):
+    def _preflight(self, session, ops: Sequence[Op]):
+        """Route, check eligibility, predict RIFL duplicates and make the
+        ring rows coherent; returns ``_run``'s plan, or None to decline."""
         cluster = self.cluster
         if cluster.gang is None or not ops:
             return None
@@ -282,14 +287,84 @@ class FusedBatchDriver:
             g = cluster.shards[sid]
             g.slot_ops[s] = g.slot_ops.get(s, 0) + 1
 
-        return self._run(session, ops, now, shard_ids, touched, exec_pred,
-                         per_shard_appends)
+        return shard_ids, touched, exec_pred, per_shard_appends
 
     def _run(self, session, ops, now, shard_ids, touched, exec_pred,
              per_shard_appends):
-        from repro.kernels import gang_fastpath_batch
-
         from .local import OpOutcome
+
+        with telemetry.span("record"):
+            res, k_cls = self._record(ops, shard_ids, touched, exec_pred)
+
+        # Witness settle: fold each op's per-lane reason codes into mirror +
+        # stats + RecordStatus, exactly as DeviceWitness.record_batch does.
+        cluster = self.cluster
+        witnesses = {sid: cluster.shards[sid].witnesses for sid in touched}
+        with telemetry.span("settle"):
+            for ws in witnesses.values():
+                for w in ws:
+                    w.stats["kernel_batches"] += 1
+            statuses_per_op: List[List[RecordStatus]] = []
+            for b, op in enumerate(ops):
+                key = (int(res.q_hi[b]), int(res.q_lo[b]))
+                statuses_per_op.append([
+                    w._settle(int(res.reasons[b, j]), [key], op.rpc_id, op,
+                              [int(k_cls[b])])
+                    for j, w in enumerate(witnesses[shard_ids[b]])
+                ])
+
+        # Master rounds in op order, the ring's conflict bit standing in for
+        # the host window lookup.
+        acks = session.acks()
+        need_drain: Set[int] = set()
+        outcomes: List[OpOutcome] = []
+        with telemetry.span("master"):
+            for b, op in enumerate(ops):
+                g = cluster.shards[shard_ids[b]]
+                cfg = cluster.config.fetch(g.shard_id)
+                verdict, result = g.master.handle_update(
+                    op, cfg.witness_list_version, acks, now,
+                    commutes=not bool(res.conflicts[b]),
+                )
+                if verdict == ERROR:
+                    # Preflight closed every ERROR path; reaching here means
+                    # the invariants broke mid-batch.
+                    raise RuntimeError(
+                        f"fused master round failed: {result.error}"
+                    )
+                decision, rtts, fast = g._classify(
+                    verdict, result, statuses_per_op[b]
+                )
+                if verdict == SYNCED or decision is Decision.NEED_SYNC:
+                    need_drain.add(g.shard_id)
+                session.mark_completed(op.rpc_id)
+                if verdict != DUP:   # dups re-externalize the original, once
+                    g.record(op, result.value, session.client_id)
+                outcomes.append(OpOutcome(
+                    value=result.value,
+                    rtts=rtts,
+                    fast_path=fast,
+                    synced_path=verdict == SYNCED,
+                    witness_accepts=sum(
+                        1 for s in statuses_per_op[b]
+                        if s is RecordStatus.ACCEPTED
+                    ),
+                ))
+
+        # Ring bookkeeping + the batched sync/gc tail (one drain per shard).
+        for sid in touched:
+            g = cluster.shards[sid]
+            self.ring.committed(sid, g.master, per_shard_appends[sid])
+            if sid in need_drain or (g.auto_sync and g.master.want_sync):
+                g._drain_syncs()
+            self.ring.advance(sid, g.master)
+        return outcomes
+
+    def _record(self, ops, shard_ids, touched, exec_pred):
+        """Pack the batch, run the one fused dispatch, wait for its results
+        and rebind the gang table and ring state; returns the result and
+        the ops' merge classes."""
+        from repro.kernels import gang_fastpath_batch
 
         cluster = self.cluster
         gang = cluster.gang
@@ -314,10 +389,9 @@ class FusedBatchDriver:
             gang.table, gang.n_sets, k_hi, k_lo, r_hi, r_lo, exec_pred,
             np.asarray(cluster.router.slot_map, np.int32), lane_map,
             self.ring.hi, self.ring.lo, self.ring.tail, self.ring.count,
-            key_cls=k_cls, ring_cls=self.ring.cls, counters=gang.counters,
+            key_cls=k_cls, ring_cls=self.ring.cls,
         )
         gang.table = res.table
-        gang.counters = res.counters
         self.ring.hi = res.ring_hi
         self.ring.lo = res.ring_lo
         self.ring.cls = res.ring_cls
@@ -326,64 +400,4 @@ class FusedBatchDriver:
             "device slot routing diverged from the host router"
         self.stats["fused_batches"] += 1
         self.stats["fused_ops"] += len(ops)
-
-        # Witness settle: fold each op's per-lane reason codes into mirror +
-        # stats + RecordStatus, exactly as DeviceWitness.record_batch does.
-        witnesses = {sid: cluster.shards[sid].witnesses for sid in touched}
-        for ws in witnesses.values():
-            for w in ws:
-                w.stats["kernel_batches"] += 1
-        statuses_per_op: List[List[RecordStatus]] = []
-        for b, op in enumerate(ops):
-            key = (int(res.q_hi[b]), int(res.q_lo[b]))
-            statuses_per_op.append([
-                w._settle(int(res.reasons[b, j]), [key], op.rpc_id, op,
-                          [int(k_cls[b])])
-                for j, w in enumerate(witnesses[shard_ids[b]])
-            ])
-
-        # Master rounds in op order, the ring's conflict bit standing in for
-        # the host window lookup.
-        acks = session.acks()
-        need_drain: Set[int] = set()
-        outcomes: List[OpOutcome] = []
-        for b, op in enumerate(ops):
-            g = cluster.shards[shard_ids[b]]
-            cfg = cluster.config.fetch(g.shard_id)
-            verdict, result = g.master.handle_update(
-                op, cfg.witness_list_version, acks, now,
-                commutes=not bool(res.conflicts[b]),
-            )
-            if verdict == ERROR:
-                # Preflight closed every ERROR path; reaching here means the
-                # invariants broke mid-batch.
-                raise RuntimeError(
-                    f"fused master round failed: {result.error}"
-                )
-            decision, rtts, fast = g._classify(
-                verdict, result, statuses_per_op[b]
-            )
-            if verdict == SYNCED or decision is Decision.NEED_SYNC:
-                need_drain.add(g.shard_id)
-            session.mark_completed(op.rpc_id)
-            if verdict != DUP:   # dups re-externalize the original, once
-                g.record(op, result.value, session.client_id)
-            outcomes.append(OpOutcome(
-                value=result.value,
-                rtts=rtts,
-                fast_path=fast,
-                synced_path=verdict == SYNCED,
-                witness_accepts=sum(
-                    1 for s in statuses_per_op[b]
-                    if s is RecordStatus.ACCEPTED
-                ),
-            ))
-
-        # Ring bookkeeping + the batched sync/gc tail (one drain per shard).
-        for sid in touched:
-            g = cluster.shards[sid]
-            self.ring.committed(sid, g.master, per_shard_appends[sid])
-            if sid in need_drain or (g.auto_sync and g.master.want_sync):
-                g._drain_syncs()
-            self.ring.advance(sid, g.master)
-        return outcomes
+        return res, k_cls

@@ -45,6 +45,7 @@ import random
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from . import telemetry
 from .backup import Backup
 from .client import ClientSession, Decision, combine_decisions, decide
 from .config import ConfigManager, WitnessGeometry
@@ -381,7 +382,9 @@ class ShardGroup:
         """
         from .local import OpOutcome
 
-        results = [self._master_round(op, session.acks(), now) for op in ops]
+        with telemetry.span("master"):
+            results = [self._master_round(op, session.acks(), now)
+                       for op in ops]
         cfg = self.config.fetch(self.shard_id)
         per_witness: List[List[RecordStatus]] = []
         for i, w in enumerate(self.witnesses):
@@ -392,24 +395,26 @@ class ShardGroup:
 
         outcomes: List[OpOutcome] = []
         need_drain = False
-        for j, op in enumerate(ops):
-            verdict, result, _cfg = results[j]
-            statuses = [pw[j] for pw in per_witness]
-            decision, rtts, fast = self._classify(verdict, result, statuses)
-            if verdict == SYNCED or decision is Decision.NEED_SYNC:
-                need_drain = True
-            session.mark_completed(op.rpc_id)
-            if verdict != DUP:   # see update(): dups re-externalize, once
-                self.record(op, result.value, session.client_id)
-            outcomes.append(OpOutcome(
-                value=result.value,
-                rtts=rtts,
-                fast_path=fast,
-                synced_path=verdict == SYNCED,
-                witness_accepts=sum(
-                    1 for s in statuses if s is RecordStatus.ACCEPTED
-                ),
-            ))
+        with telemetry.span("master"):
+            for j, op in enumerate(ops):
+                verdict, result, _cfg = results[j]
+                statuses = [pw[j] for pw in per_witness]
+                decision, rtts, fast = self._classify(verdict, result,
+                                                      statuses)
+                if verdict == SYNCED or decision is Decision.NEED_SYNC:
+                    need_drain = True
+                session.mark_completed(op.rpc_id)
+                if verdict != DUP:   # see update(): dups re-externalize, once
+                    self.record(op, result.value, session.client_id)
+                outcomes.append(OpOutcome(
+                    value=result.value,
+                    rtts=rtts,
+                    fast_path=fast,
+                    synced_path=verdict == SYNCED,
+                    witness_accepts=sum(
+                        1 for s in statuses if s is RecordStatus.ACCEPTED
+                    ),
+                ))
         if need_drain or (self.auto_sync and self.master.want_sync):
             self._drain_syncs()
         return outcomes
@@ -521,29 +526,35 @@ class ShardGroup:
 
     # ------------------------------------------------------------------ syncs
     def _drain_syncs(self) -> None:
-        """Run batched backup syncs + witness gc until quiescent (§4.4, §3.5)."""
+        """Run batched backup syncs + witness gc until quiescent (§4.4, §3.5).
+        Each round is one ``sync`` span (backups' handle_sync and
+        complete_sync) and one ``gc`` span; the probe that finds nothing to
+        sync emits none."""
         while True:
             req = self.master.begin_sync()
             if req is None:
                 return
-            ok = True
-            for b in self.backups:
-                resp = b.handle_sync(req)
-                ok = ok and resp.ok
-            if not ok:
-                self.master.abort_sync()
-                return
-            gc_entries = self.master.complete_sync()
-            live = [w for i, w in enumerate(self.witnesses)
-                    if i not in self._dropped_witnesses]
-            for resp in self._gc_witnesses(live, gc_entries):
-                # §4.5: retry suspected uncollected garbage through RIFL.
-                for op in resp.stale_requests:
-                    self.master.handle_update(
-                        op,
-                        self.config.fetch(self.shard_id).witness_list_version,
-                        (), 0.0,
-                    )
+            with telemetry.span("sync"):
+                ok = True
+                for b in self.backups:
+                    resp = b.handle_sync(req)
+                    ok = ok and resp.ok
+                if not ok:
+                    self.master.abort_sync()
+                    return
+                gc_entries = self.master.complete_sync()
+            with telemetry.span("gc"):
+                live = [w for i, w in enumerate(self.witnesses)
+                        if i not in self._dropped_witnesses]
+                for resp in self._gc_witnesses(live, gc_entries):
+                    # §4.5: retry suspected uncollected garbage through RIFL.
+                    for op in resp.stale_requests:
+                        self.master.handle_update(
+                            op,
+                            self.config.fetch(
+                                self.shard_id).witness_list_version,
+                            (), 0.0,
+                        )
 
     def _gc_witnesses(self, witnesses, gc_entries):
         """One sync round's witness gc: device witnesses sharing a gang
@@ -960,14 +971,20 @@ class ShardedCluster:
         dispatch covers hashing, slot routing, the device-resident master
         window conflict check, and every shard's every witness record.  The
         driver declines (returns None) whenever any op or shard falls off
-        its eligibility envelope, and the per-shard path below runs."""
-        if self.tracer is not None:
-            return self._update_batch_traced(session, ops, now)
-        return self._update_batch(session, ops, now)
+        its eligibility envelope, and the per-shard path below runs.
+
+        The call is the root ``update_batch`` span of the profiler's
+        timeline (``telemetry.span``); its phases nest inside it."""
+        with telemetry.span("update_batch"):
+            if self.tracer is not None:
+                return self._update_batch_traced(session, ops, now)
+            return self._update_batch(session, ops, now)
 
     def _update_batch_traced(self, session, ops, now):
         """Wall-clock batch + sampled per-op spans around the real path
-        (times in µs since an arbitrary perf_counter origin)."""
+        (times in µs since an arbitrary perf_counter origin).  Every op of
+        a batch is issued at its start and acknowledged at its return, so
+        each op's span is the batch's own window."""
         import time as _time
 
         t0 = _time.perf_counter()
@@ -982,11 +999,10 @@ class ShardedCluster:
         tr.span(("batch", self._batch_seq), "update_batch", t0 * 1e6,
                 (t1 - t0) * 1e6, actor="cluster",
                 args={"ops": len(ops), "fused": fused}, force=True)
-        per_op = (t1 - t0) * 1e6 / max(1, len(ops))
-        for i, op in enumerate(ops):
-            tr.span(op.rpc_id, "op", t0 * 1e6 + i * per_op, per_op,
+        for op, o in zip(ops, out):
+            tr.span(op.rpc_id, "op", t0 * 1e6, (t1 - t0) * 1e6,
                     actor="cluster",
-                    status="fast" if out[i].fast_path else "slow")
+                    status="fast" if o.fast_path else "slow")
         return out
 
     def _update_batch(self, session: ShardedClientSession, ops: Sequence[Op],
@@ -996,8 +1012,10 @@ class ShardedCluster:
             if fused is not None:
                 return fused
         groups: Dict[int, List[int]] = {}
-        for idx, op in enumerate(ops):
-            groups.setdefault(self._group_for(op).shard_id, []).append(idx)
+        with telemetry.span("preflight"):
+            for idx, op in enumerate(ops):
+                groups.setdefault(self._group_for(op).shard_id,
+                                  []).append(idx)
         out: List[Optional["OpOutcome"]] = [None] * len(ops)
         for shard_id, idxs in groups.items():
             sub = session.session_for(shard_id)

@@ -27,13 +27,20 @@ every actor in a distributed flow makes the same keep/drop decision with no
 coordination.  Spans outside the per-RPC id space (sync batches, gc rounds)
 pass ``force=True`` and are always kept.
 
+* ``span(name)`` — a host span of the served update path (``curp.<name>``),
+  emitted through the JAX profiler so it lies on the same timeline as the
+  device's operations.  Outside a profiler session an annotation costs well
+  under a microsecond; nothing is kept unless a trace is being recorded.
+
 Overhead discipline: instruments are plain attribute bumps; tracing does one
 dict insert per span.  ``disable()`` swaps ``get_registry()`` to a null
-registry whose instruments are no-ops — used by benchmarks/fig_obs.py to
-measure the (near-zero) registry cost on the device fast path.
+registry whose instruments are no-ops, and ``span`` to a shared null
+context — used by benchmarks/fig_obs.py to measure the (near-zero) registry
+cost on the device fast path.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -41,7 +48,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span", "Tracer",
     "get_registry", "registry", "reset_registry", "enable", "disable",
-    "enabled",
+    "enabled", "span", "SPAN_PREFIX",
 ]
 
 
@@ -289,6 +296,24 @@ def disable() -> None:
 
 def enabled() -> bool:
     return _ENABLED
+
+
+# --------------------------------------------------------------------------
+# Profiler spans
+# --------------------------------------------------------------------------
+SPAN_PREFIX = "curp."
+_NULL_SPAN = contextlib.nullcontext()
+
+
+def span(name: str):
+    """Context manager for one host span ``curp.<name>`` on the JAX
+    profiler's timeline (a shared null context while disabled).  The
+    profiler keeps spans only while a trace is being recorded."""
+    if not _ENABLED:
+        return _NULL_SPAN
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(SPAN_PREFIX + name)
 
 
 # --------------------------------------------------------------------------

@@ -26,7 +26,6 @@ from .ops import (
     matrix_rows,
     GangFastPathResult,
     GangRecordResult,
-    N_REASON_CODES,
     GangTable,
     TxnProbeResult,
     WitnessTable,
@@ -64,7 +63,6 @@ __all__ = [
     "reset_dispatch_count", "ref_conflict_scan", "ref_keyhash2x32",
     "ref_witness_gc", "ref_witness_record", "ref_witness_record_txn",
     "GangTable", "GangRecordResult", "GangFastPathResult",
-    "N_REASON_CODES",
     "gang_record", "gang_record_groups", "gang_gc", "gang_fastpath_batch",
     "gang_rows",
     "np_keyhash2x32", "ref_gang_record", "ref_gang_gc",

@@ -54,12 +54,10 @@ from .ref import (
 )
 from .witness_record import (
     DEFAULT_TILE_SETS,
-    N_REASON_CODES,
     _unsort,
     fastpath_record_scan_pallas,
     gang_gc_pallas,
     gang_record_pallas,
-    reason_counts_update,
     witness_gc_pallas,
     witness_record_seq_pallas,
     witness_record_setpar_pallas,
@@ -556,14 +554,6 @@ class GangRecordResult(NamedTuple):
     q_hi: np.ndarray         # [G, K] mixed lanes of every key (padding = 0)
     q_lo: np.ndarray         # [G, K]
     table: GangTable         # updated gang table (donated buffers)
-    counters: jnp.ndarray | None = None  # [L, 5] reason-counter plane, if fed
-
-
-def _dummy_counters() -> jnp.ndarray:
-    """Placeholder counters operand for untracked dispatches (track=False
-    is jit-static, so the scatter-add never traces; the buffer just keeps
-    the jitted signature stable)."""
-    return jnp.zeros((1, N_REASON_CODES), jnp.int32)
 
 
 def _key_rows(lanes, raw_lo, n_sets: int):
@@ -575,10 +565,9 @@ def _key_rows(lanes, raw_lo, n_sets: int):
         jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_sets", "track", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_sets", "interpret"))
 def _gang_groups_impl(table, k_hi, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
-                      g_valid, counters, n_sets: int, track: bool,
-                      interpret: bool):
+                      g_valid, n_sets: int, interpret: bool):
     G, K = k_hi.shape
     qh, ql = ref_keyhash2x32(k_hi.reshape(-1), k_lo.reshape(-1))
     qh = qh.reshape(G, K)
@@ -588,16 +577,13 @@ def _gang_groups_impl(table, k_hi, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
         table, qh, ql, rows, k_valid, k_cls, r_hi, r_lo, g_valid,
         n_sets=n_sets, interpret=interpret,
     )
-    if track:
-        # One count per GROUP (the host settles grouped ops group-wise).
-        counters = reason_counts_update(counters, lanes, rsn, g_valid)
-    return rsn, qh, ql, new_table, counters
+    return rsn, qh, ql, new_table
 
 
 def gang_record_groups(
     table: GangTable, n_sets: int,
     key_hi, key_lo, key_valid, lanes, rpc_hi, rpc_lo, key_cls=None,
-    *, counters=None, interpret: bool | None = None,
+    *, interpret: bool | None = None,
 ) -> GangRecordResult:
     """Batched per-group all-or-nothing record: ONE dispatch for a whole
     batch of (possibly multi-key) ops.
@@ -609,11 +595,6 @@ def gang_record_groups(
     index order with the Python reference's exact placement semantics; dup/
     conflict decisions use the kernel-held rpc lanes (no host mirror
     input).  Rebind ``result.table``.
-
-    ``counters`` is the optional [L, 5] device reason-counter plane; when
-    passed, each group's reason code is accumulated at its lane inside the
-    same dispatch and the updated plane comes back as ``result.counters``
-    (rebind it alongside the table).
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -635,15 +616,13 @@ def gang_record_groups(
     rpc_lo = np.pad(np.asarray(rpc_lo, np.uint32), (0, Gp - G))
     g_valid = np.zeros((Gp,), np.int32)
     g_valid[:G] = 1
-    track = counters is not None
-    rsn, qh, ql, new_table, new_counters = _gang_groups_impl(
+    rsn, qh, ql, new_table = _gang_groups_impl(
         table, key_hi, key_lo, jnp.asarray(key_cls), key_valid, lanes,
-        rpc_hi, rpc_lo, jnp.asarray(g_valid),
-        counters if track else _dummy_counters(), n_sets, track, interpret,
+        rpc_hi, rpc_lo, jnp.asarray(g_valid), n_sets, interpret,
     )
     return GangRecordResult(
         np.asarray(rsn)[:G], np.asarray(qh)[:G, :K], np.asarray(ql)[:G, :K],
-        new_table, new_counters if track else None,
+        new_table,
     )
 
 
@@ -658,34 +637,28 @@ def _single_key_record(table, qh, ql, raw_lo, k_cls, valid, lanes, r_hi,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("n_sets", "track", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_sets", "interpret"))
 def _gang_record_impl(table, k_hi, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
-                      counters, n_sets: int, track: bool, interpret: bool):
+                      n_sets: int, interpret: bool):
     qh, ql = ref_keyhash2x32(k_hi, k_lo)
     rsn, new_table = _single_key_record(
         table, qh, ql, k_lo, k_cls, k_valid, lanes, r_hi, r_lo,
         n_sets, interpret,
     )
-    if track:
-        # One count per ROW, mirroring the host's per-op settle accounting.
-        counters = reason_counts_update(counters, lanes, rsn, k_valid)
-    return rsn, qh, ql, new_table, counters
+    return rsn, qh, ql, new_table
 
 
 def gang_record(
     table: GangTable, n_sets: int, key_hi, key_lo, lanes, rpc_hi, rpc_lo,
     key_cls=None,
-    *, counters=None, interpret: bool | None = None,
+    *, interpret: bool | None = None,
 ):
     """Single-key record over the gang: ONE dispatch for a batch of [B]
     single-key ops (each with its own lane + rpc identity).
     ``key_cls`` is the optional [B] merge-lattice class lane (default SET).
 
     Returns (reasons [B], q_hi [B], q_lo [B], table) — numpy outputs,
-    caller order, same reason codes as ``gang_record_groups``.  With the
-    optional ``counters`` plane ([L, 5] int32) the return grows a fifth
-    element: the updated plane with each op's reason accumulated at its
-    lane inside the same dispatch.
+    caller order, same reason codes as ``gang_record_groups``.
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -700,15 +673,12 @@ def gang_record(
         np.asarray(lanes, np.int32),
         np.asarray(rpc_hi, np.uint32), np.asarray(rpc_lo, np.uint32),
     )
-    track = counters is not None
-    rsn, qh, ql, new_table, new_counters = _gang_record_impl(
+    rsn, qh, ql, new_table = _gang_record_impl(
         table, key_hi, key_lo, jnp.asarray(key_cls), valid, lanes,
-        rpc_hi, rpc_lo, counters if track else _dummy_counters(),
-        n_sets, track, interpret,
+        rpc_hi, rpc_lo, n_sets, interpret,
     )
-    out = (np.asarray(rsn)[:B], np.asarray(qh)[:B], np.asarray(ql)[:B],
-           new_table)
-    return out + (new_counters,) if track else out
+    return (np.asarray(rsn)[:B], np.asarray(qh)[:B], np.asarray(ql)[:B],
+            new_table)
 
 
 @functools.partial(jax.jit, static_argnames=("n_sets", "do_age", "interpret"))
@@ -772,16 +742,14 @@ class GangFastPathResult(NamedTuple):
     ring_lo: jnp.ndarray     # [NS, CAP]
     counts: np.ndarray       # [NS] post-append live-entry count per ring
     ring_cls: jnp.ndarray    # [NS, CAP] merge-lattice class per ring entry
-    counters: jnp.ndarray | None = None  # [L, 5] reason-counter plane, if fed
 
 
 @functools.partial(jax.jit, static_argnames=("n_slots", "n_sets", "f",
-                                             "track", "interpret"))
+                                             "interpret"))
 def _gang_fastpath_impl(table, k_hi, k_lo, k_cls, k_valid, r_hi, r_lo,
                         exec_pred, slot_map, lane_map, ring_hi, ring_lo,
-                        ring_cls, tail_slot, count, counters,
-                        n_slots: int, n_sets: int, f: int, track: bool,
-                        interpret: bool):
+                        ring_cls, tail_slot, count,
+                        n_slots: int, n_sets: int, f: int, interpret: bool):
     (B,) = k_hi.shape
     NS, CAP = ring_hi.shape
     qh, ql = ref_keyhash2x32(k_hi, k_lo)
@@ -833,15 +801,9 @@ def _gang_fastpath_impl(table, k_hi, k_lo, k_cls, k_valid, r_hi, r_lo,
         table, rep(qh), rep(ql), rep(k_lo), rep(qcls), rep(valid), lanes_e,
         rep(r_hi), rep(r_lo), n_sets, interpret,
     )
-    if track:
-        # One count per (op, witness copy) at the copy's lane — the same
-        # granularity the host settles at (FusedBatchDriver settles every
-        # witness of every op individually).
-        counters = reason_counts_update(
-            counters, lanes_e, rsn_flat, rep(valid))
     reasons = rsn_flat.reshape(B, f)
     return (reasons, conflicts, shard, qh, ql, new_table,
-            ring_hi, ring_lo, new_count, ring_cls, counters)
+            ring_hi, ring_lo, new_count, ring_cls)
 
 
 def gang_fastpath_batch(
@@ -849,8 +811,7 @@ def gang_fastpath_batch(
     key_hi, key_lo, rpc_hi, rpc_lo, exec_pred,
     slot_map, lane_map,
     ring_hi, ring_lo, tail_slot, count,
-    *, key_cls=None, ring_cls=None, counters=None,
-    interpret: bool | None = None,
+    *, key_cls=None, ring_cls=None, interpret: bool | None = None,
 ) -> GangFastPathResult:
     """The whole cluster-batch hot loop in ONE device dispatch:
 
@@ -869,10 +830,6 @@ def gang_fastpath_batch(
     everything, the legacy behaviour).  Reasons/conflicts come back per op
     as numpy; ring buffers and table stay on device.  Rebind table and
     ring state (including ``ring_cls``) from the result.
-
-    ``counters`` is the optional [L, 5] reason-counter plane: when passed,
-    every (op, witness copy) outcome is accumulated at the copy's lane
-    inside the same dispatch; rebind ``result.counters``.
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -892,23 +849,20 @@ def gang_fastpath_batch(
         np.asarray(rpc_hi, np.uint32), np.asarray(rpc_lo, np.uint32),
         np.asarray(exec_pred, np.int32),
     )
-    track = counters is not None
     out = _gang_fastpath_impl(
         table, key_hi, key_lo, jnp.asarray(key_cls), valid, rpc_hi, rpc_lo,
         exec_pred, jnp.asarray(slot_map), jnp.asarray(lane_map),
         ring_hi, ring_lo, ring_cls,
         jnp.asarray(np.asarray(tail_slot, np.int32)),
         jnp.asarray(np.asarray(count, np.int32)),
-        counters if track else _dummy_counters(),
-        n_slots, n_sets, f, track, interpret,
+        n_slots, n_sets, f, interpret,
     )
     (reasons, conflicts, shard, qh, ql, new_table, rh, rl, new_count,
-     rcls, new_counters) = out
+     rcls) = out
     return GangFastPathResult(
         np.asarray(reasons)[:B], np.asarray(conflicts)[:B],
         np.asarray(shard)[:B], np.asarray(qh)[:B], np.asarray(ql)[:B],
         new_table, rh, rl, np.asarray(new_count), rcls,
-        new_counters if track else None,
     )
 
 
@@ -919,7 +873,7 @@ __all__ = [
     "conflict_scan", "fastpath_batch", "txn_probe", "dispatch_count",
     "reset_dispatch_count", "ref_keyhash2x32", "ref_witness_record",
     "ref_witness_gc", "ref_conflict_scan", "ref_witness_record_txn",
-    "GangTable", "GangRecordResult", "GangFastPathResult", "N_REASON_CODES",
+    "GangTable", "GangRecordResult", "GangFastPathResult",
     "gang_record", "gang_record_groups", "gang_gc", "gang_fastpath_batch",
     "np_keyhash2x32", "ref_gang_record", "ref_gang_gc", "gang_rows",
     "matrix_rows", "conflict_matrix_np",

@@ -57,10 +57,10 @@ compiler's ``memory_analysis()`` for a described v5e at the smoke's gang
   =====================  ==============  ============  ==========
   jitted impl            arguments (B)   outputs (B)   temps (B)
   =====================  ==============  ============  ==========
-  _gang_record_impl          6,324,224     6,308,352           0
-  _gang_groups_impl          6,344,704     6,316,544           0
+  _gang_record_impl          6,320,128     6,304,256           0
+  _gang_groups_impl          6,340,608     6,312,448           0
   _gang_gc_impl              6,316,544     6,296,064           0
-  _gang_fastpath_impl        6,524,928     6,525,952   3,580,416
+  _gang_fastpath_impl        6,520,832     6,521,856   3,322,368
   =====================  ==============  ============  ==========
 
 (B = 1024, two-key groups, 16 rings of 1024; ``tests/test_tpu_compile.py``
@@ -912,32 +912,3 @@ def gang_gc_pallas(
     clr = jnp.where(g_valid == 1, _unsort(perm, out[0]), 0)
     return clr, table._replace(occ=out[1], age=out[2])
 
-
-# ---------------------------------------------------------------------------
-# In-dispatch reason-code counters plane (flight recorder)
-# ---------------------------------------------------------------------------
-
-N_REASON_CODES = 5  # index 0 unused; 1..4 = INSERT / DUP / CONFLICT / FULL
-
-
-def reason_counts_update(
-    counters: jnp.ndarray, lanes: jnp.ndarray, reasons: jnp.ndarray,
-    valid: jnp.ndarray,
-) -> jnp.ndarray:
-    """Scatter-accumulate per-lane reason-code outcomes on device.
-
-    ``counters`` is the [n_lanes, N_REASON_CODES] int32 telemetry plane owned
-    by the caller's ``WitnessGang``; ``lanes``/``reasons``/``valid`` are flat
-    [N] vectors (lane id, REASON_* code, 0/1 participation mask) for the rows
-    resolved by one record dispatch.  This is plain-XLA scatter-add, not a
-    pallas kernel, on purpose: called inside the jitted record impls it fuses
-    into the same single dispatch as the prep sorts (module docstring's
-    "plain XLA around one pallas_call" layout), so tracking adds zero extra
-    dispatches.  ``mode="drop"`` discards padding rows that carry an
-    out-of-range lane.
-
-    Memory cost: N_REASON_CODES x 4 B per lane (20 B) in HBM — noise next
-    to the six [W, L*S] table planes (kernels/README.md has the budget).
-    """
-    return counters.at[lanes, reasons].add(
-        valid.astype(jnp.int32), mode="drop")

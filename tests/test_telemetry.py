@@ -1,13 +1,7 @@
 """Flight-recorder tests: registry instruments, causal tracing, AIMD
-admission, and device-vs-host reason-code counter parity.
-
-The parity tests are the contract for the in-dispatch telemetry plane: the
-[lane, 5] counters the record kernels scatter-accumulate on device must be
-BIT-EXACT with the host ``DeviceWitness.stats["reason_*"]`` accounting over
-the same drain interval, on every record path (set-parallel, grouped
-multi-key, fused cluster fast path) — otherwise the cheap on-device view
-cannot be trusted as a stand-in for host bookkeeping.
-"""
+admission, the host's reason-code stats on every record path (set-parallel,
+grouped multi-key, fused cluster fast path), and the profiler spans of the
+served update path (``telemetry.span``)."""
 import numpy as np
 import pytest
 
@@ -227,13 +221,8 @@ class TestAimdBound:
 
 
 # ---------------------------------------------------------------------------
-# device-vs-host reason-code counter parity
+# reason-code stats: one count per settled outcome
 # ---------------------------------------------------------------------------
-def _drain_total(gang: WitnessGang) -> np.ndarray:
-    """Sum the per-lane plane into one [5] vector (and zero the plane)."""
-    return gang.drain_counters().sum(axis=0)
-
-
 def _host_reasons(*witnesses) -> np.ndarray:
     out = np.zeros(5, np.int64)
     for w in witnesses:
@@ -242,7 +231,15 @@ def _host_reasons(*witnesses) -> np.ndarray:
     return out
 
 
+def _accepted(statuses) -> int:
+    return sum(1 for st in statuses if st is RecordStatus.ACCEPTED)
+
+
 class TestReasonCounterParity:
+    """``DeviceWitness.stats["reason_*"]`` counts every settled kernel
+    outcome once, on every record path, and agrees with the statuses the
+    witness returned."""
+
     def test_collision_heavy_setparallel_batch(self):
         s = ClientSession(client_id=1)
         dw = DeviceWitness(16, 2)
@@ -252,12 +249,12 @@ class TestReasonCounterParity:
         ops = [s.op_set(f"k{i % 6}", "v") for i in range(40)]
         st = dw.record_batch(1, ops)
         st += dw.record_batch(1, ops[:10])   # exact dup retries
-        device = _drain_total(dw.gang)
         host = _host_reasons(dw)
-        np.testing.assert_array_equal(device, host)
-        assert device[_R_INSERT] > 0 and device[_R_CONFLICT] > 0
-        assert device[_R_DUP] > 0
-        assert device.sum() == len(st)
+        assert host[_R_INSERT] > 0 and host[_R_CONFLICT] > 0
+        assert host[_R_DUP] > 0
+        assert host.sum() == len(st)
+        assert host[_R_INSERT] + host[_R_DUP] == _accepted(st) \
+            == dw.stats["accepts"]
 
     def test_dup_retry_single_op_grouped_path(self):
         s = ClientSession(client_id=2)
@@ -270,11 +267,11 @@ class TestReasonCounterParity:
         op2 = s.op_set("x", "w")
         assert dw.record(1, op2.key_hashes(), op2.rpc_id, op2) \
             is RecordStatus.REJECTED
-        device = _drain_total(dw.gang)
-        np.testing.assert_array_equal(device, _host_reasons(dw))
-        assert device[_R_INSERT] == 1
-        assert device[_R_DUP] == 2
-        assert device[_R_CONFLICT] == 1
+        host = _host_reasons(dw)
+        assert host[_R_INSERT] == 1
+        assert host[_R_DUP] == 2
+        assert host[_R_CONFLICT] == 1
+        assert host[_R_FULL] == 0
 
     def test_multikey_groups_batch(self):
         s = ClientSession(client_id=3)
@@ -282,69 +279,71 @@ class TestReasonCounterParity:
         dw.start(master_id=1)
         ops = [s.op_mset([(f"a{i}", "1"), (f"b{i % 3}", "2")])
                for i in range(12)]
-        dw.record_batch(1, ops)
-        dw.record_batch(1, ops[:4])          # multi-key dup retries
-        device = _drain_total(dw.gang)
+        st = dw.record_batch(1, ops)
+        st += dw.record_batch(1, ops[:4])          # multi-key dup retries
         host = _host_reasons(dw)
-        np.testing.assert_array_equal(device, host)
         # Grouped accounting is per-GROUP (one count per op), like _settle.
-        assert device.sum() == 16
+        assert host.sum() == 16
+        assert host[_R_INSERT] + host[_R_DUP] == _accepted(st)
+        assert host[_R_DUP] == _accepted(st[12:])
 
     def test_full_sets_reason_full(self):
         s = ClientSession(client_id=4)
         dw = DeviceWitness(2, 1)   # 2 sets x 1 way: fills instantly
         dw.start(master_id=1)
         ops = [s.op_set(f"u{i}", "v") for i in range(16)]
-        dw.record_batch(1, ops)
-        device = _drain_total(dw.gang)
-        np.testing.assert_array_equal(device, _host_reasons(dw))
-        assert device[_R_FULL] + device[_R_CONFLICT] > 0
+        st = dw.record_batch(1, ops)
+        host = _host_reasons(dw)
+        assert host[_R_FULL] + host[_R_CONFLICT] > 0
+        assert host[_R_FULL] + host[_R_CONFLICT] == len(st) - _accepted(st)
+        assert host[_R_FULL] == dw.stats["rejects_full"]
 
     def test_parity_matches_python_witness_outcomes(self):
-        """Same batch on both witness backends: the device counter plane
-        agrees with the python Witness's own outcome bookkeeping."""
+        """Same batch on both witness backends: the device witness's reason
+        counts agree with the python Witness's own outcome bookkeeping."""
         s = ClientSession(client_id=5)
         ops = [s.op_set(f"k{i % 5}", "v") for i in range(30)]
         pw, dw = Witness(64, 4), DeviceWitness(64, 4)
         pw.start(master_id=9)
         dw.start(master_id=9)
         assert pw.record_batch(9, ops) == dw.record_batch(9, ops)
-        device = _drain_total(dw.gang)
-        assert device[_R_INSERT] == \
+        host = _host_reasons(dw)
+        assert host[_R_INSERT] == \
             pw.stats["accepts"] - pw.stats["accepts_dup"]
-        assert device[_R_DUP] == pw.stats["accepts_dup"]
-        assert device[_R_CONFLICT] == pw.stats["rejects_conflict"]
-        assert device[_R_FULL] == pw.stats["rejects_full"]
+        assert host[_R_DUP] == pw.stats["accepts_dup"]
+        assert host[_R_CONFLICT] == pw.stats["rejects_conflict"]
+        assert host[_R_FULL] == pw.stats["rejects_full"]
 
     def test_fused_cluster_fastpath_parity(self):
-        """The one-dispatch multi-shard fast path accumulates one count per
-        (op, witness copy) — the same granularity the driver settles at."""
+        """The one-dispatch multi-shard fast path counts one outcome per
+        (op, witness copy): the granularity the driver settles at."""
         from repro.sim.workload import BatchedWorkload
 
         cluster = ShardedCluster(n_shards=2, f=2, seed=3,
                                  witness_backend="device")
         session = cluster.new_client()
         wl = BatchedWorkload(batch_size=32, conflict_frac=0.3, seed=3)
+        outs = []
         for _ in range(3):
-            cluster.update_batch(session, wl.batch(session))
+            outs += cluster.update_batch(session, wl.batch(session))
+        assert cluster._fused.stats["fused_batches"] == 3
         witnesses = [w for sh in cluster.shards for w in sh.witnesses]
-        device = _drain_total(cluster.gang)
         host = _host_reasons(*witnesses)
-        np.testing.assert_array_equal(device, host)
-        assert device.sum() > 0 and device[_R_INSERT] > 0
+        assert host.sum() == 3 * 32 * 2
+        assert host[_R_INSERT] > 0
+        assert host[_R_INSERT] + host[_R_DUP] == \
+            sum(o.witness_accepts for o in outs)
 
     def test_drain_zeroes_and_lane_recycle_resets(self):
+        """A recycled lane starts from an empty table and its new tenant's
+        counts from zero: the key the old tenant held inserts afresh."""
         s = ClientSession(client_id=6)
         gang = WitnessGang(16, 2, n_lanes=2)
         w = DeviceWitness(16, 2, gang=gang)
         w.start(master_id=1)
         op = s.op_set("x", "v")
         w.record(1, op.key_hashes(), op.rpc_id, op)
-        assert _drain_total(gang).sum() == 1
-        assert _drain_total(gang).sum() == 0     # drained plane is zero
-        # Recycled lane starts from zero even without a drain.
-        op2 = s.op_set("y", "v")
-        w.record(1, op2.key_hashes(), op2.rpc_id, op2)
+        assert _host_reasons(w)[_R_INSERT] == 1
         lane = w.lane
         w.end()
         w2 = DeviceWitness(16, 2, gang=gang)
@@ -352,7 +351,122 @@ class TestReasonCounterParity:
         w3 = DeviceWitness(16, 2, gang=gang)
         w3.start(master_id=3)
         assert lane in (w2.lane, w3.lane)        # lane actually recycled
-        assert np.asarray(gang.counters)[lane].sum() == 0
+        tenant = w2 if w2.lane == lane else w3
+        assert _host_reasons(tenant).sum() == 0
+        op2 = s.op_set("x", "w")
+        assert tenant.record(tenant.master_id, op2.key_hashes(), op2.rpc_id,
+                             op2) is RecordStatus.ACCEPTED
+        host = _host_reasons(tenant)
+        assert host[_R_INSERT] == 1 and host.sum() == 1
+
+
+# ---------------------------------------------------------------------------
+# profiler spans of the served update path
+# ---------------------------------------------------------------------------
+def _spans(tmp_path, fn):
+    """Run ``fn`` under a CPU profiler session; return its ``curp.*`` host
+    spans as (name, start_ns, end_ns), in start order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out += [(e.name[len(telemetry.SPAN_PREFIX):], e.start_ns,
+                     e.end_ns) for e in line.events
+                    if e.name.startswith(telemetry.SPAN_PREFIX)]
+    return sorted(out, key=lambda x: (x[1], -x[2]))
+
+
+def _parents(spans):
+    """Each span's innermost enclosing span (None for a root); raises on
+    spans that overlap without nesting."""
+    parents, stack = [], []
+    for i, (_n, a, b) in enumerate(spans):
+        while stack and spans[stack[-1]][2] <= a:
+            stack.pop()
+        if stack:
+            assert b <= spans[stack[-1]][2], "spans overlap without nesting"
+        parents.append(stack[-1] if stack else None)
+        stack.append(i)
+    return parents
+
+
+def _small_cluster():
+    from repro.core import WitnessGeometry
+
+    cl = ShardedCluster(n_shards=2, f=2, sync_batch=4,
+                        witness_backend="device",
+                        geometry=WitnessGeometry(64, 4))
+    return cl, cl.new_client()
+
+
+def _phases_after_root(spans):
+    parents = _parents(spans)
+    assert [n for n, _a, _b in spans].count("update_batch") == 1
+    assert spans[0][0] == "update_batch" and parents[0] is None
+    assert all(p == 0 for p in parents[1:]), "phases nest under the root"
+    names = [n for n, _a, _b in spans[1:]]
+    assert names.count("sync") == names.count("gc") >= 1
+    return names
+
+
+class TestProfilerSpans:
+    def test_fused_path_spans(self, tmp_path):
+        cl, s = _small_cluster()
+        cl.update_batch(s, [s.op_set(f"w{i}", "v") for i in range(16)])
+        ops = [s.op_set(f"k{i}", "v") for i in range(16)]
+        spans = _spans(tmp_path, lambda: cl.update_batch(s, ops))
+        assert cl._fused.stats["fused_batches"] == 2
+        names = _phases_after_root(spans)
+        assert names[:4] == ["preflight", "record", "settle", "master"]
+        assert set(names[4:]) == {"sync", "gc"}
+        assert names[4::2] == ["sync"] * (len(names[4:]) // 2)
+
+    def test_per_shard_path_spans(self, tmp_path):
+        """A one-field HMSET batch declines the fused path: its preflight,
+        then the per-shard grouping, then per shard the master rounds, one
+        record and settle per witness, the classification and the drain."""
+        cl, s = _small_cluster()
+        cl.update_batch(s, [s.op_hmset(f"w{i}", (("f0", "v"),))
+                            for i in range(16)])
+        ops = [s.op_hmset(f"h{i}", (("f0", "v"),)) for i in range(16)]
+        spans = _spans(tmp_path, lambda: cl.update_batch(s, ops))
+        assert cl._fused.stats["fused_batches"] == 0
+        names = _phases_after_root(spans)
+        assert names[:2] == ["preflight", "preflight"]
+        per_shard = ["master"] + ["record", "settle"] * 2 + ["master"]
+        rest, shards = names[2:], 0
+        while rest:
+            assert rest[:len(per_shard)] == per_shard
+            rest = rest[len(per_shard):]
+            while rest[:2] == ["sync", "gc"]:
+                rest = rest[2:]
+            shards += 1
+        assert shards == len({cl.shard_of(op.keys[0]) for op in ops})
+
+    def test_disabled_emits_no_spans(self, tmp_path):
+        cl, s = _small_cluster()
+        cl.update_batch(s, [s.op_set(f"w{i}", "v") for i in range(16)])
+        ops = [s.op_set(f"k{i}", "v") for i in range(16)]
+        telemetry.disable()
+        try:
+            assert telemetry.span("x") is telemetry.span("y")
+            spans = _spans(tmp_path, lambda: cl.update_batch(s, ops))
+        finally:
+            telemetry.enable()
+        assert spans == []
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from repro.core.fastbatch import RING_CAP  # noqa: E402
-from repro.kernels import N_REASON_CODES, gang_rows  # noqa: E402
+from repro.kernels import gang_rows  # noqa: E402
 from repro.kernels.ops import (  # noqa: E402
     DEFAULT_N_SLOTS,
     _gang_fastpath_impl,
@@ -79,20 +79,18 @@ def _table(spec):
 
 def _args(spec, name):
     u, i = jnp.uint32, jnp.int32
-    counters = spec((LANES, N_REASON_CODES), i)
     common = dict(n_sets=N_SETS, interpret=False)
     if name == "gang_record":
         return _gang_record_impl, (
             _table(spec), spec((B,), u), spec((B,), u), spec((B,), i),
             spec((B,), i), spec((B,), i), spec((B,), u), spec((B,), u),
-            counters,
-        ), dict(common, track=True)
+        ), common
     if name == "gang_record_groups":
         return _gang_groups_impl, (
             _table(spec), spec((B, K), u), spec((B, K), u), spec((B, K), i),
             spec((B, K), i), spec((B,), i), spec((B,), u), spec((B,), u),
-            spec((B,), i), counters,
-        ), dict(common, track=True)
+            spec((B,), i),
+        ), common
     if name == "gang_gc":
         return _gang_gc_impl, (
             _table(spec), spec((B,), u), spec((B,), u), spec((B,), u),
@@ -105,8 +103,8 @@ def _args(spec, name):
         spec((B,), i), spec((B,), u), spec((B,), u), spec((B,), i),
         spec((DEFAULT_N_SLOTS,), i), spec((N_SHARDS, F), i),
         spec(ring, u), spec(ring, u), spec(ring, i),
-        spec((N_SHARDS,), i), spec((N_SHARDS,), i), counters,
-    ), dict(common, n_slots=DEFAULT_N_SLOTS, f=F, track=True)
+        spec((N_SHARDS,), i), spec((N_SHARDS,), i),
+    ), dict(common, n_slots=DEFAULT_N_SLOTS, f=F)
 
 
 @pytest.mark.parametrize("name", ["gang_record", "gang_record_groups",
