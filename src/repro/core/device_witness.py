@@ -22,8 +22,10 @@ consulted to decide accept/reject/gc outcomes on the hot path.
 Many witness instances share one device-resident **gang**
 (:class:`WitnessGang`): all shards' x all witnesses' tables stacked into a
 single [n_lanes*S, W] array, so a routed cross-shard batch records at every
-target lane in ONE dispatch (repro.kernels.ops.gang_fastpath_batch) and a
-sync round gc's every witness of a shard in ONE dispatch (``gc_many``).
+target lane in ONE dispatch (repro.kernels.ops.gang_fastpath_batch), a batch
+the fused path declines records at every witness of every master in ONE
+dispatch (``record_many``), and a sync round gc's every witness of a shard
+in ONE dispatch (``gc_many``).
 
 Set placement equals the Python witness's: ``kh % n_sets`` on the raw 64-bit
 key hash (its low lane masked by S-1), so both backends fill the same sets
@@ -218,81 +220,9 @@ class DeviceWitness:
         return self._record_keys(key_hashes, rpc_id, request)
 
     def record_batch(self, master_id: int, ops: List[Op]) -> List[RecordStatus]:
-        """Whole-batch record, ONE kernel dispatch, any mix of group sizes.
-
-        All-single-key batches (the batched client path's common case) go
-        through ``gang_record`` as groups of one key; batches containing
-        multi-key ops go through ``gang_record_groups``.  Both run the same
-        gang record kernel, which keeps batch order exactly within every
-        set row.  Packing through the kernel's results is one ``record``
-        span, the fold into statuses one ``settle`` span."""
-        if self.mode is not WitnessMode.NORMAL or master_id != self.master_id:
-            self.stats["rejects_mode"] += len(ops)
-            return [RecordStatus.REJECTED] * len(ops)
-        if not ops:
-            return []
-        from repro.kernels import gang_record
-
-        pairs = [op.hash_classes() for op in ops]
-        if any(len(p) != 1 for p in pairs):
-            return self._record_groups(ops, pairs)
-        with telemetry.span("record"):
-            khs = [p[0][0] for p in pairs]
-            kcls = np.fromiter((p[0][1] for p in pairs), np.int32, len(pairs))
-            hi, lo = _lanes(khs)
-            rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
-            lanes = np.full(len(ops), self.lane, np.int32)
-            rsn, qh, ql, table = gang_record(
-                self.gang.table, self.n_sets, hi, lo, lanes, rhi, rlo, kcls,
-            )
-            self.gang.table = table
-            self.stats["kernel_batches"] += 1
-        with telemetry.span("settle"):
-            return [
-                self._settle(int(rsn[i]), [(int(qh[i]), int(ql[i]))],
-                             ops[i].rpc_id, ops[i], [int(kcls[i])])
-                for i in range(len(ops))
-            ]
-
-    def _record_groups(self, ops: List[Op], pairs=None) -> List[RecordStatus]:
-        """Batch of (possibly multi-pair) ops via the grouped kernel: every
-        op resolves all-or-nothing, whole batch in ONE dispatch.  Groups are
-        the ops' lattice pairs — HMSET contributes its derived per-field
-        FIELD sub-hashes, so field overlap conflicts in-kernel."""
-        from repro.kernels import gang_record_groups
-
-        if pairs is None:
-            pairs = [op.hash_classes() for op in ops]
-        with telemetry.span("record"):
-            G = len(pairs)
-            K = max(len(p) for p in pairs)
-            khi = np.zeros((G, K), np.uint32)
-            klo = np.zeros((G, K), np.uint32)
-            kval = np.zeros((G, K), np.int32)
-            kcls = np.zeros((G, K), np.int32)
-            for g, p in enumerate(pairs):
-                hi, lo = _lanes([kh for kh, _c in p])
-                khi[g, :len(p)] = hi
-                klo[g, :len(p)] = lo
-                kval[g, :len(p)] = 1
-                kcls[g, :len(p)] = [c for _kh, c in p]
-            rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
-            lanes = np.full(G, self.lane, np.int32)
-            res = gang_record_groups(
-                self.gang.table, self.n_sets, khi, klo, kval, lanes, rhi, rlo,
-                kcls,
-            )
-            self.gang.table = res.table
-            self.stats["kernel_batches"] += 1
-        out = []
-        with telemetry.span("settle"):
-            for g, op in enumerate(ops):
-                keys = [(int(res.q_hi[g, k]), int(res.q_lo[g, k]))
-                        for k in range(len(pairs[g]))]
-                out.append(self._settle(int(res.reasons[g]), keys,
-                                        op.rpc_id, op,
-                                        [c for _kh, c in pairs[g]]))
-        return out
+        """Whole-batch record, ONE kernel dispatch, any mix of group sizes:
+        ``record_many`` with this witness alone."""
+        return record_many([(self, master_id, ops)])[0]
 
     def _settle(self, reason: int, keys: List[Tuple[int, int]],
                 rpc_id: RpcId, request: Op,
@@ -506,3 +436,98 @@ def gc_many(witnesses: Sequence[DeviceWitness],
         w._apply_gc(keys, rpcs, [bool(c) for c in cleared[i * E:(i + 1) * E]])
         for i, w in enumerate(witnesses)
     ]
+
+
+def _pack(ops: Sequence[Op], pairs, K: int):
+    """[G, K] key lanes, validity and classes plus [G] rpc lanes of one op
+    list, each op a group of its (key_hash, class) pairs."""
+    G = len(ops)
+    counts = np.fromiter(map(len, pairs), np.int64, G)
+    flat = [pc for p in pairs for pc in p]
+    hi, lo = _lanes([kh for kh, _c in flat])
+    g = np.repeat(np.arange(G), counts)
+    k = np.arange(len(flat)) - np.repeat(np.cumsum(counts) - counts, counts)
+    khi = np.zeros((G, K), np.uint32)
+    klo = np.zeros((G, K), np.uint32)
+    kval = np.zeros((G, K), np.int32)
+    kcls = np.zeros((G, K), np.int32)
+    khi[g, k] = hi
+    klo[g, k] = lo
+    kval[g, k] = 1
+    kcls[g, k] = np.fromiter((c for _kh, c in flat), np.int32, len(flat))
+    rhi, rlo = _rpc_lanes([op.rpc_id for op in ops])
+    return khi, klo, kval, kcls, rhi, rlo
+
+
+def record_many(
+    jobs: Sequence[Tuple[DeviceWitness, int, List[Op]]],
+) -> List[List[RecordStatus]]:
+    """Record each job's ops at its witness, every job in ONE dispatch.
+
+    ``jobs`` are (witness, master_id, ops) whose witnesses share one gang;
+    ops resolve all-or-nothing per op, in op order within each witness.
+    Witness lanes never overlap, so one stacked dispatch makes the decisions
+    one dispatch per job would.  Each distinct op list is packed once and
+    repeated across its witnesses; all-single-pair batches go through
+    ``gang_record`` with per-item lanes, any multi-pair op sends the whole
+    dispatch through ``gang_record_groups``.  A witness whose mode or master
+    does not match rejects its ops (``rejects_mode``) and takes no part.
+    Packing through the kernel's results is one ``record`` span, the fold
+    into statuses one ``settle`` span.  Returns statuses per job, in order.
+    """
+    from repro.kernels import gang_record, gang_record_groups
+
+    out: List[Optional[List[RecordStatus]]] = []
+    live = []
+    for w, master_id, ops in jobs:
+        if w.mode is not WitnessMode.NORMAL or master_id != w.master_id:
+            w.stats["rejects_mode"] += len(ops)
+            out.append([RecordStatus.REJECTED] * len(ops))
+        elif not ops:
+            out.append([])
+        else:
+            live.append((len(out), w, ops))
+            out.append(None)
+    if not live:
+        return out  # type: ignore[return-value]
+    gang = live[0][1].gang
+    assert all(w.gang is gang for _j, w, _ops in live), \
+        "witnesses must share a gang"
+    with telemetry.span("record"):
+        lists = {id(ops): ops for _j, _w, ops in live}
+        pairs = {key: [op.hash_classes() for op in ops]
+                 for key, ops in lists.items()}
+        K = max(len(p) for ps in pairs.values() for p in ps)
+        packed = {key: _pack(ops, pairs[key], K)
+                  for key, ops in lists.items()}
+        khi, klo, kval, kcls, rhi, rlo = (
+            np.concatenate([packed[id(ops)][i] for _j, _w, ops in live])
+            for i in range(6))
+        lanes = np.repeat(
+            np.fromiter((w.lane for _j, w, _ops in live), np.int32, len(live)),
+            [len(ops) for _j, _w, ops in live])
+        if K == 1:
+            rsn, qh, ql, table = gang_record(
+                gang.table, gang.n_sets, khi[:, 0], klo[:, 0], lanes, rhi,
+                rlo, kcls[:, 0])
+            qh, ql = qh[:, None], ql[:, None]
+        else:
+            rsn, qh, ql, table = gang_record_groups(
+                gang.table, gang.n_sets, khi, klo, kval, lanes, rhi, rlo, kcls)
+        gang.table = table
+        telemetry.registry().counter("witness.stacked_records").inc()
+        telemetry.registry().counter("witness.stacked_lanes").inc(len(live))
+    with telemetry.span("settle"):
+        rsn, qh, ql = rsn.tolist(), qh.tolist(), ql.tolist()
+        at = 0
+        for j, w, ops in live:
+            w.stats["kernel_batches"] += 1
+            ps = pairs[id(ops)]
+            out[j] = [
+                w._settle(rsn[at + g],
+                          list(zip(qh[at + g][:len(p)], ql[at + g][:len(p)])),
+                          op.rpc_id, op, [c for _kh, c in p])
+                for g, (op, p) in enumerate(zip(ops, ps))
+            ]
+            at += len(ops)
+    return out  # type: ignore[return-value]

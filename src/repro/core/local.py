@@ -105,8 +105,8 @@ class LocalCluster:
         return self.group.update(session, op, now)
 
     def update_batch(self, session: ClientSession, ops, now: float = 0.0):
-        """Batched updates: one master round + one record invocation per
-        witness for the whole batch (see ShardGroup.update_batch)."""
+        """Batched updates: one master round + one record of the whole batch
+        at all witnesses (see ShardGroup.update_batch)."""
         return self.group.update_batch(session, ops, now)
 
     def read(self, session: ClientSession, op: Op, now: float = 0.0) -> OpOutcome:

@@ -371,8 +371,8 @@ class ShardGroup:
     def update_batch(self, session: ClientSession, ops: Sequence[Op],
                      now: float = 0.0) -> List["OpOutcome"]:
         """Batched CURP updates: one master round (ops executed in order) +
-        ONE record invocation per witness for the whole batch (a single
-        gang record kernel dispatch on the device backend).
+        ONE record of the whole batch at all f witnesses (a single stacked
+        gang record dispatch on the device backend, see ``_record_batches``).
 
         Per-op accept/reject and fast/slow-path accounting are preserved —
         op j's witness statuses see exactly the accepts of ops < j, as the
@@ -380,18 +380,25 @@ class ShardGroup:
         (that's the batching window); any op that needs a sync is drained
         once before the batch returns, so nothing is externalized early.
         """
-        from .local import OpOutcome
+        results = self._master_rounds(session, ops, now)
+        (statuses,) = _record_batches([(self, list(ops))])
+        return self._finish_batch(session, ops, results, statuses)
 
+    def _master_rounds(self, session: ClientSession, ops: Sequence[Op],
+                       now: float) -> List[Tuple[str, ExecResult,
+                                                 ClusterConfig]]:
+        """First step of a batch: every op's master round, in order."""
         with telemetry.span("master"):
-            results = [self._master_round(op, session.acks(), now)
-                       for op in ops]
-        cfg = self.config.fetch(self.shard_id)
-        per_witness: List[List[RecordStatus]] = []
-        for i, w in enumerate(self.witnesses):
-            if i in self._dropped_witnesses:
-                per_witness.append([RecordStatus.REJECTED] * len(ops))
-            else:
-                per_witness.append(w.record_batch(cfg.master_id, list(ops)))
+            return [self._master_round(op, session.acks(), now)
+                    for op in ops]
+
+    def _finish_batch(self, session: ClientSession, ops: Sequence[Op],
+                      results, per_witness: List[List[RecordStatus]],
+                      ) -> List["OpOutcome"]:
+        """Last step of a batch: fold each op's master verdict and witness
+        statuses into its outcome, mark it completed, record its history,
+        then drain the syncs the batch needs."""
+        from .local import OpOutcome
 
         outcomes: List[OpOutcome] = []
         need_drain = False
@@ -643,6 +650,37 @@ class ShardGroup:
         ids = list(self._witness_ids)
         ids[witness_idx] = new_id
         self._witness_ids = tuple(ids)
+
+
+def _record_batches(batches: Sequence[Tuple[ShardGroup, List[Op]]],
+                    ) -> List[List[List[RecordStatus]]]:
+    """Record each (group, ops) batch at every witness of its group; returns
+    per batch the per-witness status lists.  Device witnesses sharing a gang
+    record in ONE stacked dispatch (``record_many``); any other witness
+    records by its own ``record_batch``.  A dropped witness rejects every
+    op (timeout == reject)."""
+    from .device_witness import DeviceWitness, record_many
+
+    out: List[List[Optional[List[RecordStatus]]]] = []
+    stacked: Dict[int, list] = {}
+    for g, ops in batches:
+        master_id = g.config.fetch(g.shard_id).master_id
+        per: List[Optional[List[RecordStatus]]] = []
+        for i, w in enumerate(g.witnesses):
+            if i in g._dropped_witnesses:
+                per.append([RecordStatus.REJECTED] * len(ops))
+            elif isinstance(w, DeviceWitness):
+                stacked.setdefault(id(w.gang), []).append(
+                    (w, master_id, ops, per, len(per)))
+                per.append(None)
+            else:
+                per.append(w.record_batch(master_id, ops))
+        out.append(per)
+    for jobs in stacked.values():
+        for (_w, _m, _ops, per, i), st in zip(
+                jobs, record_many([job[:3] for job in jobs])):
+            per[i] = st
+    return out  # type: ignore[return-value]
 
 
 # ---------------------------------------------------------------------------
@@ -961,10 +999,11 @@ class ShardedCluster:
 
     def update_batch(self, session: ShardedClientSession, ops: Sequence[Op],
                      now: float = 0.0) -> List["OpOutcome"]:
-        """Batched client path: group ops by owning shard, drive each shard's
-        batch through ShardGroup.update_batch (one witness-record invocation
-        — one kernel dispatch on the device backend — per witness per shard),
-        and return per-op outcomes in the input order.
+        """Batched client path: group ops by owning shard, run every shard's
+        master rounds, record every shard's ops at all its witnesses (ONE
+        stacked kernel dispatch on the device backend, ``record_many``),
+        then classify and drain shard by shard; return per-op outcomes in
+        the input order.
 
         On the device backend a routed cross-shard batch of plain updates
         first tries the fused driver (core/fastbatch.py): ONE stacked-gang
@@ -1017,16 +1056,40 @@ class ShardedCluster:
                 groups.setdefault(self._group_for(op).shard_id,
                                   []).append(idx)
         out: List[Optional["OpOutcome"]] = [None] * len(ops)
-        for shard_id, idxs in groups.items():
-            sub = session.session_for(shard_id)
-            res = self._with_txn_resolution(
-                lambda shard_id=shard_id, sub=sub, idxs=idxs:
-                self.shards[shard_id].update_batch(
-                    sub, [ops[i] for i in idxs], now
-                )
-            )
-            for i, outcome in zip(idxs, res):
-                out[i] = outcome
+        todo = list(groups.items())
+        seen: set = set()
+        while todo:
+            # Shards run phased (all master rounds, one record, then each
+            # shard's classification and drain).  Lanes and masters never
+            # overlap, so outcomes equal a shard-by-shard loop's; masters
+            # see the batch-start ack frontier, which only delays dropping
+            # completion records.  A shard blocked by an orphaned txn lock
+            # finishes the shards begun before it, resolves the txn and
+            # runs again from itself, as _with_txn_resolution would.
+            begun, pend = [], None
+            for shard_id, idxs in todo:
+                g = self.shards[shard_id]
+                sub = session.session_for(shard_id)
+                sub_ops = [ops[i] for i in idxs]
+                try:
+                    results = g._master_rounds(sub, sub_ops, now)
+                except TxnPending as exc:
+                    pend = exc
+                    break
+                begun.append((g, sub, sub_ops, idxs, results))
+            statuses = _record_batches([(b[0], b[2]) for b in begun])
+            for (g, sub, sub_ops, idxs, results), st in zip(begun, statuses):
+                for i, outcome in zip(
+                        idxs, g._finish_batch(sub, sub_ops, results, st)):
+                    out[i] = outcome
+            todo = todo[len(begun):]
+            if pend is not None:
+                if begun:
+                    seen = set()
+                if pend.spec.txn_id in seen:
+                    raise pend
+                seen.add(pend.spec.txn_id)
+                resolve_txn(self, pend.spec)
         return out  # type: ignore[return-value]
 
     def mset(self, session: ShardedClientSession, kvs, now: float = 0.0,

@@ -113,8 +113,8 @@ class CurpSessionStore:
 
     def commit_batch(self, states: Sequence[SessionState]) -> None:
         """Durably commit a whole decode step's sessions in one batched CURP
-        round: ops grouped per shard, each shard's witnesses record the batch
-        in a single invocation (one kernel dispatch on the device backend),
+        round: ops grouped per shard, every shard's witnesses record the
+        batch together (one stacked kernel dispatch on the device backend),
         per-session fast/slow accounting preserved.  Distinct sessions have
         distinct keys, so a multi-session batch stays on the 1-RTT path."""
         if not states:

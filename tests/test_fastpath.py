@@ -747,6 +747,60 @@ class TestGangKernelState:
         assert [w.stats["gc_drops"] for w in ws] == \
             [w.stats["gc_drops"] for w in ws2] == [4, 4, 4]
 
+    @pytest.mark.parametrize("kind", ["single", "hmset", "mixed"])
+    def test_record_many_one_dispatch_matches_per_witness(self, kind):
+        """Stacked record: one dispatch covers every witness of the gang
+        (two sharing one op list, one with its own, one addressed to the
+        wrong master), with the statuses, mirrors and stats of one
+        ``record_batch`` per witness, and the Python witness's statuses."""
+        from repro.core import telemetry
+        from repro.core.client import ClientSession
+        from repro.core.device_witness import WitnessGang, record_many
+
+        s = ClientSession(client_id=27)
+
+        def op(i, tag="m"):
+            k = f"{tag}{i % 7}"
+            if kind == "single" or (kind == "mixed" and i % 3 == 0):
+                return s.op_set(k, "v")
+            if kind == "hmset" or i % 3 == 1:
+                return s.op_hmset(k, ((f"f{i % 2}", "v"),))
+            return s.op_hmset(k, (("f0", "v"), (f"g{i % 4}", "v")))
+
+        warm = [op(i) for i in range(10)]
+        ops_a = [op(i) for i in range(24)] + warm[:3]   # dup retries
+        ops_b = [op(i, "n") for i in range(5, 21)]     # fresh keys
+        masters = (1, 1, 1, 2)
+        lists = (ops_a, ops_a, ops_b, ops_a)
+
+        def build(cls):
+            gang = WitnessGang(16, 2, n_lanes=4)
+            ws = [cls(16, 2, gang=gang) if cls is DeviceWitness
+                  else cls(16, 2) for _ in masters]
+            for w, m in zip(ws, masters):
+                w.start(master_id=m)
+                w.record_batch(m, warm)
+            return ws
+
+        ws = build(DeviceWitness)
+        reg = telemetry.registry()
+        lanes0 = reg.counter("witness.stacked_lanes").value
+        reset_dispatch_count()
+        got = record_many([(w, 1, ops) for w, ops in zip(ws, lists)])
+        assert dispatch_count() == 1
+        assert reg.counter("witness.stacked_lanes").value - lanes0 == 3
+        reset_dispatch_count()
+        ws2 = build(DeviceWitness)
+        want = [w.record_batch(1, ops) for w, ops in zip(ws2, lists)]
+        py = [w.record_batch(1, ops) for w, ops in zip(build(Witness), lists)]
+        assert got == want == py
+        assert got[3] == [RecordStatus.REJECTED] * len(ops_a)
+        flat = [st for sts in got[:3] for st in sts]
+        assert RecordStatus.ACCEPTED in flat and RecordStatus.REJECTED in flat
+        assert [w._held for w in ws] == [w._held for w in ws2]
+        assert [w.stats for w in ws] == [w.stats for w in ws2]
+        assert ws[3].stats["rejects_mode"] == len(ops_a)
+
     def test_gang_record_one_dispatch_and_bounded_jit_cache(self):
         """Batches of any size are ONE dispatch, and bucket padding keeps
         the jit cache logarithmic in the largest batch seen."""
@@ -940,6 +994,140 @@ class TestFusedClusterBatch:
         assert len(outs) == 12
         for i in range(12):
             assert c.read(s, s.op_get(f"k{i}")).value == "post"
+
+    def test_per_shard_hmset_batch_one_record_dispatch(self):
+        """A one-field HMSET batch over 4 shards declines the fused path and
+        costs exactly ONE record dispatch plus its gc dispatches."""
+        import repro.kernels as K
+
+        c, s = self._mk("device", sync_batch=4)
+        ops = [s.op_hmset(f"h{i}", (("f0", "v"),)) for i in range(32)]
+        assert len({c.shard_of(op.keys[0]) for op in ops}) == 4
+        calls = {"gc": 0, "record": 0}
+        real_gc, real_groups = K.gang_gc, K.gang_record_groups
+
+        def gc(*a, **kw):
+            calls["gc"] += 1
+            return real_gc(*a, **kw)
+
+        def groups(*a, **kw):
+            calls["record"] += 1
+            return real_groups(*a, **kw)
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(K, "gang_gc", gc)
+            mp.setattr(K, "gang_record_groups", groups)
+            reset_dispatch_count()
+            outs = c.update_batch(s, ops)
+            n = dispatch_count()
+            reset_dispatch_count()
+        assert c._fused.stats["declined"] == 1
+        assert calls["record"] == 1 and calls["gc"] > 0
+        assert n == 1 + calls["gc"]
+        assert all(o.fast_path and o.witness_accepts == 3 for o in outs)
+
+    @staticmethod
+    def _drive_per_shard(backend, phased, sync_batch=6):
+        """Mixed batches that decline the fused path (each holds an HMSET),
+        with conflicts, a RIFL retry and a dropped witness, through the
+        phased ``update_batch`` or a shard-by-shard loop of
+        ``ShardGroup.update_batch`` (the per-shard path before stacking)."""
+        import random
+
+        c = ShardedCluster(n_shards=4, f=3, witness_backend=backend, seed=7,
+                           sync_batch=sync_batch,
+                           geometry=WitnessGeometry(256, 4))
+        s = c.new_client()
+        c.shards[2].witness_drop(1)
+        rng_ = random.Random(11)
+        seen, out = [], []
+        for r in range(5):
+            ops = [s.op_hmset(f"h{rng_.randrange(6)}", ((f"f{r % 2}", r),))]
+            for _ in range(15):
+                k = f"k{rng_.randrange(10)}"
+                ops.append(s.op_set(k, f"v{r}") if rng_.random() < .6
+                           else s.op_hmset(k, (("a", r), ("b", r))))
+            if r == 3:
+                ops[1] = seen[2]                # RIFL retry of an old op
+            seen.extend(ops)
+            if phased:
+                res = c.update_batch(s, ops)
+            else:
+                groups = {}
+                for i, op in enumerate(ops):
+                    groups.setdefault(c.shard_of(op.keys[0]), []).append(i)
+                res = [None] * len(ops)
+                for sid, idxs in groups.items():
+                    for i, o in zip(idxs, c.shards[sid].update_batch(
+                            s.session_for(sid), [ops[i] for i in idxs])):
+                        res[i] = o
+            out += [(o.value, o.rtts, o.fast_path, o.synced_path,
+                     o.witness_accepts) for o in res]
+        hist = [(h["op"].rpc_id, h["value"], h["invoke"]) for h in c.history]
+        return c, out, hist
+
+    def test_per_shard_path_matches_shard_loop_and_python_backend(self):
+        """The phased per-shard path gives the outcomes and history of the
+        shard-by-shard loop, on both backends, and the backends agree."""
+        runs = {(b, p): self._drive_per_shard(b, p)
+                for b in ("device", "python") for p in (True, False)}
+        (_c, out, hist) = runs[("python", False)]
+        assert any(not o[2] for o in out) and any(o[2] for o in out)
+        for (b, p), (c, o, h) in runs.items():
+            assert o == out, (b, p)
+            assert h == hist, (b, p)
+        cd = runs[("device", True)][0]
+        assert cd._fused.stats["fused_batches"] == 0
+        for sid in range(4):
+            assert cd.shards[sid].master.store.snapshot() == \
+                runs[("python", False)][0].shards[sid].master.store.snapshot()
+
+    @pytest.mark.parametrize("backend", ["python", "device"])
+    def test_per_shard_batch_resolves_orphaned_txn_lock(self, backend):
+        """A shard whose master round hits an orphaned txn lock: the shards
+        begun before it finish, the txn is resolved, and the batch runs on
+        from that shard, with the outcomes and history of resolving and
+        re-running shard by shard."""
+        from repro.core.txn import participant_state, prepare_op
+
+        def drive(phased):
+            c = ShardedCluster(n_shards=4, f=3, witness_backend=backend,
+                               seed=7, geometry=WitnessGeometry(256, 4))
+            s = c.new_client()
+            keys = {}
+            for i in range(400):
+                keys.setdefault(c.shard_of(f"t{i}"), []).append(f"t{i}")
+            spec = s.txn_spec([(keys[2][0], "x"), (keys[3][0], "y")])
+            p0 = [p for p in spec.parts if p.shard_id == 2][0]
+            assert c.shards[2].txn_prepare(s.session_for(2),
+                                           prepare_op(spec, p0)).granted
+            ops = [s.op_hmset(keys[sid][j], (("f", j),))
+                   for sid in (1, 2, 0, 3) for j in range(1, 4)]
+            ops.insert(5, s.op_set(keys[2][0], "after"))   # locked key
+            if phased:
+                res = c.update_batch(s, ops)
+            else:
+                groups = {}
+                for i, op in enumerate(ops):
+                    groups.setdefault(c.shard_of(op.keys[0]), []).append(i)
+                res = [None] * len(ops)
+                for sid, idxs in groups.items():
+                    sub = s.session_for(sid)
+                    done = c._with_txn_resolution(
+                        lambda sid=sid, sub=sub, idxs=idxs:
+                        c.shards[sid].update_batch(
+                            sub, [ops[i] for i in idxs]))
+                    for i, o in zip(idxs, done):
+                        res[i] = o
+            assert participant_state(c.shards[2].master, spec, p0) \
+                == "aborted"
+            assert c.read(s, s.op_get(keys[2][0])).value == "after"
+            return ([(o.value, o.rtts, o.fast_path, o.synced_path,
+                      o.witness_accepts) for o in res],
+                    [(h["op"].rpc_id, h["value"], h["invoke"])
+                     for h in c.history])
+
+        assert drive(True) == drive(False)
 
     def test_fused_respects_dropped_witness(self):
         c, s = self._mk("device")

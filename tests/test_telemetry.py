@@ -436,8 +436,9 @@ class TestProfilerSpans:
 
     def test_per_shard_path_spans(self, tmp_path):
         """A one-field HMSET batch declines the fused path: its preflight,
-        then the per-shard grouping, then per shard the master rounds, one
-        record and settle per witness, the classification and the drain."""
+        then the per-shard grouping, the master rounds of every shard, ONE
+        record and settle for every witness of every shard, then per shard
+        the classification and the drain."""
         cl, s = _small_cluster()
         cl.update_batch(s, [s.op_hmset(f"w{i}", (("f0", "v"),))
                             for i in range(16)])
@@ -445,16 +446,18 @@ class TestProfilerSpans:
         spans = _spans(tmp_path, lambda: cl.update_batch(s, ops))
         assert cl._fused.stats["fused_batches"] == 0
         names = _phases_after_root(spans)
+        n_shards = len({cl.shard_of(op.keys[0]) for op in ops})
         assert names[:2] == ["preflight", "preflight"]
-        per_shard = ["master"] + ["record", "settle"] * 2 + ["master"]
-        rest, shards = names[2:], 0
+        assert names[2:4 + n_shards] == \
+            ["master"] * n_shards + ["record", "settle"]
+        rest, shards = names[4 + n_shards:], 0
         while rest:
-            assert rest[:len(per_shard)] == per_shard
-            rest = rest[len(per_shard):]
+            assert rest[0] == "master"
+            rest = rest[1:]
             while rest[:2] == ["sync", "gc"]:
                 rest = rest[2:]
             shards += 1
-        assert shards == len({cl.shard_of(op.keys[0]) for op in ops})
+        assert shards == n_shards
 
     def test_disabled_emits_no_spans(self, tmp_path):
         cl, s = _small_cluster()
@@ -467,6 +470,30 @@ class TestProfilerSpans:
         finally:
             telemetry.enable()
         assert spans == []
+
+
+# ---------------------------------------------------------------------------
+# stacked-record counters of the per-shard path
+# ---------------------------------------------------------------------------
+class TestStackedRecordCounters:
+    def test_redis_shaped_call_counts_one_stacked_record(self):
+        """One call of one-field HMSETs over 4 shards at f=2 declines the
+        fused path and records in ONE stacked dispatch over all 4*f lanes."""
+        from repro.core import WitnessGeometry
+
+        f = 2
+        cl = ShardedCluster(n_shards=4, f=f, witness_backend="device",
+                            geometry=WitnessGeometry(64, 4))
+        s = cl.new_client()
+        ops = [s.op_hmset(f"user{i}", (("field0", "v"),)) for i in range(32)]
+        assert len({cl.shard_of(op.keys[0]) for op in ops}) == 4
+        names = ("witness.stacked_records", "witness.stacked_lanes")
+        reg = telemetry.registry()
+        before = [reg.counter(n).value for n in names]
+        cl.update_batch(s, ops)
+        assert cl._fused.stats["declined"] == 1
+        assert [reg.counter(n).value - b for n, b in zip(names, before)] \
+            == [1, 4 * f]
 
 
 # ---------------------------------------------------------------------------
