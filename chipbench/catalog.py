@@ -1,9 +1,14 @@
 """Resolve a cell of ``BENCHMARK.json`` by name: its configuration file, its
-traffic file, the generator its traffic names, and the reader of every
-metric it reports.  Everything is found by name, so a new configuration,
-traffic mix or metric is new files plus new entries, and no edit here.
+record kind, its traffic file, the generator its traffic names, and the
+reader of every metric it reports.  Everything is found by name, so a new
+configuration, record kind, traffic mix or metric is new files plus new
+entries, and no edit here.
 
 * configuration: the file ``configs[].file`` names;
+* record kind ``<kind>`` (the configuration's ``record.kind``):
+  ``chipbench/kinds/<kind>.py`` (a ``Kind`` class: what a request is, how it
+  is loaded, warmed up, sent, read back and replayed by the reference, and
+  the pairs it records at each witness);
 * traffic ``<mix>``: ``chipbench/traffic/<mix>.json``, whose ``kind`` names
   the generator ``chipbench/traffic/<kind>.py`` (a ``Generator`` class);
 * metric ``<name>``: ``chipbench/metrics/<name>.py`` (a ``read(run)``
@@ -35,6 +40,7 @@ class Cell:
     name: str
     chips: int
     cfg: dict
+    kind: object
     traffic: dict
     generator: type
     end_to_end: List[dict]
@@ -72,6 +78,8 @@ class Catalog:
         if config not in configs:
             raise KeyError(f"no configuration {config!r} in BENCHMARK.json")
         cfg = json.loads((self.root / configs[config]["file"]).read_text())
+        rk = cfg["record"]["kind"]
+        kind = _module(HERE / "kinds" / f"{rk}.py", f"kind_{rk}").Kind()
         traffic_file = HERE / "traffic" / f"{mix}.json"
         if not traffic_file.is_file():
             raise FileNotFoundError(f"no traffic file for {mix!r}")
@@ -84,7 +92,7 @@ class Catalog:
         layer = [m for m in self.bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
-        return Cell(name, chips, cfg, traffic, gen, e2e, layer)
+        return Cell(name, chips, cfg, kind, traffic, gen, e2e, layer)
 
     def cells(self) -> List[str]:
         return [w["name"] for w in self.bench["workloads"]]

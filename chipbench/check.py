@@ -1,8 +1,8 @@
 """The comparison that decides ``correct``.
 
-The reference (``reference.py``) replays the window's requests in the order
-the benchmark sent them.  Four numbers are compared, each an exact count with
-the limit 0:
+The record kind's reference (a subclass of ``reference.Reference``) replays
+the window's requests in the order the benchmark sent them.  Four numbers are
+compared, each an exact count with the limit 0:
 
 * ``outcome_mismatches``: acknowledged updates whose outcome (value, fast
   path, synced path, round trips, witness accepts) differs from the
@@ -16,7 +16,7 @@ the limit 0:
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 from chipbench.reference import Reference
 
@@ -24,11 +24,18 @@ LIMITS = {"outcome_mismatches": 0, "read_mismatches": 0,
           "replica_mismatches": 0, "unacknowledged": 0}
 
 
-def replay(cfg: dict, snapshot: Optional[Dict[str, Any]], actions: List[Tuple]
-           ) -> Reference:
-    """Run the reference over a window's actions; returns it with, for every
-    action, its expected rows (batches) or value (reads) in ``ref.expected``."""
-    ref = Reference(cfg, snapshot)
+def written(kind, actions: List[Tuple]) -> Set[str]:
+    """Every key the window's update batches wrote."""
+    return set().union(*(kind.written(act[1]) for act in actions
+                         if act[0] == "batch"))
+
+
+def replay(kind, cfg: dict, snapshot: Optional[Dict[str, Any]],
+           actions: List[Tuple]) -> Reference:
+    """Run the kind's reference over a window's actions; returns it with, for
+    every action, its expected rows (batches) or value (reads) in
+    ``ref.expected``."""
+    ref = kind.Reference(cfg, snapshot)
     ref.expected = []
     for act in actions:
         if act[0] == "read":
@@ -39,8 +46,11 @@ def replay(cfg: dict, snapshot: Optional[Dict[str, Any]], actions: List[Tuple]
 
 
 def compare(ref: Reference, actions: List[Tuple], replicas: Dict[str, List[Any]],
-            attempted: int, acknowledged: int) -> Dict[str, Tuple[int, int]]:
-    """Each number compared with its limit: ``{name: (value, limit)}``."""
+            keys: Set[str], attempted: int, acknowledged: int
+            ) -> Dict[str, Tuple[int, int]]:
+    """Each number compared with its limit: ``{name: (value, limit)}``;
+    ``keys`` are the keys the window wrote, each of which ``replicas`` must
+    hold."""
     outcome = reads = 0
     for act, want in zip(actions, ref.expected):
         if act[0] == "read":
@@ -51,8 +61,7 @@ def compare(ref: Reference, actions: List[Tuple], replicas: Dict[str, List[Any]]
             outcome += abs(len(got) - len(want))
     replica = sum(v != ref.values.get(k)
                   for k, vals in replicas.items() for v in vals)
-    written = {k for act in actions if act[0] == "batch" for k, _f, _v in act[1]}
-    replica += len(written - set(replicas))
+    replica += len(keys - set(replicas))
     nums = {"outcome_mismatches": outcome, "read_mismatches": reads,
             "replica_mismatches": replica,
             "unacknowledged": attempted - acknowledged}

@@ -29,7 +29,6 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT)]
 
 from chipbench import catalog, check, loops  # noqa: E402
-from chipbench.reference import Reference  # noqa: E402
 
 
 @dataclass
@@ -42,26 +41,21 @@ class Outcome:
 
 
 class ControlCluster:
-    """The reference, answering through the program's client interface."""
+    """The cell's record kind with its reference, broken, in the program's
+    place: update requests go straight to the reference, and reads answer
+    through the program's client interface."""
 
-    def __init__(self, cfg: dict, snapshot) -> None:
-        self.ref = Reference(cfg, snapshot, fault="no_master_sync")
+    def __init__(self, kind, cfg: dict, snapshot) -> None:
+        self.ref = kind.Reference(cfg, snapshot, fault="no_master_sync")
 
     def new_client(self):
         return self
 
-    def op_set(self, key, value):
-        return (key, None, value)
-
-    def op_hmset(self, key, fields):
-        ((field, value),) = fields
-        return (key, field, value)
+    def send(self, _cluster, _session, reqs, _span):
+        return self.ref.update_batch(reqs)
 
     def op_get(self, key):
         return key
-
-    def update_batch(self, _session, ops):
-        return [Outcome(*row) for row in self.ref.update_batch(ops)]
 
     def read(self, _session, key):
         return Outcome(True, False, 1, 0, self.ref.read(key))
@@ -74,18 +68,17 @@ def run(cell, seed: int, seconds: float) -> dict:
     if traffic["load"]:
         keys, values = gen.snapshot()
         base = dict(zip(keys, values))
-    cl = ControlCluster(cfg, base)
-    server = loops.Server(cl, cfg, loops.no_span)
+    cl = ControlCluster(cell.kind, cfg, base)
+    server = loops.Server(cl, cl, loops.no_span)
     if traffic["loop"] == "closed":
         w = loops.closed_loop(server, gen, seconds)
     else:
         due, reqs = gen.schedule(seconds)
         w = loops.open_loop(server, due, reqs, traffic["batch"])
-    written = {k for act in w.actions if act[0] == "batch"
-               for k, _f, _v in act[1]}
+    written = check.written(cell.kind, w.actions)
     replicas = {k: [cl.ref.values.get(k)] * (cfg["f"] + 1) for k in written}
-    ref = check.replay(cfg, base, w.actions)
-    nums = check.compare(ref, w.actions, replicas, w.attempted,
+    ref = check.replay(cell.kind, cfg, base, w.actions)
+    nums = check.compare(ref, w.actions, replicas, written, w.attempted,
                          w.acknowledged)
     return {"seed": seed, "correct": check.passed(nums),
             "updates": len(w.fast),

@@ -3,9 +3,11 @@ served path, recording everything the checks and the metrics need.
 
 Server semantics, shared by both loops: each turn takes every request that
 is due, serves its reads one by one through ``ShardedCluster.read`` (the only
-read path), then sends up to ``batch`` of its updates through one
-``ShardedCluster.update_batch`` call.  ``actions`` keeps what was sent, in
-order, with what came back, so the reference can replay it exactly.
+read path), then hands up to ``batch`` of its updates to the record kind's
+``send``, which serves them through the program's entry point (for key-value
+records one ``ShardedCluster.update_batch`` call).  ``actions`` keeps the
+requests as the generator made them, in order, with what came back, so the
+kind's reference can replay them exactly.
 """
 from __future__ import annotations
 
@@ -16,8 +18,6 @@ from dataclasses import dataclass, field
 from typing import Callable, List, Tuple
 
 import numpy as np
-
-from chipbench import deploy
 
 
 @dataclass
@@ -34,16 +34,12 @@ class Window:
     lateness_s: float = 0.0
 
 
-def _row(o):
-    return (o.fast_path, o.synced_path, o.rtts, o.witness_accepts, o.value)
-
-
 class Server:
     """One serving turn: reads, then one update batch."""
 
-    def __init__(self, cluster, cfg: dict, span: Callable) -> None:
+    def __init__(self, cluster, kind, span: Callable) -> None:
         self.cl = cluster
-        self.cfg = cfg
+        self.kind = kind
         self.s = cluster.new_client()
         self.span = span
 
@@ -56,18 +52,13 @@ class Server:
                 w.reads += 1
 
     def updates(self, w: Window, reqs, t0: float) -> float:
-        with self.span("bench.make_ops"):
-            ops = [deploy.update_op(self.s, self.cfg, k, f, v)
-                   for _op, k, f, v in reqs]
         ts = time.perf_counter()
-        with self.span("bench.update_batch"):
-            out = self.cl.update_batch(self.s, ops)
+        rows = self.kind.send(self.cl, self.s, reqs, self.span)
         te = time.perf_counter()
-        w.batch_spans.append((ts - t0, te - t0, len(ops)))
-        w.actions.append(("batch", [(k, f, v) for _op, k, f, v in reqs],
-                          [_row(o) for o in out]))
-        w.acknowledged += len(out)
-        w.fast.extend(o.fast_path for o in out)
+        w.batch_spans.append((ts - t0, te - t0, len(reqs)))
+        w.actions.append(("batch", reqs, rows))
+        w.acknowledged += len(rows)
+        w.fast.extend(r[0] for r in rows)
         return te - t0
 
 

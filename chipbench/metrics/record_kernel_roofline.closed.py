@@ -12,6 +12,6 @@ def read(run):
     kernel_s = t.kernel(trace.RECORD_MODULES) / 1e9 if t is not None else 0.0
     if not kernel_s:
         return None
-    items = roofline.record_items(run.cfg, run.window)
+    items = roofline.record_items(run.kind, run.cfg, run.window)
     need_s = roofline.record_bytes(run.cfg, items) / run.peaks["hbm_bytes_per_s"]
     return 100.0 * need_s / kernel_s

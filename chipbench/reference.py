@@ -4,7 +4,10 @@ Written from the protocol (CURP, arXiv 1710.09921, §3-§4) and the
 configuration's stated rules, and independent of the code under test: it
 imports nothing of ``repro`` and takes nothing the program made.  It replays
 the exact requests the benchmark sent, in the order it sent them, and gives
-what every acknowledgement must say:
+what every acknowledgement must say.  What a request records and does is its
+record kind's: each ``chipbench/kinds/<kind>.py`` subclasses ``Reference``
+with the request's (hash, class) pairs and its effect on the values.  The
+rest is the deployment's, shared by every kind:
 
 * key placement: a 64-bit key hash (FNV-1a over the key's UTF-8 bytes, then
   the SplitMix64 finaliser), mixed into two 32-bit lanes by the murmur3
@@ -122,11 +125,6 @@ def shard_of_np(keys: Sequence[str], masters: int, slots: int) -> np.ndarray:
     return ((h3 % np.uint32(slots)) % np.uint32(masters)).astype(np.int64)
 
 
-def field_subkey(key: str, field: str) -> str:
-    """The derived per-field key an HMSET records besides its base key."""
-    return f"{key!r}\x1fhf\x1f{field!r}"
-
-
 class _Witness:
     def __init__(self, n_sets: int, n_ways: int) -> None:
         self.n_sets, self.n_ways = n_sets, n_ways
@@ -187,7 +185,8 @@ class _Master:
 
 class Reference:
     """The deployment's plain model: one dict of values and, per master, its
-    unsynced window and its witnesses' tables."""
+    unsynced window and its witnesses' tables.  A record kind's subclass
+    gives ``pairs`` and ``apply`` of its requests."""
 
     def __init__(self, cfg: dict, snapshot: Optional[Dict[str, Any]] = None,
                  fault: Optional[str] = None) -> None:
@@ -197,7 +196,6 @@ class Reference:
         self.masters = cfg["masters"]
         self.slots = cfg["slots"]
         self.sync_batch = cfg["sync_batch"]
-        self.record = cfg["record"]["kind"]
         self.fault = fault
         self.values: Dict[str, Any] = dict(snapshot or {})
         self.m = [_Master(self.f, w["sets"], w["ways"])
@@ -214,35 +212,27 @@ class Reference:
     def shard_of(self, key: str) -> int:
         return (mixed_lo(self._hash(key)) % self.slots) % self.masters
 
-    def _pairs(self, key: str, field: Optional[str]):
-        if self.record == "object":
-            return ((self._hash(key), CLS_SET),)
-        return ((self._hash(key), CLS_HMSET),
-                (self._hash(field_subkey(key, field)), CLS_FIELD))
+    def pairs(self, req) -> Tuple[Tuple[int, int], ...]:
+        """The (hash, class) pairs ``req`` records at each witness."""
+        raise NotImplementedError
 
-    def _apply(self, key: str, field: Optional[str], value: str) -> str:
-        if self.record == "object":
-            self.values[key] = value
-        else:
-            cur = self.values.get(key)
-            h = dict(cur) if isinstance(cur, dict) else {}
-            h[field] = value
-            self.values[key] = h
-        return "OK"
+    def apply(self, req) -> Any:
+        """Apply ``req`` to ``values``; returns what its reply says."""
+        raise NotImplementedError
 
-    def update_batch(self, updates: Sequence[Tuple[str, Optional[str], str]]
-                     ) -> List[Row]:
-        """Updates ``(key, field, value)`` sent as one batch; one row each."""
+    def update_batch(self, updates: Sequence) -> List[Row]:
+        """Update requests ``(op, key, field, value)``, as the generator made
+        them, sent as one batch; one row each."""
         rows: List[Row] = []
         touched: Dict[int, bool] = {}
-        for key, field, value in updates:
-            sid = self.shard_of(key)
+        for req in updates:
+            sid = self.shard_of(req[1])
             m = self.m[sid]
-            pairs = self._pairs(key, field)
+            pairs = self.pairs(req)
             self._tag += 1
             accepts = sum(w.record(pairs, self._tag) for w in m.witnesses)
             commutes = self.fault is not None or m.commutes(pairs)
-            result = self._apply(key, field, value)
+            result = self.apply(req)
             m.unsynced.append((pairs, self._tag))
             for kh, cls in pairs:
                 m.window.setdefault(kh, Counter())[cls] += 1

@@ -15,14 +15,13 @@ WORD = 4
 ITEM_WORDS_IN, ITEM_WORDS_OUT = 7, 1
 
 
-def pairs_per_update(cfg: dict) -> int:
-    return 1 if cfg["record"]["kind"] == "object" else 2
-
-
-def record_items(cfg: dict, window) -> int:
-    """Items recorded by the window's update batches."""
-    updates = sum(n for _a, _b, n in window.batch_spans)
-    return updates * pairs_per_update(cfg) * cfg["f"]
+def record_items(kind, cfg: dict, window) -> int:
+    """Items recorded by the window's update batches: the (hash, class) pairs
+    of every request, as its record kind counts them, at each of f
+    witnesses."""
+    pairs = sum(kind.pairs(r) for act in window.actions if act[0] == "batch"
+                for r in act[1])
+    return pairs * cfg["f"]
 
 
 def record_bytes(cfg: dict, items: int) -> int:

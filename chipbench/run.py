@@ -3,16 +3,17 @@
 
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell (``BENCHMARK.json``) names a deployment and a traffic mix.  The run
-builds the deployment's ``ShardedCluster`` on the device witness backend,
+The cell (``BENCHMARK.json``) names a deployment and a traffic mix; the
+deployment's record kind (``chipbench/kinds/``) says what a request is.  The
+run builds the deployment's ``ShardedCluster`` on the device witness backend,
 warms up the shapes the traffic reaches on a scratch cluster of the same
 deployment, installs the loaded records where the traffic needs them, then
-drives the served path (``update_batch``, ``read``) for ``--seconds``.  With
-``--trace 1`` the window runs under the profiler and the run reports the
+drives the served path (the kind's ``send``, ``read``) for ``--seconds``.
+With ``--trace 1`` the window runs under the profiler and the run reports the
 cell's per-layer metrics; otherwise its end-to-end metrics.
 
 After the window it syncs every master, reads every acknowledged key back
-from its master and backups, and replays the window through the plain
+from its master and backups, and replays the window through the kind's plain
 reference (``reference.py``); ``correct`` holds when every number compared is
 within its limit (``check.py``).  Those numbers are the last lines on
 standard error and the last key of the result line, the last line on
@@ -57,6 +58,7 @@ def log(*a) -> None:
 class RunRecord:
     """What a metric reader reads."""
     cfg: dict
+    kind: Any
     traffic: dict
     window: Any
     setup_s: float
@@ -123,21 +125,21 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, *,
 
     from repro.kernels import dispatch_count
 
-    cfg, traffic = cell.cfg, cell.traffic
+    cfg, traffic, kind = cell.cfg, cell.traffic, cell.kind
     gen = cell.generator(traffic, cfg, seed)
-    warm = deploy.warm_up(cfg, traffic, gen)
+    warm = kind.warm_up(cfg, traffic, gen)
     cluster = deploy.build(cfg)
     base = {}
     if traffic["load"]:
         keys, values = gen.snapshot()
-        deploy.install_snapshot(cluster, cfg, keys, values)
+        kind.snapshot(cluster, cfg, keys, values)
         base = dict(zip(keys, values))
         del keys, values
     if traffic["loop"] == "open":
         due, reqs = gen.schedule(seconds)
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced else None
     span = jax.profiler.TraceAnnotation if traced else loops.no_span
-    server = loops.Server(cluster, cfg, span)
+    server = loops.Server(cluster, kind, span)
     gc.collect()
     setup_s = time.perf_counter() - t_start
     log(f"setup: {setup_s:.3f} s ({warm} warm-up updates; compile cache "
@@ -166,23 +168,23 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, *,
         f"mean arrival-to-take lateness {window.lateness_s * 1e3:.3f} ms")
     log(f"compiles inside the window: {in_window}")
 
-    written = {k for act in window.actions if act[0] == "batch"
-               for k, _f, _v in act[1]}
+    written = check.written(kind, window.actions)
     cluster.sync_all()
-    replicas = deploy.read_back(cluster, cfg, sorted(written), base)
+    replicas = kind.read_back(cluster, cfg, sorted(written), base)
     del cluster, server
     gc.collect()
     t = time.perf_counter()
-    ref = check.replay(cfg, base, window.actions)
-    nums = check.compare(ref, window.actions, replicas, window.attempted,
-                         window.acknowledged)
+    ref = check.replay(kind, cfg, base, window.actions)
+    nums = check.compare(ref, window.actions, replicas, written,
+                         window.attempted, window.acknowledged)
     log(f"reference replay and comparison: {time.perf_counter() - t:.1f} s")
 
     trace = None
     if traced:
         trace = trace_mod.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    rec = RunRecord(cfg, traffic, window, setup_s, dispatches, trace, peaks)
+    rec = RunRecord(cfg, kind, traffic, window, setup_s, dispatches, trace,
+                    peaks)
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         value = catalog.reader(m["name"])(rec)

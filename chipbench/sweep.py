@@ -57,13 +57,13 @@ def main(argv=None) -> int:
         return 2
     cfg, traffic = cell.cfg, cell.traffic
     gen = cell.generator(traffic, cfg, args.seed)
-    deploy.warm_up(cfg, traffic, gen)
+    cell.kind.warm_up(cfg, traffic, gen)
     cluster = deploy.build(cfg)
     if traffic["load"]:
         keys, values = gen.snapshot()
-        deploy.install_snapshot(cluster, cfg, keys, values)
+        cell.kind.snapshot(cluster, cfg, keys, values)
         del keys, values
-    server = loops.Server(cluster, cfg, loops.no_span)
+    server = loops.Server(cluster, cell.kind, loops.no_span)
     log(f"setup {time.perf_counter() - T_START:.1f} s")
     for i, rate in enumerate(args.rates):
         g = cell.generator(dict(traffic, rate=rate), cfg, args.seed + i)

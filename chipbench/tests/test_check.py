@@ -137,22 +137,28 @@ def test_fault_is_not_correct(name, fault, compiles, monkeypatch):
 
 
 def test_compare_counts_each_disagreement():
-    cfg = tiny(CELLS[0]).cfg
-    actions = [("batch", [("user0000001", None, "a"), ("user0000001", None, "b")],
+    cell = tiny(CELLS[0])
+    cfg, kind = cell.cfg, cell.kind
+    actions = [("batch", [("update", "user0000001", None, "a"),
+                          ("update", "user0000001", None, "b")],
                 [(True, False, 1, 3, "OK"), (False, True, 2, 0, "OK")]),
                ("read", "user0000001", "b")]
-    ref = check.replay(cfg, {}, actions)
+    ref = check.replay(kind, cfg, {}, actions)
+    keys = check.written(kind, actions)
+    assert keys == {"user0000001"}
     replicas = {"user0000001": ["b"] * 4}
-    good = check.compare(ref, actions, replicas, 3, 3)
+    good = check.compare(ref, actions, replicas, keys, 3, 3)
     assert check.passed(good), good
     bad_row = [(True, False, 1, 3, "OK"), (True, False, 1, 3, "OK")]
     bad = check.compare(ref, [(actions[0][0], actions[0][1], bad_row),
-                              actions[1]], replicas, 3, 3)
+                              actions[1]], replicas, keys, 3, 3)
     assert bad["outcome_mismatches"][0] == 1
     bad = check.compare(ref, actions, {"user0000001": ["b", "b", "a", "b"]},
-                        3, 3)
+                        keys, 3, 3)
+    assert bad["replica_mismatches"][0] == 1
+    bad = check.compare(ref, actions, {}, keys, 3, 3)
     assert bad["replica_mismatches"][0] == 1
     bad = check.compare(ref, [actions[0], ("read", "user0000001", "a")],
-                        replicas, 3, 2)
+                        replicas, keys, 3, 2)
     assert bad["read_mismatches"][0] == 1 and bad["unacknowledged"][0] == 1
     assert np.isfinite(sum(v for v, _l in bad.values()))

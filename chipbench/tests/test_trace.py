@@ -81,13 +81,13 @@ def test_host_self_time(t):
 
 def test_roofline_arithmetic(t):
     cell = catalog.Catalog().cell("ramcloud16.write_uniform.closed")
-    window = SimpleNamespace(batch_spans=[(i, i + 1, 1024)
-                                          for i in range(BATCHES)])
-    items = roofline.record_items(cell.cfg, window)
+    batch = [("update", f"user{i}", None, "v") for i in range(1024)]
+    window = SimpleNamespace(actions=[("batch", batch, [])] * BATCHES)
+    items = roofline.record_items(cell.kind, cell.cfg, window)
     assert items == BATCHES * 1024 * 3
     assert roofline.record_bytes(cell.cfg, items) == items * (6 * 4 * 4 + 6 * 4
                                                               + 8 * 4)
-    run = SimpleNamespace(trace=t, cfg=cell.cfg, window=window,
+    run = SimpleNamespace(trace=t, cfg=cell.cfg, kind=cell.kind, window=window,
                           peaks={"hbm_bytes_per_s": 819e9})
     share = catalog.reader("record_kernel_roofline.closed")(run)
     want = 100 * items * 152 / 819e9 / (t.kernel(trace.RECORD_MODULES) / 1e9)
