@@ -459,23 +459,54 @@ def _pack(ops: Sequence[Op], pairs, K: int):
     return khi, klo, kval, kcls, rhi, rlo
 
 
+def _split(sizes: Sequence[int], widths: Sequence[np.ndarray],
+           fits) -> int:
+    """The fewest dispatches ``n`` into which the jobs' op lists (``sizes``,
+    per-op pair counts ``widths``) split so that every dispatch fits:
+    dispatch ``c`` takes ops ``[len*c//n, len*(c+1)//n)`` of every list,
+    which keeps each witness's ops in order."""
+    total = sum(sizes)
+    n = 1
+    while True:
+        ok = True
+        for c in range(n):
+            g = k = 0
+            for size, wid in zip(sizes, widths):
+                a, b = size * c // n, size * (c + 1) // n
+                if b > a:
+                    g += b - a
+                    k = max(k, int(wid[a:b].max()))
+            if g and not fits(g, k):
+                ok = False
+                break
+        if ok or n >= total:
+            return n
+        n += 1
+
+
 def record_many(
     jobs: Sequence[Tuple[DeviceWitness, int, List[Op]]],
 ) -> List[List[RecordStatus]]:
-    """Record each job's ops at its witness, every job in ONE dispatch.
+    """Record each job's ops at its witness, every job in ONE dispatch when
+    it fits the kernel's SMEM.
 
     ``jobs`` are (witness, master_id, ops) whose witnesses share one gang;
     ops resolve all-or-nothing per op, in op order within each witness.
     Witness lanes never overlap, so one stacked dispatch makes the decisions
     one dispatch per job would.  Each distinct op list is packed once and
-    repeated across its witnesses; all-single-pair batches go through
-    ``gang_record`` with per-item lanes, any multi-pair op sends the whole
-    dispatch through ``gang_record_groups``.  A witness whose mode or master
-    does not match rejects its ops (``rejects_mode``) and takes no part.
-    Packing through the kernel's results is one ``record`` span, the fold
-    into statuses one ``settle`` span.  Returns statuses per job, in order.
+    repeated across its witnesses; a dispatch of single-pair ops goes
+    through ``gang_record`` with per-item lanes, one with any multi-pair op
+    through ``gang_record_groups`` padded to its own widest op.  A batch
+    whose work items overflow SMEM (``repro.kernels.record_fits``) splits
+    into the fewest dispatches that fit, each taking a contiguous run of
+    every job's ops, so every witness still records its ops in order and
+    the statuses and table equal one dispatch's.  A witness whose mode or
+    master does not match rejects its ops (``rejects_mode``) and takes no
+    part.  Packing through the kernels' results is one ``record`` span, the
+    fold into statuses one ``settle`` span.  Returns statuses per job, in
+    order.
     """
-    from repro.kernels import gang_record, gang_record_groups
+    from repro.kernels import gang_record, gang_record_groups, record_fits
 
     out: List[Optional[List[RecordStatus]]] = []
     live = []
@@ -493,41 +524,64 @@ def record_many(
     gang = live[0][1].gang
     assert all(w.gang is gang for _j, w, _ops in live), \
         "witnesses must share a gang"
+    reg = telemetry.registry()
     with telemetry.span("record"):
         lists = {id(ops): ops for _j, _w, ops in live}
         pairs = {key: [op.hash_classes() for op in ops]
                  for key, ops in lists.items()}
-        K = max(len(p) for ps in pairs.values() for p in ps)
+        widths = {key: np.fromiter(map(len, ps), np.int64, len(ps))
+                  for key, ps in pairs.items()}
+        K = max(int(wd.max()) for wd in widths.values())
         packed = {key: _pack(ops, pairs[key], K)
                   for key, ops in lists.items()}
-        khi, klo, kval, kcls, rhi, rlo = (
-            np.concatenate([packed[id(ops)][i] for _j, _w, ops in live])
-            for i in range(6))
-        lanes = np.repeat(
-            np.fromiter((w.lane for _j, w, _ops in live), np.int32, len(live)),
-            [len(ops) for _j, _w, ops in live])
-        if K == 1:
-            rsn, qh, ql, table = gang_record(
-                gang.table, gang.n_sets, khi[:, 0], klo[:, 0], lanes, rhi,
-                rlo, kcls[:, 0])
-            qh, ql = qh[:, None], ql[:, None]
-        else:
-            rsn, qh, ql, table = gang_record_groups(
-                gang.table, gang.n_sets, khi, klo, kval, lanes, rhi, rlo, kcls)
-        gang.table = table
-        telemetry.registry().counter("witness.stacked_records").inc()
-        telemetry.registry().counter("witness.stacked_lanes").inc(len(live))
+        sizes = [len(ops) for _j, _w, ops in live]
+        n = _split(sizes, [widths[id(ops)] for _j, _w, ops in live],
+                   record_fits)
+        chunks = []
+        for c in range(n):
+            runs = [(size * c // n, size * (c + 1) // n) for size in sizes]
+            if all(b == a for a, b in runs):
+                continue
+            kc = max(int(widths[id(ops)][a:b].max())
+                     for (_j, _w, ops), (a, b) in zip(live, runs) if b > a)
+            khi, klo, kval, kcls, rhi, rlo = (
+                np.concatenate([packed[id(ops)][i][a:b]
+                                for (_j, _w, ops), (a, b) in zip(live, runs)])
+                for i in range(6))
+            lanes = np.repeat(
+                np.fromiter((w.lane for _j, w, _ops in live), np.int32,
+                            len(live)),
+                [b - a for a, b in runs])
+            if kc == 1:
+                rsn, qh, ql, table = gang_record(
+                    gang.table, gang.n_sets, khi[:, 0], klo[:, 0], lanes,
+                    rhi, rlo, kcls[:, 0])
+                qh, ql = qh[:, None], ql[:, None]
+            else:
+                rsn, qh, ql, table = gang_record_groups(
+                    gang.table, gang.n_sets, khi[:, :kc], klo[:, :kc],
+                    kval[:, :kc], lanes, rhi, rlo, kcls[:, :kc])
+            gang.table = table
+            reg.counter("witness.record_dispatches").inc()
+            chunks.append((runs, rsn, qh, ql))
+        reg.counter("witness.stacked_records").inc()
+        reg.counter("witness.stacked_lanes").inc(len(live))
     with telemetry.span("settle"):
-        rsn, qh, ql = rsn.tolist(), qh.tolist(), ql.tolist()
-        at = 0
-        for j, w, ops in live:
+        for _j, w, _ops in live:
             w.stats["kernel_batches"] += 1
-            ps = pairs[id(ops)]
-            out[j] = [
-                w._settle(rsn[at + g],
-                          list(zip(qh[at + g][:len(p)], ql[at + g][:len(p)])),
-                          op.rpc_id, op, [c for _kh, c in p])
-                for g, (op, p) in enumerate(zip(ops, ps))
-            ]
-            at += len(ops)
+        done: Dict[int, List[RecordStatus]] = {j: [] for j, _w, _o in live}
+        for runs, rsn, qh, ql in chunks:
+            rsn, qh, ql = rsn.tolist(), qh.tolist(), ql.tolist()
+            at = 0
+            for (j, w, ops), (a, b) in zip(live, runs):
+                ps = pairs[id(ops)]
+                done[j].extend(
+                    w._settle(rsn[at + g - a],
+                              list(zip(qh[at + g - a][:len(ps[g])],
+                                       ql[at + g - a][:len(ps[g])])),
+                              ops[g].rpc_id, ops[g], [c for _kh, c in ps[g]])
+                    for g in range(a, b))
+                at += b - a
+        for j, statuses in done.items():
+            out[j] = statuses
     return out  # type: ignore[return-value]

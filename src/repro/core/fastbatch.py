@@ -42,6 +42,7 @@ import numpy as np
 from . import telemetry
 from .client import Decision
 from .master import DUP, ERROR, SYNCED
+from .shard import hash_tag
 from .types import Op, OpType, RecordStatus, WitnessMode
 
 _M32 = 0xFFFFFFFF
@@ -232,6 +233,10 @@ class FusedBatchDriver:
             return None
         for op in ops:
             if op.op_type not in _PLAIN_UPDATES or len(op.keys) != 1:
+                return None
+            if hash_tag(op.keys[0]) is not None:
+                # The kernel routes by the whole key's hash, the router by
+                # the tag's.
                 return None
             if len(op.hash_classes()) != 1:
                 # HMSET with fields fans out to FIELD sub-pairs; the fused

@@ -234,7 +234,7 @@ class Master:
         # conflict path — the caller must resolve the transaction (or wait
         # for its coordinator) and retry.  ExecResult.value carries the
         # blocking TxnSpec for exactly that.
-        blocking = self.store.txn_lock_conflict(op.keys)
+        blocking = self.store.txn_lock_conflict(op.keys, op=op)
         if blocking is not None:
             return ERROR, ExecResult(blocking, synced=False, ok=False,
                                      error="TXN_PENDING")
@@ -339,7 +339,8 @@ class Master:
                 self.stats["txn_vote_no"] += 1
                 return ERROR, ExecResult(dec.result, synced=False, ok=False,
                                          error="TXN_DECIDED")
-            blocking = self.store.txn_lock_conflict(op.keys, spec.txn_id)
+            blocking = self.store.txn_lock_conflict(op.keys, spec.txn_id,
+                                                    op=op)
             if blocking is not None:
                 self.stats["txn_vote_no"] += 1
                 return ERROR, ExecResult(blocking, synced=False, ok=False,

@@ -35,7 +35,13 @@ cls   op       merge rule
 6     APPEND   commutes under the canonical sorted-chunks value
 7     MAX      max commutes and is idempotent
 8     OTHER    conservative catch-all (reads, TXN legs, migration ops)
+9     READ     a stored procedure's read of a key (repro.core.txn): READ ||
+               READ commutes, READ conflicts with every write class
 ====  =======  ==========================================================
+
+A stored-procedure transaction leg declares each key's class itself (READ,
+INCR for an addition, SET for a read-modify-write); other transaction legs
+keep the conservative OTHER.
 
 ``CONFLICT_MATRIX[a]`` is a 16-bit row: bit ``b`` set iff class ``a``
 conflicts with class ``b``.  The matrix is built FROM ``MERGEABLE`` —
@@ -62,10 +68,12 @@ CLS_SADD = 5
 CLS_APPEND = 6
 CLS_MAX = 7
 CLS_OTHER = 8
+CLS_READ = 9
 N_CLASSES = 16          # matrix rows; headroom for future classes
 
 #: Classes whose ops merge with a concurrent op of the SAME class.
-MERGEABLE = frozenset({CLS_INCR, CLS_HMSET, CLS_SADD, CLS_APPEND, CLS_MAX})
+MERGEABLE = frozenset({CLS_INCR, CLS_HMSET, CLS_SADD, CLS_APPEND, CLS_MAX,
+                       CLS_READ})
 
 #: Bit c set iff class c is mergeable — the kernels' scalar shortcut.
 MERGE_MASK = 0
@@ -122,14 +130,34 @@ def op_hash_classes(op) -> List[Tuple[int, int]]:
             (keyhash(field_subkey(k, f)), CLS_FIELD) for f, _v in fields
         )
         return pairs
-    # Reads, NOOP, TXN legs, migration ops: conservative — OTHER conflicts
-    # with every class, reproducing the un-widened CURP check exactly.
+    if t in (OpType.TXN, OpType.TXN_PREPARE, OpType.TXN_COMMIT,
+             OpType.TXN_ABORT) and len(op.args) > 1 \
+            and op.args[1] is not None:
+        decl = op.args[0].part_on(op.args[1]).decl
+        if decl:
+            return [(keyhash(k), c) for k, c in decl]
+    # Reads, NOOP, other TXN legs, migration ops: conservative — OTHER
+    # conflicts with every class, reproducing the un-widened CURP check.
     return [(keyhash(k), CLS_OTHER) for k in op.keys]
+
+
+def key_classes(op) -> Tuple[int, ...]:
+    """The class of each of ``op.keys`` (one per key, unlike the pair list:
+    an HMSET's FIELD pairs name derived sub-keys).  What a transaction
+    intent's key lock is checked against."""
+    from .types import OpType
+
+    if op.op_type is OpType.HMSET:
+        return (CLS_HMSET,)
+    pairs = op.hash_classes()
+    if len(pairs) == len(op.keys):
+        return tuple(c for _kh, c in pairs)
+    return (CLS_OTHER,) * len(op.keys)
 
 
 __all__ = [
     "CLS_SET", "CLS_DEL", "CLS_INCR", "CLS_HMSET", "CLS_FIELD",
-    "CLS_SADD", "CLS_APPEND", "CLS_MAX", "CLS_OTHER", "N_CLASSES",
-    "MERGEABLE", "MERGE_MASK", "CONFLICT_MATRIX",
-    "conflicts", "field_subkey", "op_hash_classes",
+    "CLS_SADD", "CLS_APPEND", "CLS_MAX", "CLS_OTHER", "CLS_READ",
+    "N_CLASSES", "MERGEABLE", "MERGE_MASK", "CONFLICT_MATRIX",
+    "conflicts", "field_subkey", "key_classes", "op_hash_classes",
 ]

@@ -41,8 +41,9 @@ records can move.)
 """
 from __future__ import annotations
 
+import functools
 import random
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import telemetry
@@ -60,8 +61,14 @@ from .txn import (
     TxnSpec,
     TxnStatus,
     TxnVote,
+    abort_op,
+    commit_op,
+    forwarded_of,
+    prepare_op,
+    procedure,
     resolve_pending,
     resolve_txn,
+    single_shard_op,
 )
 from .types import ClusterConfig, ExecResult, Op, OpType, RecordStatus, keyhash
 from .witness import Witness
@@ -93,6 +100,29 @@ def mix2x32(hi: int, lo: int) -> Tuple[int, int]:
 N_SLOTS = 256
 
 
+def hash_tag(key: Any) -> Optional[str]:
+    """Redis Cluster's hash tag: the part of a string key between its first
+    ``{`` and the first ``}`` after it, when that part is not empty.  Only
+    the tag is hashed for the key's slot, so keys that share a tag share a
+    master."""
+    if not isinstance(key, str):
+        return None
+    i = key.find("{")
+    if i < 0:
+        return None
+    j = key.find("}", i + 1)
+    if j <= i + 1:
+        return None
+    return key[i + 1:j]
+
+
+@functools.lru_cache(maxsize=1 << 16)
+def _tag_hash(tag: str) -> int:
+    """``keyhash`` of a hash tag: a deployment has few tags, each hashed
+    for every key that carries it."""
+    return keyhash(tag)
+
+
 class SlotRouter:
     """Deterministic key -> shard placement shared by Python and Pallas.
 
@@ -103,7 +133,9 @@ class SlotRouter:
     table (``assign``) and bumps ``version`` so cached placements (e.g. the
     serving store's session cache) know to refetch.  ``repro.kernels.ops.
     shard_route`` computes the same placement batched on-device from the
-    same table.
+    same table.  A key with a hash tag (``hash_tag``) hashes only its tag;
+    the device placement hashes whole keys, so the fused batch path
+    declines tagged keys.
     """
 
     def __init__(self, slot_map: Sequence[int],
@@ -129,7 +161,9 @@ class SlotRouter:
         return h3 % self.n_slots
 
     def slot_of(self, key: Any) -> int:
-        return self.slot_of_hash(keyhash(key))
+        tag = hash_tag(key)
+        return self.slot_of_hash(keyhash(key) if tag is None
+                                 else _tag_hash(tag))
 
     def shard_of_hash(self, kh64: int) -> int:
         return self.slot_map[self.slot_of_hash(kh64)]
@@ -328,15 +362,15 @@ class ShardGroup:
         """One 1-RTT round: update RPC to the master + parallel witness
         records.  Retries internally on stale-config errors (§3.6)."""
         verdict, result, cfg = self._master_round(op, acks, now)
-        statuses: List[RecordStatus] = []
-        for i, w in enumerate(self.witnesses):
-            if i in self._dropped_witnesses:
-                statuses.append(RecordStatus.REJECTED)  # timeout == reject
-            else:
-                statuses.append(
-                    w.record(cfg.master_id, op.key_hashes(), op.rpc_id, op)
-                )
-        return verdict, result, statuses
+        return verdict, result, self._record_at_witnesses(cfg, op)
+
+    def _record_at_witnesses(self, cfg: ClusterConfig,
+                             op: Op) -> List[RecordStatus]:
+        """One op's record at each of the group's witnesses, in parallel in
+        a deployment; a dropped witness rejects (timeout == reject)."""
+        return [RecordStatus.REJECTED if i in self._dropped_witnesses
+                else w.record(cfg.master_id, op.key_hashes(), op.rpc_id, op)
+                for i, w in enumerate(self.witnesses)]
 
     def update(self, session: ClientSession, op: Op, now: float = 0.0):
         """Full CURP update; returns an OpOutcome (see local.py)."""
@@ -411,7 +445,9 @@ class ShardGroup:
                 if verdict == SYNCED or decision is Decision.NEED_SYNC:
                     need_drain = True
                 session.mark_completed(op.rpc_id)
-                if verdict != DUP:   # see update(): dups re-externalize, once
+                # See update(): dups re-externalize, once.  A transaction's
+                # legs enter the history as one transaction (txn()).
+                if verdict != DUP and op.op_type is not OpType.TXN_PREPARE:
                     self.record(op, result.value, session.client_id)
                 outcomes.append(OpOutcome(
                     value=result.value,
@@ -472,13 +508,7 @@ class ShardGroup:
         sync (2 RTTs for this leg only).  A vote NO (foreign intent lock or
         an existing decision tombstone) installs nothing.
         """
-        for _attempt in range(4):
-            cfg = self.config.fetch(self.shard_id)
-            verdict, result = self.master.handle_update(
-                op, cfg.witness_list_version, session.acks(), now
-            )
-            if verdict != ERROR or result.error != "WRONG_WITNESS_VERSION":
-                break
+        verdict, result, cfg = self._leg_round(op, session.acks(), now)
         if verdict == ERROR:
             # TXN_LOCKED carries the blocking spec: the coordinator's
             # wound/wait policy (repro.core.txn) needs the holder's txn_id.
@@ -487,14 +517,7 @@ class ShardGroup:
                 blocking=result.value if result.error == "TXN_LOCKED"
                 else None,
             )
-        statuses: List[RecordStatus] = []
-        for i, w in enumerate(self.witnesses):
-            if i in self._dropped_witnesses:
-                statuses.append(RecordStatus.REJECTED)
-            else:
-                statuses.append(
-                    w.record(cfg.master_id, op.key_hashes(), op.rpc_id, op)
-                )
+        statuses = self._record_at_witnesses(cfg, op)
         decision, rtts, fast = self._classify(verdict, result, statuses)
         if verdict == SYNCED or decision is Decision.NEED_SYNC:
             # Slow path: the intent reaches the backups before the vote is
@@ -510,26 +533,34 @@ class ShardGroup:
             _status, reads = result.value
         return TxnVote(granted=True, fast=fast, rtts=rtts, read_values=reads)
 
-    def txn_decide(self, op: Op,
-                   session: Optional[ClientSession] = None) -> str:
+    def txn_decide(self, op: Op, session: Optional[ClientSession] = None,
+                   drain: bool = True) -> Any:
         """Apply one COMMIT/ABORT leg.  No witness records and no pre-reply
         sync — the decision re-derives from durable prepare state on crash
         (see repro.core.txn).  ``session=None`` is the recovery-resolution
-        path (the coordinator is gone; no acks, no completion marking)."""
+        path (the coordinator is gone; no acks, no completion marking);
+        ``drain=False`` leaves the nudged sync to the caller's batch."""
         acks = session.acks() if session is not None else ()
-        for _attempt in range(4):
-            cfg = self.config.fetch(self.shard_id)
-            verdict, result = self.master.handle_update(
-                op, cfg.witness_list_version, acks, 0.0
-            )
-            if verdict != ERROR:
-                break
+        verdict, result, _cfg = self._leg_round(op, acks, 0.0)
         assert verdict != ERROR, f"decide leg failed: {result.error}"
         if session is not None:
             session.mark_completed(op.rpc_id)
-        if self.auto_sync and self.master.want_sync:
+        if drain and self.auto_sync and self.master.want_sync:
             self._drain_syncs()
         return result.value
+
+    def _leg_round(self, op: Op, acks: Tuple[Tuple[int, int], ...],
+                   now: float) -> Tuple[str, ExecResult, ClusterConfig]:
+        """Master half of a transaction leg: refetches a stale witness list
+        (§3.6) and returns any other error (a vote NO) to the caller."""
+        for _attempt in range(4):
+            cfg = self.config.fetch(self.shard_id)
+            verdict, result = self.master.handle_update(
+                op, cfg.witness_list_version, acks, now
+            )
+            if verdict != ERROR or result.error != "WRONG_WITNESS_VERSION":
+                break
+        return verdict, result, cfg
 
     # ------------------------------------------------------------------ syncs
     def _drain_syncs(self) -> None:
@@ -809,18 +840,29 @@ class ShardedClientSession:
             )
         for k in reads:
             by_shard.setdefault(self.router.shard_of(k), ([], []))[1].append(k)
+        return self.new_txn(
+            TxnPart(shard_id=sid, prepare_rpc=None, decide_rpc=None,
+                    write_kvs=tuple(w), read_keys=tuple(r))
+            for sid, (w, r) in sorted(by_shard.items()))
+
+    def new_txn(self, parts) -> TxnSpec:
+        """A transaction of ``parts`` (legs in shard order) under a fresh
+        txn_id, each leg with fresh RIFL identities (prepare_rpc +
+        decide_rpc), whatever identities the parts carried."""
         self._txn_seq += 1
-        parts = tuple(
-            TxnPart(
-                shard_id=sid,
-                prepare_rpc=self.session_for(sid).next_rpc_id(),
-                decide_rpc=self.session_for(sid).next_rpc_id(),
-                write_kvs=tuple(w),
-                read_keys=tuple(r),
-            )
-            for sid, (w, r) in sorted(by_shard.items())
-        )
-        return TxnSpec(txn_id=(self.client_id, self._txn_seq), parts=parts)
+        return TxnSpec(txn_id=(self.client_id, self._txn_seq), parts=tuple(
+            replace(p, prepare_rpc=self._ids.next_rpc_id(),
+                    decide_rpc=self._ids.next_rpc_id())
+            for p in parts))
+
+    def op_txn(self, spec: TxnSpec) -> Op:
+        """A transaction as one request of ``ShardedCluster.update_batch``:
+        the 1-RTT TXN op of a single-shard transaction, else a TXN op of the
+        whole spec, which the batch serves as 2PC legs."""
+        if len(spec.parts) == 1:
+            return single_shard_op(spec)
+        return Op(OpType.TXN, tuple(k for p in spec.parts for k in p.keys),
+                  (spec, None), spec.parts[0].prepare_rpc)
 
 
 @dataclass
@@ -1046,6 +1088,8 @@ class ShardedCluster:
 
     def _update_batch(self, session: ShardedClientSession, ops: Sequence[Op],
                       now: float = 0.0) -> List["OpOutcome"]:
+        if any(op.op_type is OpType.TXN for op in ops):
+            return self._txn_batch(session, ops, now)
         if self._fused is not None:
             fused = self._fused.try_update_batch(session, ops, now)
             if fused is not None:
@@ -1091,6 +1135,173 @@ class ShardedCluster:
                 seen.add(pend.spec.txn_id)
                 resolve_txn(self, pend.spec)
         return out  # type: ignore[return-value]
+
+    def _txn_batch(self, session: ShardedClientSession, ops: Sequence[Op],
+                   now: float) -> List["OpOutcome"]:
+        """A batch holding transactions (``op_txn``), served in rounds.
+
+        Each round, in request order, runs every pending request's master
+        round: a single-shard transaction's TXN op, each PREPARE leg of a
+        multi-shard one, a plain op as itself.  A request that meets the
+        intent lock of an earlier, still undecided transaction of the round
+        is deferred: it aborts the legs it had prepared and runs in the
+        next round as a fresh transaction (new txn_id and RIFL identities),
+        so the older transaction wins.  The round's ops are recorded in one
+        ``_record_batches`` and each master classifies and drains as a
+        plain batch does; then every multi-shard transaction decides: COMMIT
+        legs carrying the legs' forwarded exports, or ABORT legs when a leg
+        asked for a rollback.  Decide legs leave their sync to the next
+        round's drain or the batch's last one.  A lock held by a transaction
+        of no round (an orphan) is resolved and the request retried, as
+        ``_with_txn_resolution`` does.
+
+        A multi-shard transaction's outcome is fast when every leg was,
+        synced when any leg was, takes its slowest leg's round trips plus
+        the decide round, and sums its legs' witness accepts.  Procedure
+        transactions' values are their procedure's ``combine``."""
+        reg = telemetry.registry()
+        n = len(ops)
+        out: List[Optional["OpOutcome"]] = [None] * n
+        cur = list(ops)
+        pending = list(range(n))
+        while pending:
+            reg.counter("txn.batch.rounds").inc()
+            live: set = set()    # txn_ids of this round's transactions
+            per_shard: Dict[int, List[Tuple[Op, Tuple]]] = {}
+            legs: Dict[int, List[Tuple[int, int]]] = {}  # req -> (sid, pos)
+            deferred: List[int] = []
+            with telemetry.span("master"):
+                for i in pending:
+                    op = cur[i]
+                    if op.op_type is OpType.TXN:
+                        spec = op.args[0]
+                        live.add(spec.txn_id)
+                        steps = ([op] if op.args[1] is not None else
+                                 [prepare_op(spec, p) for p in spec.parts])
+                    else:
+                        steps = [op]
+                    done: List[Tuple[int, int]] = []
+                    for step in steps:
+                        g = self._group_for(step)
+                        res = self._round_step(g, session, step, now, live)
+                        if res is None:
+                            break
+                        per_shard.setdefault(g.shard_id, []).append(
+                            (step, res))
+                        done.append((g.shard_id,
+                                     len(per_shard[g.shard_id]) - 1))
+                    else:
+                        legs[i] = done
+                        continue
+                    deferred.append(i)
+                    reg.counter("txn.batch.deferred").inc()
+                    if op.op_type is OpType.TXN:
+                        # Abort what it prepared; it returns as a new
+                        # transaction.
+                        live.discard(op.args[0].txn_id)
+                        self._abandon(session, op, done, per_shard)
+                        cur[i] = session.op_txn(session.new_txn(
+                            op.args[0].parts))
+            begun = sorted(per_shard.items())
+            statuses = _record_batches(
+                [(self.shards[sid], [st for st, _r in steps])
+                 for sid, steps in begun])
+            rows: Dict[int, List["OpOutcome"]] = {}
+            for (sid, steps), st in zip(begun, statuses):
+                g = self.shards[sid]
+                rows[sid] = g._finish_batch(
+                    session.session_for(sid), [op for op, _r in steps],
+                    [r for _op, r in steps], st)
+            with telemetry.span("txn_decide"):
+                for i, done in legs.items():
+                    out[i] = self._txn_outcome(
+                        session, cur[i], [rows[sid][j] for sid, j in done])
+            pending = deferred
+        for g in self.shards:
+            if not g.retired and g.auto_sync and g.master.want_sync:
+                g._drain_syncs()
+        return out  # type: ignore[return-value]
+
+    def _round_step(self, g: ShardGroup, session: ShardedClientSession,
+                    op: Op, now: float, live: set):
+        """One master round of a transaction batch: (verdict, result, cfg),
+        or None when an intent of a transaction in ``live`` blocks it.  An
+        orphan's intent is resolved and the round retried."""
+        seen: set = set()
+        while True:
+            verdict, result, cfg = g._leg_round(op, session.acks(), now)
+            if verdict != ERROR:
+                return verdict, result, cfg
+            if result.error not in ("TXN_LOCKED", "TXN_PENDING"):
+                raise RuntimeError(f"transaction leg failed: {result.error}")
+            blocking = result.value
+            if blocking.txn_id in live:
+                return None
+            if blocking.txn_id in seen:
+                raise TxnPending(blocking)
+            seen.add(blocking.txn_id)
+            resolve_txn(self, blocking)
+
+    def _abandon(self, session: ShardedClientSession, op: Op,
+                 done: List[Tuple[int, int]],
+                 per_shard: Dict[int, List[Tuple[Op, Tuple]]]) -> None:
+        """A deferred request gives up this round's attempt: ABORT legs at
+        the masters where its PREPAREs ran, and release the identities that
+        never reached a master."""
+        spec = op.args[0]
+        ran = {per_shard[sid][j][0].rpc_id for sid, j in done}
+        for part in spec.parts:
+            if part.prepare_rpc in ran:
+                self.shards[part.shard_id].txn_decide(
+                    abort_op(spec, part), session.session_for(part.shard_id),
+                    drain=False)
+            else:
+                session.abandon(part.prepare_rpc)
+                session.abandon(part.decide_rpc)
+
+    def _txn_outcome(self, session: ShardedClientSession, op: Op,
+                     rows: List["OpOutcome"]) -> "OpOutcome":
+        """A request's outcome from its legs' rows; a multi-shard
+        transaction decides here (COMMIT or ABORT legs, without a drain)."""
+        from .local import OpOutcome
+
+        if op.op_type is not OpType.TXN:
+            return rows[0]
+        spec, sid = op.args
+        proc = spec.parts[0].proc
+        if sid is not None:
+            session.abandon(spec.parts[0].decide_rpc)   # never sent
+            (row,) = rows
+            if proc is None:
+                return row
+            value = row.value
+            row.value = procedure(proc).combine(
+                spec, None if value == "ROLLBACK" else {sid: value})
+            return row
+        telemetry.registry().counter("txn.batch.multi_shard").inc()
+        exports = [r.value[1] if r.value is not None else {} for r in rows]
+        commit = proc is None or all(ex is not None for ex in exports)
+        forwarded = forwarded_of(exports) if commit and proc else ()
+        results: Dict[int, Any] = {}
+        for part in spec.parts:
+            g = self.shards[part.shard_id]
+            leg = (commit_op(spec, part, forwarded) if commit
+                   else abort_op(spec, part))
+            results[part.shard_id] = g.txn_decide(
+                leg, session.session_for(part.shard_id), drain=False)
+        if proc is not None:
+            value = procedure(proc).combine(spec, results if commit else None)
+        else:
+            value = ("COMMITTED", tuple(
+                v for r in rows for v in (r.value[1] if r.value else ())))
+        self._record(self._txn_history_op(spec), value, session.client_id)
+        return OpOutcome(
+            value=value,
+            rtts=max(r.rtts for r in rows) + 1,
+            fast_path=all(r.fast_path for r in rows),
+            synced_path=any(r.synced_path for r in rows),
+            witness_accepts=sum(r.witness_accepts for r in rows),
+        )
 
     def mset(self, session: ShardedClientSession, kvs, now: float = 0.0,
              parts: Optional[Dict[int, Op]] = None):

@@ -8,8 +8,10 @@ reproduce master state exactly.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
+from .merge import CLS_OTHER, conflicts, key_classes
+from .txn import procedure
 from .types import Op, OpType
 
 
@@ -71,8 +73,9 @@ class KVStore:
         self._data: Dict[Any, VersionedValue] = {}
         # txn_id -> (TxnSpec, TxnPart): this store's prepared intents.
         self._intents: Dict[Tuple[int, int], Tuple[Any, Any]] = {}
-        # key -> txn_id holding the intent lock on it.
-        self._locks: Dict[Any, Tuple[int, int]] = {}
+        # key -> {txn_id: merge class} of the intents locking it; a lock
+        # blocks only an op whose class on the key conflicts with it.
+        self._locks: Dict[Any, Dict[Tuple[int, int], int]] = {}
 
     # -- mutation -----------------------------------------------------------
     def execute(self, op: Op, now: float = 0.0) -> Any:
@@ -82,6 +85,13 @@ class KVStore:
             # BEFORE the writes land (mini-transaction compare/read rule).
             spec, shard_id = op.args
             part = spec.part_on(shard_id)
+            if part.proc is not None:
+                proc = procedure(part.proc)
+                exports = proc.prepare(self.get, part.args)
+                if exports is None:
+                    return "ROLLBACK"
+                return proc.commit(self.get, self._putter(now), part.args,
+                                   exports)
             reads = tuple(self.get(k) for k in part.read_keys)
             for key, value in part.write_kvs:
                 self._set(key, value, now)
@@ -90,17 +100,25 @@ class KVStore:
             spec, shard_id = op.args
             part = spec.part_on(shard_id)
             self._intents[spec.txn_id] = (spec, part)
-            for k in part.keys:
-                self._locks[k] = spec.txn_id
+            classes = key_classes(op)
+            for k, c in zip(part.keys, classes):
+                self._locks.setdefault(k, {})[spec.txn_id] = c
+            if part.proc is not None:
+                # The procedure's exports (None: it asks for a rollback).
+                return ("PREPARED",
+                        procedure(part.proc).prepare(self.get, part.args))
             # Read values are stable until the decision: the locks block
             # every overlapping writer, so a prepare retry re-reads the
             # same values.
             reads = tuple(self.get(k) for k in part.read_keys)
             return ("PREPARED", reads)
         if t == OpType.TXN_COMMIT:
-            spec, shard_id = op.args
+            spec, shard_id = op.args[:2]
             part = spec.part_on(shard_id)
             self._drop_intent(spec.txn_id, part)
+            if part.proc is not None:
+                return procedure(part.proc).commit(
+                    self.get, self._putter(now), part.args, dict(op.args[2]))
             for key, value in part.write_kvs:
                 self._set(key, value, now)
             return "COMMITTED"
@@ -182,6 +200,9 @@ class KVStore:
             return None
         raise ValueError(f"unknown op type {t}")
 
+    def _putter(self, now: float) -> Callable[[Any, Any], None]:
+        return lambda key, value: self._set(key, value, now)
+
     def _set(self, key: Any, value: Any, now: float) -> None:
         cur = self._data.get(key)
         if cur is None:
@@ -195,7 +216,9 @@ class KVStore:
     def _drop_intent(self, txn_id: Tuple[int, int], part) -> None:
         self._intents.pop(txn_id, None)
         for k in part.keys:
-            if self._locks.get(k) == txn_id:
+            held = self._locks.get(k)
+            if held is not None and held.pop(txn_id, None) is not None \
+                    and not held:
                 del self._locks[k]
 
     def txn_intent(self, txn_id: Tuple[int, int]):
@@ -205,13 +228,23 @@ class KVStore:
     def txn_intents(self) -> Dict[Tuple[int, int], Tuple[Any, Any]]:
         return dict(self._intents)
 
-    def txn_lock_conflict(self, keys, txn_id=None):
-        """The spec of a FOREIGN transaction holding an intent lock on any of
-        these keys (None if unlocked or locked only by ``txn_id``)."""
-        for k in keys:
-            owner = self._locks.get(k)
-            if owner is not None and owner != txn_id:
-                return self._intents[owner][0]
+    def txn_lock_conflict(self, keys, txn_id=None, op: Optional[Op] = None):
+        """The spec of a FOREIGN transaction whose intent lock on any of
+        these keys conflicts with ``op``'s class on it (None if there is
+        none).  Without ``op`` every lock conflicts (class OTHER)."""
+        if not self._locks:
+            return None
+        classes = None
+        for i, k in enumerate(keys):
+            held = self._locks.get(k)
+            if not held:
+                continue
+            if classes is None:
+                classes = (key_classes(op) if op is not None
+                           else (CLS_OTHER,) * len(keys))
+            for owner, cls in held.items():
+                if owner != txn_id and conflicts(cls, classes[i]):
+                    return self._intents[owner][0]
         return None
 
     # -- introspection ------------------------------------------------------

@@ -85,18 +85,73 @@ class TxnPending(Exception):
 
 @dataclass(frozen=True)
 class TxnPart:
-    """One shard's leg of a transaction (its slice of the read/write sets)."""
+    """One shard's leg of a transaction (its slice of the read/write sets).
+
+    A stored-procedure leg (``proc`` set) carries no values: it names a
+    registered deterministic procedure (``register_procedure``), its inputs
+    ``args``, and every key it touches with the merge class it declares
+    (``decl``); the master computes the writes from what it reads, so backup
+    replay and witness replay recompute the same ones."""
     shard_id: int
     prepare_rpc: RpcId
     decide_rpc: RpcId
     write_kvs: Tuple[Tuple[Any, Any], ...]
     read_keys: Tuple[Any, ...] = ()
+    proc: Optional[str] = None
+    args: Tuple[Any, ...] = ()
+    decl: Tuple[Tuple[Any, int], ...] = ()
 
     @property
     def keys(self) -> Tuple[Any, ...]:
-        """All keys this leg touches (write first, then read) — the lock set
-        and the witness-record key set."""
+        """All keys this leg touches (write first, then read; a procedure
+        leg's declared keys) — the lock set and the witness-record key
+        set."""
+        if self.proc is not None:
+            return tuple(k for k, _c in self.decl)
         return tuple(k for k, _ in self.write_kvs) + tuple(self.read_keys)
+
+
+class Procedure:
+    """A deterministic stored procedure, run leg by leg by the masters.
+
+    ``prepare`` reads, at a leg's PREPARE (and inside a single-shard TXN),
+    the values other legs need: only values that no transaction writes, so
+    ``resolve_txn`` can re-read them from their owner after a crash.  It
+    returns them as a dict, or None when the transaction must roll back.
+    ``commit`` applies the leg at its COMMIT (or TXN) through ``get``/``put``
+    given every leg's exports (``forwarded``) and returns the leg's result;
+    ``combine`` folds the legs' results (None on a rollback) into the
+    transaction's value."""
+
+    def prepare(self, get: Callable[[Any], Any], args) -> Optional[dict]:
+        raise NotImplementedError
+
+    def commit(self, get: Callable[[Any], Any],
+               put: Callable[[Any, Any], None], args, forwarded: dict) -> Any:
+        raise NotImplementedError
+
+    def combine(self, spec: "TxnSpec", results: Optional[Dict[int, Any]]):
+        raise NotImplementedError
+
+
+_PROCEDURES: Dict[str, Procedure] = {}
+
+
+def register_procedure(name: str, proc: Procedure) -> None:
+    _PROCEDURES[name] = proc
+
+
+def procedure(name: str) -> Procedure:
+    return _PROCEDURES[name]
+
+
+def forwarded_of(exports: Sequence[Optional[dict]]) -> Tuple:
+    """Every leg's prepare exports as one sorted item tuple: the argument a
+    COMMIT leg carries."""
+    out: Dict[Any, Any] = {}
+    for ex in exports:
+        out.update(ex or {})
+    return tuple(sorted(out.items(), key=lambda kv: repr(kv[0])))
 
 
 @dataclass(frozen=True)
@@ -135,9 +190,12 @@ def prepare_op(spec: TxnSpec, part: TxnPart) -> Op:
               part.prepare_rpc)
 
 
-def commit_op(spec: TxnSpec, part: TxnPart) -> Op:
-    return Op(OpType.TXN_COMMIT, part.keys, (spec, part.shard_id),
-              part.decide_rpc)
+def commit_op(spec: TxnSpec, part: TxnPart, forwarded: Tuple = ()) -> Op:
+    """``forwarded`` (``forwarded_of``) carries the values a procedure leg
+    needs from other legs."""
+    args = (spec, part.shard_id, forwarded) if part.proc is not None \
+        else (spec, part.shard_id)
+    return Op(OpType.TXN_COMMIT, part.keys, args, part.decide_rpc)
 
 
 def abort_op(spec: TxnSpec, part: TxnPart) -> Op:
@@ -203,12 +261,23 @@ def resolve_txn(cluster, spec: TxnSpec) -> TxnStatus:
         decision = TxnStatus.COMMITTED
     else:
         decision = TxnStatus.ABORTED
+    forwarded: Tuple = ()
+    if decision is TxnStatus.COMMITTED and spec.parts[0].proc is not None:
+        # Procedure legs need the values other legs read at PREPARE; those
+        # are never written, so their owners still hold them, and a leg
+        # that asked for a rollback asks again.
+        exports = [procedure(p.proc).prepare(
+            cluster.shards[p.shard_id].master.store.get, p.args)
+            for p in spec.parts]
+        if any(ex is None for ex in exports):
+            decision = TxnStatus.ABORTED
+        forwarded = forwarded_of(exports)
     for part in spec.parts:
         if states[part.shard_id] in ("committed", "aborted"):
             continue  # decision already durable at this participant
         group = cluster.shards[part.shard_id]
-        op = (commit_op(spec, part) if decision is TxnStatus.COMMITTED
-              else abort_op(spec, part))
+        op = (commit_op(spec, part, forwarded)
+              if decision is TxnStatus.COMMITTED else abort_op(spec, part))
         group.txn_decide(op)
     return decision
 

@@ -39,6 +39,7 @@ from .ops import (
     gang_record,
     gang_record_groups,
     keyhash2x32,
+    record_fits,
     np_keyhash2x32,
     ref_conflict_scan,
     ref_gang_gc,
@@ -64,7 +65,7 @@ __all__ = [
     "ref_witness_gc", "ref_witness_record", "ref_witness_record_txn",
     "GangTable", "GangRecordResult", "GangFastPathResult",
     "gang_record", "gang_record_groups", "gang_gc", "gang_fastpath_batch",
-    "gang_rows",
+    "gang_rows", "record_fits",
     "np_keyhash2x32", "ref_gang_record", "ref_gang_gc",
     "matrix_rows", "conflict_matrix_np",
 ]

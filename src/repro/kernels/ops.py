@@ -28,7 +28,7 @@ buffer-donation contract.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +58,7 @@ from .witness_record import (
     fastpath_record_scan_pallas,
     gang_gc_pallas,
     gang_record_pallas,
+    smem_fits,
     witness_gc_pallas,
     witness_record_seq_pallas,
     witness_record_setpar_pallas,
@@ -605,7 +606,8 @@ def gang_record_groups(
     G, K = key_hi.shape
     key_cls = (np.zeros((G, K), np.int32) if key_cls is None
                else np.asarray(key_cls, np.int32))
-    Gp, Kp = _bucket(G, lo=4), _bucket(K, lo=2)
+    Kp = _bucket(K, lo=2)
+    Gp = _groups_bucket(G, Kp)
     pad2 = ((0, Gp - G), (0, Kp - K))
     key_hi = np.pad(key_hi, pad2)
     key_lo = np.pad(key_lo, pad2)
@@ -624,6 +626,49 @@ def gang_record_groups(
         np.asarray(rsn)[:G], np.asarray(qh)[:G, :K], np.asarray(ql)[:G, :K],
         new_table,
     )
+
+
+def _group_words(G: int, K: int) -> List[int]:
+    """The SMEM arrays of a grouped record dispatch (``check_smem``)."""
+    return [G * K] * 4 + [G] * 4
+
+
+#: Groups of this many padded keys or more are wide: the record kernel's
+#: body is unrolled over a group's keys, so each wide shape takes seconds to
+#: trace and lower.
+WIDE_K = 8
+
+
+@functools.lru_cache(maxsize=None)
+def _most_groups(Kp: int) -> int:
+    """The most groups, a power of two, of ``Kp`` keys that fit SMEM."""
+    g = 4
+    while smem_fits(_group_words(2 * g, Kp)):
+        g *= 2
+    return g
+
+
+def _groups_bucket(G: int, Kp: int) -> int:
+    """Padded group count of a grouped record: the next power of two, or,
+    for wide groups, the most that fit SMEM at that width, so each wide
+    width compiles one shape.  Padded groups cost the kernel nothing (they
+    sort past the last tile)."""
+    Gp = _bucket(G, lo=4)
+    if Kp >= WIDE_K and Gp <= _most_groups(Kp):
+        return _most_groups(Kp)
+    return Gp
+
+
+def record_fits(G: int, K: int) -> bool:
+    """Whether one record dispatch of ``G`` groups of at most ``K`` keys
+    fits v5e's SMEM after its padding: ``gang_record`` for one key per
+    group, ``gang_record_groups`` otherwise (the model ``check_smem``
+    raises on)."""
+    if K == 1:
+        Gp = _bucket(G)
+        return smem_fits([Gp] * 8)
+    Kp = _bucket(K, lo=2)
+    return smem_fits(_group_words(_groups_bucket(G, Kp), Kp))
 
 
 def _single_key_record(table, qh, ql, raw_lo, k_cls, valid, lanes, r_hi,

@@ -609,12 +609,18 @@ SMEM_PAD = 1024
 SMEM_RESERVED = 1024
 
 
+def smem_fits(lengths) -> bool:
+    """Whether flat int32 SMEM arrays of ``lengths`` fit v5e's SMEM."""
+    words = sum(-(-n // SMEM_PAD) * SMEM_PAD for n in lengths)
+    return words <= SMEM_WORDS - SMEM_RESERVED
+
+
 def check_smem(kernel: str, lengths) -> None:
     """Raise before the compile if the flat int32 SMEM arrays of
     ``lengths`` (prefetched work items and per-item outputs) overflow v5e's
     SMEM, where Mosaic would refuse with RESOURCE_EXHAUSTED."""
-    words = sum(-(-n // SMEM_PAD) * SMEM_PAD for n in lengths)
-    if words > SMEM_WORDS - SMEM_RESERVED:
+    if not smem_fits(lengths):
+        words = sum(-(-n // SMEM_PAD) * SMEM_PAD for n in lengths)
         raise ValueError(
             f"{kernel}: {words} SMEM words of work items exceed the "
             f"{SMEM_WORDS - SMEM_RESERVED} that fit on v5e; split the batch")

@@ -21,9 +21,12 @@ import pytest
 from repro.core.client import ClientSession
 from repro.core.merge import (
     CLS_DEL,
+    CLS_HMSET,
     CLS_INCR,
     CLS_OTHER,
+    CLS_READ,
     CLS_SET,
+    CONFLICT_MATRIX,
     MERGEABLE,
     N_CLASSES,
     conflicts,
@@ -73,6 +76,21 @@ def test_matrix_rows_helper_matches_numpy_rows():
     rows = conflict_matrix_np()
     got = np.asarray(matrix_rows(np.arange(N_CLASSES, dtype=np.int32)))
     assert np.array_equal(got, rows.astype(got.dtype))
+
+
+def test_read_class_commutes_only_with_reads():
+    """READ (a stored procedure's read) commutes with READ alone, and the
+    rows of the classes that existed before it are bit for bit what they
+    were: READ joined the MERGEABLE set, and every other class already
+    conflicted with the unused class 9."""
+    assert not conflicts(CLS_READ, CLS_READ)
+    assert all(conflicts(CLS_READ, c) and conflicts(c, CLS_READ)
+               for c in range(N_CLASSES) if c != CLS_READ)
+    before = frozenset(MERGEABLE - {CLS_READ})
+    for a in range(CLS_OTHER + 1):
+        row = sum(1 << b for b in range(N_CLASSES)
+                  if not (a == b and a in before))
+        assert CONFLICT_MATRIX[a] == row, a
 
 
 def test_mergeable_classes_self_commute_others_conflict():
@@ -155,14 +173,20 @@ def test_gang_kernel_reserves_ways_for_same_set_group():
 
 
 def test_record_kernel_matches_oracle_on_classed_collisions():
+    _kernel_matches_oracle((CLS_SET, CLS_DEL, CLS_INCR, CLS_INCR, CLS_INCR))
+
+
+def test_record_kernel_matches_oracle_with_read_class():
+    _kernel_matches_oracle((CLS_SET, CLS_READ, CLS_READ, CLS_INCR, CLS_READ))
+
+
+def _kernel_matches_oracle(classes):
     rng = np.random.default_rng(5)
     base_hi = rng.integers(0, 2 ** 32, size=6, dtype=np.uint32)
     base_lo = rng.integers(0, 2 ** 32, size=6, dtype=np.uint32)
     pick = rng.integers(0, 6, size=128)
     q_hi, q_lo = base_hi[pick], base_lo[pick]
-    q_cls = rng.choice(
-        np.array([CLS_SET, CLS_DEL, CLS_INCR, CLS_INCR, CLS_INCR],
-                 dtype=np.int32), size=128)
+    q_cls = rng.choice(np.array(classes, dtype=np.int32), size=128)
     table = WitnessTable.empty(32, 16)
     acc_ref, t_ref = ref_witness_record(table, q_hi, q_lo, q_cls)
     acc_dev, t_dev = witness_record(table, q_hi, q_lo, q_cls)
@@ -172,6 +196,28 @@ def test_record_kernel_matches_oracle_on_classed_collisions():
                               np.asarray(getattr(t_dev, name))), name
     acc = np.asarray(acc_ref)
     assert 0 < int(acc.sum()) < len(acc)
+
+
+def test_record_kernel_consults_the_predicate_for_every_class_pair():
+    """The kernel's conflict-row consult agrees with the Python predicate
+    on every ordered pair of SET, INCR, HMSET, OTHER and READ: a key held
+    under class ``a`` accepts a second record of class ``b`` iff the two
+    commute."""
+    classes = (CLS_SET, CLS_INCR, CLS_HMSET, CLS_OTHER, CLS_READ)
+    pairs = [(a, b) for a in classes for b in classes]
+    rng = np.random.default_rng(17)
+    k_hi = rng.integers(0, 2 ** 32, size=len(pairs), dtype=np.uint32)
+    k_lo = rng.integers(0, 2 ** 32, size=len(pairs), dtype=np.uint32)
+    q_hi, q_lo = np.repeat(k_hi, 2), np.repeat(k_lo, 2)
+    q_cls = np.array(pairs, dtype=np.int32).reshape(-1)
+    table = WitnessTable.empty(64, 8)
+    acc_dev, _ = witness_record(table, q_hi, q_lo, q_cls)
+    acc_ref, _ = ref_witness_record(table, q_hi, q_lo, q_cls)
+    acc = np.asarray(acc_dev).reshape(-1, 2)
+    assert np.array_equal(np.asarray(acc_ref).reshape(-1, 2), acc)
+    assert acc[:, 0].all()
+    assert [bool(x) for x in acc[:, 1]] == [not conflicts(a, b)
+                                            for a, b in pairs]
 
 
 def test_all_set_batch_keeps_legacy_occ_encoding():

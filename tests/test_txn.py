@@ -369,3 +369,35 @@ class TestServingAtomicCommit:
         store = CurpSessionStore(f=3, n_shards=2)
         out = store.txn([])
         assert out.status is TxnStatus.COMMITTED and out.n_shards == 0
+
+
+class TestTxnInUpdateBatch:
+    def test_write_set_transactions_share_a_batch_with_updates(self):
+        """Write-set transactions ride ``update_batch`` with plain updates:
+        a multi-shard one prepares and commits in the first round, and a
+        plain SET and a single-shard transaction that meet its intents run
+        in the next round, after its commit, on the slow path."""
+        from repro.core import telemetry
+
+        cl = ShardedCluster(n_shards=N_SHARDS, f=3)
+        s = cl.new_client()
+        a = key_on_shard(cl.router, 0, "a")
+        b = key_on_shard(cl.router, 1, "b")
+        c = key_on_shard(cl.router, 0, "c")
+        multi = s.op_txn(s.txn_spec([(a, "1"), (b, "2")], reads=[c]))
+        single = s.op_txn(s.txn_spec([(c, "4")]))
+        names = ("txn.batch.rounds", "txn.batch.deferred",
+                 "txn.batch.multi_shard")
+        reg = telemetry.registry()
+        before = [reg.counter(n).value for n in names]
+        out = cl.update_batch(s, [multi, s.op_set(a, "3"), single])
+        assert [reg.counter(n).value - v for n, v in zip(names, before)] \
+            == [2, 2, 1]
+        assert out[0].value == ("COMMITTED", (None,))
+        assert out[0].rtts == 2 and out[0].fast_path
+        # Both meet the first transaction's legs in their master's
+        # unsynced window: its decision left their sync to this round.
+        assert [(o.fast_path, o.synced_path) for o in out[1:]] \
+            == [(False, True)] * 2
+        assert [cl.shards[cl.shard_of(k)].master.store.get(k)
+                for k in (a, b, c)] == ["3", "2", "4"]
