@@ -24,8 +24,8 @@ Many witness instances share one device-resident **gang**
 single [n_lanes*S, W] array, so a routed cross-shard batch records at every
 target lane in ONE dispatch (repro.kernels.ops.gang_fastpath_batch), a batch
 the fused path declines records at every witness of every master in ONE
-dispatch (``record_many``), and a sync round gc's every witness of a shard
-in ONE dispatch (``gc_many``).
+dispatch (``record_many``), and a drain wave gc's every witness of every
+master that synced in it in ONE dispatch (``gc_many``).
 
 Set placement equals the Python witness's: ``kh % n_sets`` on the raw 64-bit
 key hash (its low lane masked by S-1), so both backends fill the same sets
@@ -323,8 +323,7 @@ class DeviceWitness:
         """Drop synced records (one gang gc dispatch); report suspects."""
         if self.mode is not WitnessMode.NORMAL:
             return GcResp(stale_requests=())
-        resps = gc_many([self], entries)
-        return resps[0]
+        return gc_many([([self], entries)])[0][0]
 
     def _apply_gc(self, keys: List[Tuple[int, int]],
                   rpc_ids: List[RpcId], cleared) -> GcResp:
@@ -391,51 +390,77 @@ class DeviceWitness:
         return sum(len(by_rpc) for by_rpc in self._held.values())
 
 
-def gc_many(witnesses: Sequence[DeviceWitness],
-            entries: Tuple[Tuple[int, RpcId], ...]) -> List[GcResp]:
-    """Gc the same sync batch at MANY witnesses of one gang in ONE dispatch.
+# Most gc entries one dispatch takes: the largest power-of-two bucket
+# (``gang_gc`` pads to one) under the 43008 that fit v5e's SMEM.
+GC_CHUNK = 32768
 
-    Entries are lane-expanded (every witness gets its own copy targeting its
-    lane) and deduplicated per (key, rpc) — the Python reference clears a
-    slot once however many times the pair appears.  Aging covers exactly
-    the participating lanes.  Returns one GcResp per witness, in order.
+
+def gc_many(
+    jobs: Sequence[Tuple[Sequence[DeviceWitness],
+                         Tuple[Tuple[int, RpcId], ...]]],
+) -> List[List[GcResp]]:
+    """Gc each job's sync batch at its witnesses, every job of one gang in
+    ONE dispatch when the entries fit.
+
+    ``jobs`` are (witnesses, entries): one master's sync round and its
+    witnesses, all in NORMAL mode on one gang.  A job's entries are
+    deduplicated per (key, rpc) — the Python reference clears a slot once
+    however many times the pair appears — and lane-expanded, every witness
+    getting its own copy targeting its lane.  Lanes never overlap, so one
+    stacked dispatch makes the decisions one dispatch per job would.  Aging
+    covers exactly the lanes of jobs with entries; a job without entries
+    ages its mirrors on the host only, as its own round would.  More than
+    ``GC_CHUNK`` entries split into the fewest dispatches that fit, each a
+    contiguous run of the stacked entries; lanes age in the last.  Returns
+    one GcResp list per job, in its witnesses' order.
     """
     from repro.kernels import gang_gc, np_keyhash2x32
 
-    assert witnesses, "gc_many needs at least one witness"
-    gang = witnesses[0].gang
-    assert all(w.gang is gang for w in witnesses), "witnesses must share a gang"
-    assert all(w.mode is WitnessMode.NORMAL for w in witnesses)
-    uniq = list(dict.fromkeys((kh, rpc) for kh, rpc in entries))
-    if not uniq:
-        # Pure aging round: Python gc ages survivors even with no entries.
-        return [w._apply_gc([], [], []) for w in witnesses]
-    hi, lo = _lanes([kh for kh, _rpc in uniq])
-    qh, ql = np_keyhash2x32(hi, lo)
-    rhi, rlo = _rpc_lanes([rpc for _kh, rpc in uniq])
-    E, L = len(uniq), len(witnesses)
-    g_hi = np.tile(hi, L)
-    g_lo = np.tile(lo, L)
-    g_rh = np.tile(rhi, L)
-    g_rl = np.tile(rlo, L)
-    g_lane = np.repeat(
-        np.fromiter((w.lane for w in witnesses), np.int32, L), E
-    )
-    aged = np.zeros(gang.n_lanes, np.int32)
-    for w in witnesses:
-        aged[w.lane] = 1
-    cleared, table = gang_gc(
-        gang.table, gang.n_sets, g_hi, g_lo, g_rh, g_rl, g_lane, aged
-    )
-    gang.table = table
-    for w in witnesses:
-        w.stats["kernel_batches"] += 1
-    keys = [(int(qh[e]), int(ql[e])) for e in range(E)]
-    rpcs = [rpc for _kh, rpc in uniq]
-    return [
-        w._apply_gc(keys, rpcs, [bool(c) for c in cleared[i * E:(i + 1) * E]])
-        for i, w in enumerate(witnesses)
-    ]
+    gang = jobs[0][0][0].gang
+    assert all(w.gang is gang and w.mode is WitnessMode.NORMAL
+               for ws, _e in jobs for w in ws), \
+        "witnesses must share a gang and be NORMAL"
+    uniqs = [list(dict.fromkeys(entries)) for _ws, entries in jobs]
+    keys = [_lanes([kh for kh, _rpc in uniq]) for uniq in uniqs]
+    rpcs = [_rpc_lanes([rpc for _kh, rpc in uniq]) for uniq in uniqs]
+    stacked = [[np.tile(a, len(ws)) for a in (hi, lo, rhi, rlo)]
+               + [np.repeat(np.fromiter((w.lane for w in ws), np.int32,
+                                        len(ws)), len(hi))]
+               for (ws, _e), (hi, lo), (rhi, rlo) in zip(jobs, keys, rpcs)]
+    g_hi, g_lo, g_rh, g_rl, g_lane = (
+        np.concatenate([s[i] for s in stacked]) for i in range(5))
+    N = len(g_hi)
+    cleared = []
+    if N:
+        reg = telemetry.registry()
+        aged = np.zeros(gang.n_lanes, np.int32)
+        aged[g_lane] = 1
+        n = -(-N // GC_CHUNK)
+        for c in range(n):
+            a, b = N * c // n, N * (c + 1) // n
+            clr, gang.table = gang_gc(
+                gang.table, gang.n_sets, g_hi[a:b], g_lo[a:b], g_rh[a:b],
+                g_rl[a:b], g_lane[a:b],
+                aged if c == n - 1 else np.zeros_like(aged))
+            cleared.extend(clr.astype(bool).tolist())
+            reg.counter("witness.gc_dispatches").inc()
+        reg.counter("witness.stacked_gcs").inc()
+        reg.counter("witness.stacked_gc_lanes").inc(int(aged.sum()))
+    resps: List[List[GcResp]] = []
+    at = 0
+    for (ws, _e), uniq, (hi, lo) in zip(jobs, uniqs, keys):
+        E = len(uniq)
+        qh, ql = np_keyhash2x32(hi, lo)
+        mixed = list(zip(qh.tolist(), ql.tolist()))
+        ids = [rpc for _kh, rpc in uniq]
+        job = []
+        for w in ws:
+            if E:
+                w.stats["kernel_batches"] += 1
+            job.append(w._apply_gc(mixed, ids, cleared[at:at + E]))
+            at += E
+        resps.append(job)
+    return resps
 
 
 def _pack(ops: Sequence[Op], pairs, K: int):

@@ -42,7 +42,7 @@ import numpy as np
 from . import telemetry
 from .client import Decision
 from .master import DUP, ERROR, SYNCED
-from .shard import hash_tag
+from .shard import _drain_many, hash_tag
 from .types import Op, OpType, RecordStatus, WitnessMode
 
 _M32 = 0xFFFFFFFF
@@ -356,13 +356,17 @@ class FusedBatchDriver:
                     ),
                 ))
 
-        # Ring bookkeeping + the batched sync/gc tail (one drain per shard).
+        # Ring bookkeeping around the batched sync/gc tail: one drain of
+        # every shard that needs it, whose gc is one stacked dispatch.
+        drains = []
         for sid in touched:
             g = cluster.shards[sid]
             self.ring.committed(sid, g.master, per_shard_appends[sid])
             if sid in need_drain or (g.auto_sync and g.master.want_sync):
-                g._drain_syncs()
-            self.ring.advance(sid, g.master)
+                drains.append(g)
+        _drain_many(drains)
+        for sid in touched:
+            self.ring.advance(sid, cluster.shards[sid].master)
         return outcomes
 
     def _record(self, ops, shard_ids, touched, exec_pred):

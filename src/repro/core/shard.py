@@ -70,7 +70,8 @@ from .txn import (
     resolve_txn,
     single_shard_op,
 )
-from .types import ClusterConfig, ExecResult, Op, OpType, RecordStatus, keyhash
+from .types import (ClusterConfig, ExecResult, GcResp, Op, OpType,
+                    RecordStatus, WitnessMode, keyhash)
 from .witness import Witness
 
 _M32 = 0xFFFFFFFF
@@ -416,7 +417,10 @@ class ShardGroup:
         """
         results = self._master_rounds(session, ops, now)
         (statuses,) = _record_batches([(self, list(ops))])
-        return self._finish_batch(session, ops, results, statuses)
+        drains: List[ShardGroup] = []
+        outcomes = self._finish_batch(session, ops, results, statuses, drains)
+        _drain_many(drains)
+        return outcomes
 
     def _master_rounds(self, session: ClientSession, ops: Sequence[Op],
                        now: float) -> List[Tuple[str, ExecResult,
@@ -428,10 +432,11 @@ class ShardGroup:
 
     def _finish_batch(self, session: ClientSession, ops: Sequence[Op],
                       results, per_witness: List[List[RecordStatus]],
-                      ) -> List["OpOutcome"]:
+                      drains: List["ShardGroup"]) -> List["OpOutcome"]:
         """Last step of a batch: fold each op's master verdict and witness
-        statuses into its outcome, mark it completed, record its history,
-        then drain the syncs the batch needs."""
+        statuses into its outcome, mark it completed and record its history;
+        a group whose batch needs a sync joins ``drains``, which the caller
+        drains together (``_drain_many``)."""
         from .local import OpOutcome
 
         outcomes: List[OpOutcome] = []
@@ -459,7 +464,7 @@ class ShardGroup:
                     ),
                 ))
         if need_drain or (self.auto_sync and self.master.want_sync):
-            self._drain_syncs()
+            drains.append(self)
         return outcomes
 
     def read(self, session: ClientSession, op: Op, now: float = 0.0):
@@ -564,54 +569,9 @@ class ShardGroup:
 
     # ------------------------------------------------------------------ syncs
     def _drain_syncs(self) -> None:
-        """Run batched backup syncs + witness gc until quiescent (§4.4, §3.5).
-        Each round is one ``sync`` span (backups' handle_sync and
-        complete_sync) and one ``gc`` span; the probe that finds nothing to
-        sync emits none."""
-        while True:
-            req = self.master.begin_sync()
-            if req is None:
-                return
-            with telemetry.span("sync"):
-                ok = True
-                for b in self.backups:
-                    resp = b.handle_sync(req)
-                    ok = ok and resp.ok
-                if not ok:
-                    self.master.abort_sync()
-                    return
-                gc_entries = self.master.complete_sync()
-            with telemetry.span("gc"):
-                live = [w for i, w in enumerate(self.witnesses)
-                        if i not in self._dropped_witnesses]
-                for resp in self._gc_witnesses(live, gc_entries):
-                    # §4.5: retry suspected uncollected garbage through RIFL.
-                    for op in resp.stale_requests:
-                        self.master.handle_update(
-                            op,
-                            self.config.fetch(
-                                self.shard_id).witness_list_version,
-                            (), 0.0,
-                        )
-
-    def _gc_witnesses(self, witnesses, gc_entries):
-        """One sync round's witness gc: device witnesses sharing a gang
-        clear + age in ONE stacked dispatch (lane-expanded entries); any
-        remaining witness gc's individually.  Responses in witness order."""
-        if self.witness_backend == "device" and len(witnesses) > 1:
-            from .device_witness import DeviceWitness, gc_many
-            from .types import WitnessMode
-
-            gang = self.gang
-            stacked = [w for w in witnesses
-                       if isinstance(w, DeviceWitness)
-                       and w.mode is WitnessMode.NORMAL and w.gang is gang]
-            if len(stacked) > 1:
-                resp = dict(zip((id(w) for w in stacked),
-                                gc_many(stacked, gc_entries)))
-                return [resp[id(w)] if id(w) in resp else w.gc(gc_entries)
-                        for w in witnesses]
-        return [w.gc(gc_entries) for w in witnesses]
+        """Run batched backup syncs + witness gc until quiescent (§4.4,
+        §3.5): a drain of this group alone (``_drain_many``)."""
+        _drain_many([self])
 
     def sync_now(self) -> None:
         self.master.want_sync = True
@@ -681,6 +641,69 @@ class ShardGroup:
         ids = list(self._witness_ids)
         ids[witness_idx] = new_id
         self._witness_ids = tuple(ids)
+
+
+def _drain_many(groups: Sequence[ShardGroup]) -> None:
+    """Run batched backup syncs + witness gc at every group until each is
+    quiescent (§4.4, §3.5), in waves.  A wave is (a) each group's sync
+    round, one ``sync`` span each (backups' handle_sync and complete_sync;
+    a group whose backups refuse aborts the sync and leaves the drain), then
+    one ``gc`` span holding (b) every synced group's witness gc — device
+    witnesses sharing a gang clear and age in ONE stacked dispatch
+    (``gc_many``), any other witness gc's by itself — and (c) each group's
+    §4.5 stale retries.  Groups share no master, backup or witness lane, so
+    each sees the steps its own drain would, in the same order; the probe
+    that finds nothing to sync emits no span."""
+    from .device_witness import DeviceWitness, gc_many
+
+    todo = list(groups)
+    while todo:
+        synced = []
+        for g in todo:
+            req = g.master.begin_sync()
+            if req is None:
+                continue
+            with telemetry.span("sync"):
+                ok = True
+                for b in g.backups:
+                    ok = b.handle_sync(req).ok and ok
+                if not ok:
+                    g.master.abort_sync()
+                    continue
+                synced.append((g, g.master.complete_sync()))
+        if not synced:
+            return
+        with telemetry.span("gc"):
+            rounds = []
+            jobs: Dict[int, list] = {}
+            for g, entries in synced:
+                live = [w for i, w in enumerate(g.witnesses)
+                        if i not in g._dropped_witnesses]
+                resp: Dict[int, GcResp] = {}
+                by_gang: Dict[int, list] = {}
+                for w in live:
+                    if (isinstance(w, DeviceWitness)
+                            and w.mode is WitnessMode.NORMAL):
+                        by_gang.setdefault(id(w.gang), []).append(w)
+                    else:
+                        resp[id(w)] = w.gc(entries)
+                for key, ws in by_gang.items():
+                    jobs.setdefault(key, []).append((ws, entries, resp))
+                rounds.append((g, live, resp))
+            for gang_jobs in jobs.values():
+                for (ws, _e, resp), rs in zip(gang_jobs, gc_many(
+                        [(ws, e) for ws, e, _r in gang_jobs])):
+                    resp.update(zip(map(id, ws), rs))
+            for g, live, resp in rounds:
+                for w in live:
+                    # §4.5: retry suspected uncollected garbage through RIFL.
+                    for op in resp[id(w)].stale_requests:
+                        g.master.handle_update(
+                            op,
+                            g.config.fetch(g.shard_id).witness_list_version,
+                            (), 0.0,
+                        )
+        todo = [g for g, _entries in synced]
 
 
 def _record_batches(batches: Sequence[Tuple[ShardGroup, List[Op]]],
@@ -1044,8 +1067,9 @@ class ShardedCluster:
         """Batched client path: group ops by owning shard, run every shard's
         master rounds, record every shard's ops at all its witnesses (ONE
         stacked kernel dispatch on the device backend, ``record_many``),
-        then classify and drain shard by shard; return per-op outcomes in
-        the input order.
+        then classify shard by shard and drain every shard that needs it
+        together (``_drain_many``: one stacked gc dispatch per sync wave);
+        return per-op outcomes in the input order.
 
         On the device backend a routed cross-shard batch of plain updates
         first tries the fused driver (core/fastbatch.py): ONE stacked-gang
@@ -1103,8 +1127,8 @@ class ShardedCluster:
         todo = list(groups.items())
         seen: set = set()
         while todo:
-            # Shards run phased (all master rounds, one record, then each
-            # shard's classification and drain).  Lanes and masters never
+            # Shards run phased (all master rounds, one record, each
+            # shard's classification, one drain).  Lanes and masters never
             # overlap, so outcomes equal a shard-by-shard loop's; masters
             # see the batch-start ack frontier, which only delays dropping
             # completion records.  A shard blocked by an orphaned txn lock
@@ -1122,10 +1146,12 @@ class ShardedCluster:
                     break
                 begun.append((g, sub, sub_ops, idxs, results))
             statuses = _record_batches([(b[0], b[2]) for b in begun])
+            drains: List[ShardGroup] = []
             for (g, sub, sub_ops, idxs, results), st in zip(begun, statuses):
-                for i, outcome in zip(
-                        idxs, g._finish_batch(sub, sub_ops, results, st)):
+                for i, outcome in zip(idxs, g._finish_batch(
+                        sub, sub_ops, results, st, drains)):
                     out[i] = outcome
+            _drain_many(drains)
             todo = todo[len(begun):]
             if pend is not None:
                 if begun:
@@ -1207,19 +1233,20 @@ class ShardedCluster:
                 [(self.shards[sid], [st for st, _r in steps])
                  for sid, steps in begun])
             rows: Dict[int, List["OpOutcome"]] = {}
+            drains: List[ShardGroup] = []
             for (sid, steps), st in zip(begun, statuses):
                 g = self.shards[sid]
                 rows[sid] = g._finish_batch(
                     session.session_for(sid), [op for op, _r in steps],
-                    [r for _op, r in steps], st)
+                    [r for _op, r in steps], st, drains)
+            _drain_many(drains)
             with telemetry.span("txn_decide"):
                 for i, done in legs.items():
                     out[i] = self._txn_outcome(
                         session, cur[i], [rows[sid][j] for sid, j in done])
             pending = deferred
-        for g in self.shards:
-            if not g.retired and g.auto_sync and g.master.want_sync:
-                g._drain_syncs()
+        _drain_many([g for g in self.shards if not g.retired
+                     and g.auto_sync and g.master.want_sync])
         return out  # type: ignore[return-value]
 
     def _round_step(self, g: ShardGroup, session: ShardedClientSession,
@@ -1583,9 +1610,10 @@ class ShardedCluster:
 
     # ------------------------------------------------------------------ admin
     def sync_all(self) -> None:
-        for g in self.shards:
-            if not g.retired:
-                g.sync_now()
+        live = [g for g in self.shards if not g.retired]
+        for g in live:
+            g.master.want_sync = True
+        _drain_many(live)
 
     def crash_master(self, shard_id: int) -> RecoveryReport:
         """Crash exactly one shard's master; only that shard's witnesses are

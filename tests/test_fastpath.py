@@ -735,7 +735,7 @@ class TestGangKernelState:
         entries = tuple((kh, op.rpc_id) for op in ops[:4]
                         for kh in op.key_hashes())
         reset_dispatch_count()
-        resps = gc_many(ws, entries)
+        (resps,) = gc_many([(ws, entries)])
         assert dispatch_count() == 1
         reset_dispatch_count()
         ws2, _ = build()
