@@ -1,4 +1,4 @@
-.PHONY: check test bench-scaling bench-fastpath bench-txn bench-migration bench-crdt bench-slo bench-watchdog bench-gate
+.PHONY: check test bench-scaling bench-txn bench-migration bench-crdt bench-slo bench-watchdog bench-gate
 
 check:
 	bash scripts/check.sh
@@ -8,9 +8,6 @@ test:
 
 bench-scaling:
 	PYTHONPATH=src python -m benchmarks.fig_scaling
-
-bench-fastpath:
-	PYTHONPATH=src python -m benchmarks.fig_fastpath
 
 bench-txn:
 	PYTHONPATH=src python -m benchmarks.fig_txn

@@ -21,9 +21,10 @@ checker — widening commutativity must not widen observable behaviour):
     decided at field granularity, not key granularity.
   * parity — the Pallas conflict decision is bit-exact with Python:
     CONFLICT_MATRIX rows == scalar ``conflicts`` over all 16x16 class pairs,
-    and the set-parallel record kernel matches the sequential oracle on a
-    collision-heavy batch mixing INCR/INCR stacks with SET/INCR conflicts
-    (accept lanes AND resulting table planes compared bit-for-bit).
+    and the served record kernel (``gang_record``) matches the
+    ``ref_gang_record`` oracle on a collision-heavy batch mixing INCR/INCR
+    stacks with SET/INCR conflicts (reason codes AND resulting table planes
+    compared bit-for-bit).
 """
 from __future__ import annotations
 
@@ -35,10 +36,10 @@ from repro.core.client import ClientSession
 from repro.core.merge import N_CLASSES, conflicts
 from repro.core.types import Op
 from repro.kernels import (
-    WitnessTable,
+    GangTable,
     conflict_matrix_np,
-    ref_witness_record,
-    witness_record,
+    gang_record,
+    ref_gang_record,
 )
 from repro.sim import HotKeyWorkload, SimParams, check_linearizable_strict, run_scenario
 
@@ -93,9 +94,10 @@ def check_parity(n_queries: int = 256, seed: int = 7) -> int:
 
     1. matrix encoding: every (a, b) of the 16x16 CONFLICT_MATRIX row plane
        must equal the scalar ``conflicts`` predicate the Python witness uses.
-    2. record kernel: set-parallel Pallas witness_record vs the sequential
-       oracle on a collision-heavy classed batch (8 hot keys, 64 sets, INCR
-       stacks + SET/INCR mixes) — accept lanes and all table planes equal.
+    2. record kernel: ``gang_record`` vs the ``ref_gang_record`` oracle on
+       a collision-heavy classed batch (8 hot keys, 64 sets, INCR stacks +
+       SET/INCR mixes, one rpc per record) — reason codes and all six table
+       planes equal.
     """
     rows = conflict_matrix_np()
     for a in range(N_CLASSES):
@@ -114,19 +116,21 @@ def check_parity(n_queries: int = 256, seed: int = 7) -> int:
     # Mix mergeable INCR runs with plain SETs and DELs on the same keys.
     q_cls = rng.choice(np.array([0, 1, 2, 2, 2, 5], dtype=np.int32),
                        size=n_queries)
-    table = WitnessTable.empty(64, 16)
-    acc_ref, t_ref = ref_witness_record(
-        table, np.asarray(q_hi), np.asarray(q_lo), np.asarray(q_cls))
-    acc_dev, t_dev = witness_record(
-        table, np.asarray(q_hi), np.asarray(q_lo), np.asarray(q_cls))
-    assert np.array_equal(np.asarray(acc_ref), np.asarray(acc_dev)), (
-        "accept lanes diverge: Pallas record kernel vs sequential oracle"
+    table = GangTable.empty(64, 16)
+    rpc_lo = np.arange(1, n_queries + 1, dtype=np.uint32)
+    rsn_ref, t_ref = ref_gang_record(table, 64, [
+        (0, (0, int(rpc_lo[i])), [(int(q_hi[i]), int(q_lo[i]), int(q_cls[i]))])
+        for i in range(n_queries)])
+    rsn_dev, _qh, _ql, t_dev = gang_record(
+        table, 64, q_hi, q_lo, np.zeros(n_queries, np.int32),
+        np.zeros(n_queries, np.uint32), rpc_lo, q_cls)
+    assert np.array_equal(np.asarray(rsn_ref), np.asarray(rsn_dev)), (
+        "reason codes diverge: Pallas record kernel vs oracle"
     )
-    for name in ("keys_hi", "keys_lo", "occ"):
-        a = np.asarray(getattr(t_ref, name))
-        b = np.asarray(getattr(t_dev, name))
-        assert np.array_equal(a, b), f"table plane {name} diverges"
-    n_acc = int(np.asarray(acc_ref).sum())
+    for name, a, b in zip(GangTable._fields, t_ref, t_dev):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), \
+            f"table plane {name} diverges"
+    n_acc = int(np.isin(np.asarray(rsn_ref), (1, 2)).sum())
     # The batch is built to exercise both verdicts; an all-accept or
     # all-reject run means the collision setup regressed.
     assert 0 < n_acc < n_queries, (

@@ -12,10 +12,11 @@ Four claims, the first three asserted:
   2. **Untouched slots never leave the 1-RTT fast path** — the fast-path
      ratio of ops on non-moving slots during the migration stays within 5%
      of the pre-reshard steady state (per-window timeline reported).
-  3. **Routing parity** — the Pallas ``shard_route`` table gather matches
-     the Python ``SlotRouter`` bit-for-bit on random slot maps (including
-     mid-migration-shaped ones), and the round-robin default map matches
-     the legacy mod-N placement for power-of-two shard counts.
+  3. **Routing parity** — the device's slot-table gather (the routing
+     stage of ``gang_fastpath_batch``) matches the Python ``SlotRouter``
+     bit-for-bit on random slot maps (including mid-migration-shaped ones),
+     and the round-robin default map matches the legacy mod-N placement for
+     power-of-two shard counts.
   4. **Hot-shard auto-split beats the static skew80 line** — per-slot op
      counters from a skewed instant-cluster run feed ``rebalance``; the
      rebalanced slot map re-runs fig_scaling's skew80 scenario in the timed
@@ -26,11 +27,12 @@ from __future__ import annotations
 
 import argparse
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ShardedCluster, SlotRouter
 from repro.core.types import keyhash
-from repro.kernels import shard_route
+from repro.kernels import GangTable, gang_fastpath_batch
 from repro.sim import (
     ShardSkewedWorkload,
     run_migration_scenario,
@@ -43,8 +45,25 @@ from .common import emit
 # ---------------------------------------------------------------------------
 # 3. routing parity on random slot maps (assertion)
 # ---------------------------------------------------------------------------
+def device_route(hi, lo, slot_map, max_shards: int = 8) -> np.ndarray:
+    """Shard of each RAW key by ``gang_fastpath_batch``'s slot gather, on a
+    gang of one 16-set witness lane per shard; no op is predicted to
+    execute, so nothing enters the (empty) rings."""
+    n = len(hi)
+    ring = jnp.zeros((max_shards, 16), jnp.uint32)
+    empty = np.zeros(max_shards, np.int32)
+    res = gang_fastpath_batch(
+        GangTable.empty(16, 4, max_shards), 16, hi, lo,
+        np.zeros(n, np.uint32), np.arange(1, n + 1, dtype=np.uint32),
+        np.zeros(n, np.int32), slot_map,
+        np.arange(max_shards, dtype=np.int32)[:, None], ring, ring, empty,
+        empty)
+    return res.shard_ids
+
+
 def check_route_parity(n_keys: int = 400, seed: int = 5) -> int:
-    """SlotRouter <-> shard_route bit-exactness on random slot maps."""
+    """SlotRouter <-> device slot gather bit-exactness on random slot
+    maps."""
     r = np.random.default_rng(seed)
     keys = [f"user{i}" for i in range(n_keys)] + list(range(64))
     khs = [keyhash(k) for k in keys]
@@ -55,7 +74,7 @@ def check_route_parity(n_keys: int = 400, seed: int = 5) -> int:
         for n_shards in (2, 3, 4, 7):
             slot_map = r.integers(0, n_shards, n_slots).astype(np.int32)
             router = SlotRouter(list(slot_map), n_shards=n_shards)
-            dev = np.asarray(shard_route(hi, lo, slot_map=slot_map))
+            dev = device_route(hi, lo, slot_map)
             py = np.array([router.shard_of(k) for k in keys])
             np.testing.assert_array_equal(dev, py)
             cases += 1
@@ -63,7 +82,7 @@ def check_route_parity(n_keys: int = 400, seed: int = 5) -> int:
     # (the pre-slot-map placement this change must not disturb).
     legacy_low = np.array([_mix_low(h) for h in khs], np.uint64)
     for n_shards in (1, 2, 4):
-        dev = np.asarray(shard_route(hi, lo, n_shards))
+        dev = device_route(hi, lo, SlotRouter.uniform(n_shards).slot_map)
         np.testing.assert_array_equal(dev, (legacy_low % n_shards)
                                       .astype(np.int32))
         cases += 1

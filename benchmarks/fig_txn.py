@@ -1,7 +1,7 @@
 """Transaction figure: the RIFL-identified mini-transaction subsystem
 (repro.core.txn) over the per-shard CURP fast paths.
 
-Four claims, measured (the first three asserted, not just reported):
+Three claims, measured (the first two asserted, not just reported):
 
   1. **Atomicity under crashes** — coordinator crashes injected at every
      2PC message stage (prepare-sent / prepared / commit-sent), with and
@@ -12,13 +12,7 @@ Four claims, measured (the first three asserted, not just reported):
      shard keep the 1-RTT fast path: their fast-path ratio matches the
      fig_scaling level (~1.0 on an uncontended workload), while cross-shard
      transactions pay exactly one extra decide round.
-  3. **Transactional kernel probe** — a multi-key witness record resolves in
-     ONE device dispatch on accept AND reject (repro.kernels.txn_probe),
-     vs 2 dispatches for the record-then-rollback scheme it replaces; and
-     the probe is bit-exact with the Python witness's accept/reject
-     decisions on collision-heavy multi-key batches (plus slot-for-slot
-     with the jnp oracle).
-  4. **Abort rate vs contention** — interleaved coordinators over a shrinking
+  3. **Abort rate vs contention** — interleaved coordinators over a shrinking
      hot keyset: the intent-lock abort rate rises with contention (reported
      as a sweep).
 
@@ -30,23 +24,8 @@ from __future__ import annotations
 import argparse
 import time
 
-import numpy as np
-
-from repro.core import (
-    DeviceWitness,
-    ShardedCluster,
-    TxnStatus,
-    Witness,
-)
+from repro.core import ShardedCluster, TxnStatus
 from repro.core.txn import abort_op, commit_op, prepare_op
-from repro.core.types import Op, OpType
-from repro.kernels import (
-    WitnessTable,
-    dispatch_count,
-    ref_witness_record_txn,
-    reset_dispatch_count,
-    txn_probe,
-)
 from repro.sim import TXN_CRASH_STAGES, TxnWorkload, run_txn_crash_scenario
 
 from .common import emit
@@ -109,105 +88,7 @@ def txn_throughput(n_txns: int = 200, n_shards: int = 4) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# 3. transactional kernel probe: dispatches + parity (assertions)
-# ---------------------------------------------------------------------------
-def probe_dispatches() -> dict:
-    """One multi-key record = 1 dispatch via the txn probe (accept AND
-    reject), vs 2 on the reject path of the record-then-rollback scheme."""
-    def fresh():
-        w = DeviceWitness(256, 4)
-        w.start(master_id=1)
-        # Preload a conflicting record so the multi-key op REJECTS: key 7
-        # is held by another rpc.
-        w.record(1, (7,), (999, 1), Op(OpType.SET, ("c",), ("v",), (999, 1)))
-        return w
-
-    multi = Op(OpType.MSET, ("a", "b", "c"), (1, 2, 3), (1000, 1))
-    khs = (5, 6, 7)   # key 7 conflicts
-
-    w = fresh()
-    reset_dispatch_count()
-    st_new = w._record_keys(khs, multi.rpc_id, multi)
-    new_reject = dispatch_count()
-
-    w = fresh()
-    reset_dispatch_count()
-    st_old = w._record_keys_rollback(khs, multi.rpc_id, multi)
-    old_reject = dispatch_count()
-    assert st_new == st_old, (st_new, st_old)
-
-    w = fresh()
-    reset_dispatch_count()
-    w._record_keys((5, 6, 8), (1001, 1), multi)   # no conflict: accepts
-    new_accept = dispatch_count()
-    reset_dispatch_count()
-    return {
-        "probe_dispatches_accept": new_accept,
-        "probe_dispatches_reject": new_reject,
-        "rollback_dispatches_reject": old_reject,
-    }
-
-
-def check_probe_parity(n_ops: int = 60, seed: int = 7) -> int:
-    """Collision-heavy multi-key batches: the DeviceWitness (txn probe
-    kernel) and the Python Witness must agree accept-for-accept, and the
-    kernel table must match the jnp oracle slot-for-slot.  Conflicts here
-    are same-key collisions (placement-independent), so both backends see
-    identical decisions despite their different set mappings."""
-    r = np.random.default_rng(seed)
-    py = Witness(1024, 4)
-    dv = DeviceWitness(1024, 4)
-    py.start(1)
-    dv.start(1)
-    cases = 0
-    for i in range(n_ops):
-        n_keys = int(r.integers(1, 5))
-        khs = tuple(int(k) for k in r.integers(0, 24, n_keys))
-        rpc = (50 + i, 1)
-        op = Op(OpType.MSET, tuple(f"k{k}" for k in khs),
-                tuple(range(n_keys)), rpc)
-        st_py = py.record(1, khs, rpc, op)
-        st_dv = dv.record(1, khs, rpc, op)
-        assert st_py == st_dv, (i, khs, st_py, st_dv)
-        # retry idempotence: same rpc, same keys -> same (accepting) verdict
-        if st_py.value == "ACCEPTED":
-            assert dv.record(1, khs, rpc, op) == py.record(1, khs, rpc, op)
-        cases += 1
-
-    # Kernel vs oracle: random ops against one evolving table.
-    from repro.kernels.ops import _pad_valid
-    from repro.kernels.ref import ref_keyhash2x32
-    import jax.numpy as jnp
-
-    table = WitnessTable.empty(64, 4)
-    oracle = WitnessTable.empty(64, 4)
-    for i in range(n_ops):
-        n_keys = int(r.integers(1, 6))
-        hi = r.integers(0, 4, n_keys).astype(np.uint32)
-        lo = r.integers(0, 4, n_keys).astype(np.uint32)
-        res = txn_probe(table, hi, lo)
-        table = res.table
-        qh, ql = ref_keyhash2x32(jnp.asarray(hi), jnp.asarray(lo))
-        qhp, qlp, ownp, valid = _pad_valid(
-            n_keys, np.asarray(qh), np.asarray(ql), np.zeros(n_keys, np.int32)
-        )
-        acc_r, _hit, oracle = ref_witness_record_txn(
-            oracle, jnp.asarray(qhp), jnp.asarray(qlp),
-            jnp.asarray(ownp), jnp.asarray(valid),
-        )
-        assert res.accepted == bool(np.asarray(acc_r)[0]), i
-        np.testing.assert_array_equal(np.asarray(table.occ),
-                                      np.asarray(oracle.occ))
-        np.testing.assert_array_equal(np.asarray(table.keys_hi),
-                                      np.asarray(oracle.keys_hi))
-        np.testing.assert_array_equal(np.asarray(table.keys_lo),
-                                      np.asarray(oracle.keys_lo))
-        cases += 1
-    return cases
-
-
-# ---------------------------------------------------------------------------
-# 4. abort rate vs contention (interleaved coordinators), per conflict policy
+# 3. abort rate vs contention (interleaved coordinators), per conflict policy
 # ---------------------------------------------------------------------------
 def _interleaved_round(cluster, pairs, policy: str) -> int:
     """Run N coordinators' transactions with prepare legs INTERLEAVED
@@ -319,7 +200,7 @@ def abort_sweep(n_rounds: int = 40, n_shards: int = 4,
 
 
 # ---------------------------------------------------------------------------
-# 5. timed 2PC: concurrent prepare fan-out vs sequential vs per-shard mset
+# 4. timed 2PC: concurrent prepare fan-out vs sequential vs per-shard mset
 # ---------------------------------------------------------------------------
 def timed_rounds(n_txns: int = 60, span: int = 3) -> dict:
     """True 2-round latency in the discrete-event transport: the fan-out
@@ -344,12 +225,6 @@ def timed_rounds(n_txns: int = 60, span: int = 3) -> dict:
 
 def main(smoke: bool = False) -> dict:
     crash_cases = check_crash_atomicity(n_txns=8 if smoke else 12)
-    parity_cases = check_probe_parity(n_ops=30 if smoke else 60)
-    disp = probe_dispatches()
-    assert disp["probe_dispatches_accept"] == 1, disp
-    assert disp["probe_dispatches_reject"] == 1, disp
-    assert disp["rollback_dispatches_reject"] == 2, disp
-
     thr = txn_throughput(n_txns=40 if smoke else 200)
     # Acceptance: single-shard txns keep the 1-RTT fast-path ratio
     # fig_scaling shows for uncontended uniform writes (~1.0).
@@ -372,9 +247,6 @@ def main(smoke: bool = False) -> dict:
     assert rates["wound-wait"][hottest] < rates["vote-no"][hottest], rates
     derived = {
         "crash_cases": crash_cases,
-        "parity_cases": parity_cases,
-        "probe_dispatches_reject": disp["probe_dispatches_reject"],
-        "rollback_dispatches_reject": disp["rollback_dispatches_reject"],
         **thr,
         **timed,
         **{f"abort_rate_hot{h}": rates["vote-no"][h] for h in hots},
@@ -391,7 +263,7 @@ def main(smoke: bool = False) -> dict:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny counts (CI wiring + atomicity/parity "
+                    help="tiny counts (CI wiring + atomicity "
                          "assertions, not a measurement)")
     args = ap.parse_args()
     d = main(smoke=args.smoke)

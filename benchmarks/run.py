@@ -54,8 +54,7 @@ def write_bench_json(results, path: pathlib.Path = BENCH_JSON) -> None:
                 else time.time()
     # Perf trajectory: for every numeric metric that already had a recorded
     # value, keep the previous number next to the new one so a driver can
-    # read deltas (e.g. fig_fastpath proto_device_kops across PRs) without
-    # diffing git history.
+    # read deltas across PRs without diffing git history.
     deltas = {}
     for name, _dt, derived in results:
         prev = figures.get(name, {}).get("derived", {})
@@ -119,9 +118,6 @@ def write_bench_json(results, path: pathlib.Path = BENCH_JSON) -> None:
     with hist_path.open("a") as fh:
         fh.write(json.dumps(hist_line, sort_keys=True) + "\n")
     print(f"appended {hist_path.name} ({len(results)} figures)")
-    fp = deltas.get("fig_fastpath", {}).get("proto_device_kops")
-    if fp:
-        print(f"proto_device_kops: {fp['prev']:.2f} -> {fp['now']:.2f}")
 
 
 def main() -> None:
@@ -134,7 +130,6 @@ def main() -> None:
         fig11_witness_capacity,
         fig12_batchsize,
         fig_crdt,
-        fig_fastpath,
         fig_migration,
         fig_obs,
         fig_scaling,
@@ -153,7 +148,6 @@ def main() -> None:
         ("fig11_witness_capacity", fig11_witness_capacity.main),
         ("fig12_batchsize", fig12_batchsize.main),
         ("fig_scaling", fig_scaling.main),
-        ("fig_fastpath", fig_fastpath.main),
         ("fig_txn", fig_txn.main),
         ("fig_migration", fig_migration.main),
         ("fig_crdt", fig_crdt.main),

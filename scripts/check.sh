@@ -5,15 +5,11 @@
 #   scripts/check.sh -m 'not slow'   # extra pytest args pass through
 #
 # The smoke runs use tiny op counts: they validate that the sharded,
-# fused-fast-path, transaction, and live-migration benchmarks still run
-# end-to-end (fig_scaling stays monotonic; fig_fastpath keeps its bit-exact
-# parity assertions — set-parallel kernel vs oracle AND device witness vs
-# Python witness on the dup/stale-gc/multi-key failure paths — plus its
-# 1-dispatch-per-kernel-batch and 1-dispatch-per-cluster-batch invariants
-# (single- and cross-shard, device backend); fig_txn keeps its
-# crash-atomicity, 1-dispatch transactional-probe, single-shard fast-path,
-# fan-out-beats-sequential, and wound/wait-cuts-aborts assertions;
-# fig_migration keeps its zero-lost-writes, strict-linearizability,
+# transaction, and live-migration benchmarks still run end-to-end
+# (fig_scaling stays monotonic; fig_txn keeps its crash-atomicity,
+# single-shard fast-path, fan-out-beats-sequential, and
+# wound/wait-cuts-aborts assertions; fig_migration keeps its
+# zero-lost-writes, strict-linearizability,
 # untouched-slot fast-ratio, slot-route parity, and rebalance-beats-static
 # assertions; fig_crdt keeps the merge-lattice separation — hot-counter
 # INCR fast-frac >=0.95 vs plain SET <=0.2 at skew 1.0 — the 16x16
@@ -40,7 +36,6 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 python -m pytest -x -q "$@"
 python -m benchmarks.fig_scaling --smoke
-python -m benchmarks.fig_fastpath --smoke
 python -m benchmarks.fig_txn --smoke
 python -m benchmarks.fig_migration --smoke
 python -m benchmarks.fig_crdt --smoke

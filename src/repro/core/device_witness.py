@@ -36,9 +36,7 @@ Multi-key ops resolve all-or-nothing through the grouped record kernel
 (repro.kernels.gang_record_groups): every key's conflict/capacity verdict is
 computed against the pre-op table and writes happen only when the whole op
 accepted — ONE dispatch whether the op accepts or rejects, for a whole batch
-of multi-key ops at once.  The pre-refactor record-then-rollback scheme
-(2 dispatches on the reject path) is kept as ``_record_keys_rollback`` for
-benchmarks/fig_txn.py's old-vs-new comparison.
+of multi-key ops at once.
 """
 from __future__ import annotations
 
@@ -272,51 +270,6 @@ class DeviceWitness:
                 for k in range(len(pairs))]
         return self._settle(int(res.reasons[0]), keys, rpc_id, request,
                             [c for _kh, c in pairs])
-
-    def _record_keys_rollback(self, key_hashes: Tuple[int, ...], rpc_id: RpcId,
-                              request: Op) -> RecordStatus:
-        """Pre-refactor record-then-rollback scheme, kept only for the
-        old-vs-new dispatch comparison in benchmarks/fig_txn.py: the keys
-        record individually (one gang_record dispatch) and any accepted prefix
-        is rolled back by a second gc dispatch when the op rejects."""
-        from repro.kernels import gang_gc, gang_record
-
-        khs = list(dict.fromkeys(key_hashes))
-        hi, lo = _lanes(khs)
-        K = len(khs)
-        lanes = np.full(K, self.lane, np.int32)
-        rhi = np.full(K, rpc_id[0] & _M32, np.uint32)
-        rlo = np.full(K, rpc_id[1] & _M32, np.uint32)
-        rsn, qh, ql, table = gang_record(
-            self.gang.table, self.n_sets, hi, lo, lanes, rhi, rlo
-        )
-        self.stats["kernel_batches"] += 1
-        ok = all(int(r) in (_R_INSERT, _R_DUP) for r in rsn)
-        if ok:
-            self.gang.table = table
-            for k in range(K):
-                key = (int(qh[k]), int(ql[k]))
-                self._held.setdefault(key, {})[rpc_id] = _Held(
-                    rpc_id, request, op_class=0
-                )
-            self.stats["accepts"] += 1
-            return RecordStatus.ACCEPTED
-        # Roll back freshly inserted keys (the second dispatch on reject);
-        # dup hits predate this op and must survive.  No aging: a rollback
-        # is not a §4.5 gc round.
-        ins = [k for k in range(K) if int(rsn[k]) == _R_INSERT]
-        if ins:
-            _clr, table = gang_gc(
-                table, self.n_sets,
-                hi[ins], lo[ins], rhi[ins], rlo[ins], lanes[ins],
-                np.zeros(self.gang.n_lanes, np.int32), do_age=False,
-            )
-        self.gang.table = table
-        if any(int(r) == _R_CONFLICT for r in rsn):
-            self.stats["rejects_conflict"] += 1
-        else:
-            self.stats["rejects_full"] += 1
-        return RecordStatus.REJECTED
 
     # -- master -> witness ----------------------------------------------------
     def gc(self, entries: Tuple[Tuple[int, RpcId], ...]) -> GcResp:

@@ -10,9 +10,9 @@ DISJOINT fields the same hash.  This module is the single source of truth
 for that widened commutativity relation, consulted by every mirrored layer:
 
 - ``Witness.record`` / ``commutes_with_all`` (core/witness.py),
-- the device witness gang + fused fast-path kernels (kernels/ops.py,
-  kernels/witness_record.py, kernels/conflict_scan.py) — the kernels bake
-  ``CONFLICT_MATRIX`` in as a static constant and consult it in-dispatch,
+- the device witness gang and the fused cluster batch (kernels/ops.py,
+  kernels/witness_record.py) — the kernels bake ``CONFLICT_MATRIX`` in as
+  a static constant and consult it in-dispatch,
 - the master's unsynced-window check (core/master.py) and witness-replay
   recovery merge-fold.
 

@@ -8,14 +8,14 @@ replays that shard's witnesses.
 Three pieces live here:
 
   * ``SlotRouter`` — slot-table placement.  The mix is the pure-Python
-    mirror of the Pallas ``keyhash2x32`` kernel (repro.kernels.keyhash): the
+    mirror of the device's keyhash2x32 (repro.kernels.ref_keyhash2x32): the
     64-bit splitmix key hash is split into (hi, lo) uint32 lanes, pushed
     through the murmur3 fmix32 chain, and the low output lane mod
     ``n_slots`` picks a SLOT; a slot -> shard table names the owner.  Live
     reconfiguration (repro.core.migration) moves slots between shards by
-    editing the table — the hash never changes.  ``repro.kernels.ops.
-    shard_route`` computes the same placement batched on-device (table
-    gather); Python and Pallas must agree bit-for-bit on ANY slot map.
+    editing the table — the hash never changes.  ``repro.kernels.
+    gang_fastpath_batch`` computes the same placement batched on-device
+    (table gather); host and device must agree bit-for-bit on ANY slot map.
     ``KeyRouter`` survives as the mod-N compatibility constructor (the
     round-robin default map).
   * ``ShardGroup`` — one master + its witness group + its backups, with the
@@ -97,7 +97,8 @@ def mix2x32(hi: int, lo: int) -> Tuple[int, int]:
 
 
 # Default slot-table size.  Must match repro.kernels.ops.DEFAULT_N_SLOTS —
-# the Pallas shard_route gather and this router share the table layout.
+# the device's slot gather (gang_fastpath_batch) and this router share the
+# table layout.
 N_SLOTS = 256
 
 
@@ -132,11 +133,11 @@ class SlotRouter:
     SLOT, and ``slot_map[slot]`` names the owning shard.  The slot is the
     unit of live migration (repro.core.migration): a handover edits the
     table (``assign``) and bumps ``version`` so cached placements (e.g. the
-    serving store's session cache) know to refetch.  ``repro.kernels.ops.
-    shard_route`` computes the same placement batched on-device from the
-    same table.  A key with a hash tag (``hash_tag``) hashes only its tag;
-    the device placement hashes whole keys, so the fused batch path
-    declines tagged keys.
+    serving store's session cache) know to refetch.  ``repro.kernels.
+    gang_fastpath_batch`` computes the same placement batched on-device
+    from the same table.  A key with a hash tag (``hash_tag``) hashes only
+    its tag; the device placement hashes whole keys, so the fused batch
+    path declines tagged keys.
     """
 
     def __init__(self, slot_map: Sequence[int],

@@ -6,7 +6,7 @@ dataclasses through the same state machines.
 
 Key hashing follows the paper (§4.2): commutativity checks compare 64-bit
 hashes of primary keys, not full keys.  We use splitmix64, the same avalanche
-mixer validated in the Pallas kernel (repro.kernels.keyhash).
+mixer validated in the device path (repro.kernels.ref_keyhash2x32).
 """
 from __future__ import annotations
 

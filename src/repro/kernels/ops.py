@@ -1,45 +1,36 @@
-"""jit'd public wrappers around the CURP Pallas kernels.
+"""jit'd public wrappers around the witness-gang Pallas kernels.
 
-Each op pads/validates shapes, picks interpret mode automatically (interpret
-on CPU — the kernels target TPU), and exposes a pytree-friendly API used by
-the device-side witness (repro.core.device_witness) and the fast-path
-benchmarks.
+Each op pads its inputs to a power-of-two bucket on the host (so the jit
+cache stays O(log B) across the batch sizes the protocol layer produces),
+picks interpret mode automatically (interpret on the CPU; the kernels target
+the TPU), launches exactly one jitted program, and returns numpy outputs in
+caller order.  The ops, each ONE dispatch:
 
-Fast-path pipeline
-------------------
-``fastpath_batch`` is the one-dispatch-per-batch op: it fuses
+  * ``gang_record`` — a batch of single-key records, each at its own gang
+    lane under its own rpc identity;
+  * ``gang_record_groups`` — a batch of per-group all-or-nothing multi-key
+    records (MSET, HMSET pairs, transaction legs);
+  * ``gang_gc`` — rpc-matched clears plus §4.5 aging of whole lanes;
+  * ``gang_fastpath_batch`` — the fused cluster batch: hash -> slot route
+    -> ring conflict scan -> ring append -> record at every target shard's
+    f witness lanes.
 
-    keyhash2x32 -> shard_route -> witness_record -> conflict_scan
-
-into a single jitted call whose only pallas_call is the fused set-parallel
-record+scan kernel (the hash/route/sort prep is plain XLA that fuses around
-it).  The per-op path costs 3-4 device dispatches per update (hash, record,
-scan, sometimes route); the fused path costs exactly one per *batch*.
-``dispatch_count()`` exposes a host-side counter that fig_fastpath uses to
-demonstrate the difference.
-
-The set-parallel prep (``_setpar_prep``) buckets a query batch by probed set:
-a stable sort by ``lo & (S-1)``, a rank-within-set computation, and a second
-stable sort by rank — after which "round" r (the r-th query of every set) is
-one contiguous span and the kernel resolves whole rounds vectorized across
-sets.  See repro/kernels/witness_record.py for the kernel-side story and the
-buffer-donation contract.
+Keys arrive as RAW 64-bit keyhash lanes and are mixed in the jit
+(``ref.ref_keyhash2x32``); the host mirrors the same mix with
+``ref.np_keyhash2x32``.  See witness_record.py for the kernels' layout and
+README.md next to this file for how the protocol layer drives them.
 """
 from __future__ import annotations
 
 import functools
-from typing import List, NamedTuple, Tuple
+from typing import List, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .conflict_scan import conflict_scan_pallas
-from .keyhash import keyhash2x32_pallas
 from .ref import (
-    U32,
     GangTable,
-    WitnessTable,
     conflict_matrix_np,
     gang_rows,
     matrix_rows,
@@ -48,25 +39,11 @@ from .ref import (
     ref_gang_gc,
     ref_gang_record,
     ref_keyhash2x32,
-    ref_witness_gc,
-    ref_witness_record,
-    ref_witness_record_txn,
 )
-from .witness_record import (
-    DEFAULT_TILE_SETS,
-    _unsort,
-    fastpath_record_scan_pallas,
-    gang_gc_pallas,
-    gang_record_pallas,
-    smem_fits,
-    witness_gc_pallas,
-    witness_record_seq_pallas,
-    witness_record_setpar_pallas,
-    witness_record_txn_pallas,
-)
+from .witness_record import gang_gc_pallas, gang_record_pallas, smem_fits
 
 # ---------------------------------------------------------------------------
-# Host-side dispatch accounting (benchmarks read this; see module docstring)
+# Host-side dispatch accounting
 # ---------------------------------------------------------------------------
 # Backed by the telemetry metrics registry ("kernels.dispatches") so the
 # flight recorder sees device-program launches next to the protocol counters;
@@ -88,11 +65,11 @@ def dispatch_count() -> int:
     Structural accounting, not a device-side trace: each public op wraps
     exactly one jitted program (every prep/pad step is host-side numpy, so
     the jitted call is the only device program a wrapper launches), and the
-    counter increments once per wrapper call.  fig_fastpath uses it to show
-    the API-level amortization — 3 program launches per op on the per-op
-    path vs 1 per *batch* on the fused path.  It does not see launches made
-    outside this module, nor would it catch a second pallas_call added
-    inside an impl (the parity tests pin the impl's behavior instead).
+    counter increments once per wrapper call.  The benchmark's
+    ``dispatches_per_batch`` and the one-dispatch tests read it.  It does
+    not see launches made outside this module, nor would it catch a second
+    pallas_call added inside an impl (the parity tests pin the impl's
+    behavior instead).
     """
     from repro.core.telemetry import registry
 
@@ -109,88 +86,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# ---------------------------------------------------------------------------
-# Slot-table routing (live reconfiguration)
-# ---------------------------------------------------------------------------
-# Default size of the slot table: keys hash to one of DEFAULT_N_SLOTS slots
-# (mixed low lane mod n_slots) and a slot -> shard table names the owner.
-# Migration moves SLOTS between shards by editing the table — the hash never
-# changes, so only the gather array does.  Must match
-# repro.core.shard.N_SLOTS (the pure-Python mirror).
+# Keys hash to one of DEFAULT_N_SLOTS slots (mixed low lane mod n_slots) and
+# a slot -> shard table names the owner; ``gang_fastpath_batch`` takes the
+# table as a traced input, so a live slot handover never recompiles.  Must
+# match repro.core.shard.N_SLOTS (the pure-Python mirror).
 DEFAULT_N_SLOTS = 256
-
-
-def default_slot_map(n_shards: int, n_slots: int = DEFAULT_N_SLOTS) -> np.ndarray:
-    """Round-robin slot -> shard table: slot i is owned by shard i % N.
-
-    For power-of-two shard counts that divide ``n_slots`` this reproduces
-    the pre-slot-map ``% n_shards`` placement exactly
-    ((h % n_slots) % n == h % n when n | n_slots).
-    """
-    if n_shards < 1:
-        raise ValueError(f"n_shards must be >= 1, got {n_shards}")
-    return (np.arange(n_slots, dtype=np.int32) % n_shards).astype(np.int32)
-
-
-def _pad_to(x: jnp.ndarray, m: int, fill=0) -> Tuple[jnp.ndarray, int]:
-    n = x.shape[0]
-    pad = (-n) % m
-    if pad:
-        x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
-    return x, n
-
-
-# ---------------------------------------------------------------------------
-# Set-parallel prep: bucket the batch by probed set (traced; fuses into the
-# surrounding jit)
-# ---------------------------------------------------------------------------
-def _setpar_prep(n_sets: int, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-                 q_valid: jnp.ndarray | None = None):
-    """Sort a query batch into round-contiguous set-parallel order.
-
-    Returns (qhi_f, qlo_f, sets_f, round_start, n_rounds, perm) where
-    ``perm`` maps final positions -> original batch positions,
-    ``round_start[r]`` is the offset of round r in the final order (round r
-    holds every set's r-th query, set-ascending), and ``n_rounds`` is a [1]
-    int32 array (the longest per-set run).
-
-    ``q_valid`` marks bucket-padding lanes: invalid queries get the
-    out-of-range set id ``n_sets`` and rank B, so they sort to the tail,
-    fall beyond ``n_rounds``, and are never touched by the kernel (their
-    accept bit stays 0).  Permute any additional per-query arrays with the
-    returned ``perm``.
-    """
-    (B,) = q_hi.shape
-    sets = (q_lo & jnp.uint32(n_sets - 1)).astype(jnp.int32)       # [B]
-    if q_valid is None:
-        valid = jnp.ones((B,), jnp.int32)
-    else:
-        valid = q_valid.astype(jnp.int32)
-        sets = jnp.where(valid == 1, sets, jnp.int32(n_sets))
-    order1 = jnp.argsort(sets, stable=True)                        # by set
-    sets_s = sets[order1]
-    seg_count = jnp.zeros((n_sets,), jnp.int32).at[sets].add(
-        valid, mode="drop"
-    )
-    seg_start = jnp.cumsum(seg_count) - seg_count                  # exclusive
-    rank_s = jnp.where(
-        sets_s < n_sets,
-        jnp.arange(B, dtype=jnp.int32)
-        - seg_start[jnp.clip(sets_s, 0, n_sets - 1)],
-        jnp.int32(B),
-    )
-    # Stable sort by rank keeps the set-ascending order within each round.
-    order2 = jnp.argsort(rank_s, stable=True)
-    perm = order1[order2]
-    rank_f = rank_s[order2]
-    round_start = jnp.searchsorted(
-        rank_f, jnp.arange(B + 1, dtype=jnp.int32)
-    ).astype(jnp.int32)
-    # Longest VALID run (invalid lanes carry the rank-B sentinel).
-    n_rounds = (
-        jnp.max(jnp.where(rank_f >= B, jnp.int32(-1), rank_f)) + 1
-    ).reshape((1,))
-    return q_hi[perm], q_lo[perm], sets_s[order2], round_start, n_rounds, perm
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -219,321 +119,6 @@ def _pad_valid(B: int, *arrays):
         for a in arrays
     )
     return out + (valid,)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "tile_sets"))
-def _witness_record_impl(table: WitnessTable, q_hi, q_lo, q_cls, q_valid,
-                         interpret: bool, tile_sets: int):
-    S, _W = table.occ.shape
-    qhi_f, qlo_f, sets_f, rstart, n_rounds, perm = _setpar_prep(
-        S, q_hi, q_lo, q_valid
-    )
-    acc_f, new_table = witness_record_setpar_pallas(
-        table, qhi_f, qlo_f, sets_f, q_cls[perm], rstart, n_rounds,
-        tile_sets=tile_sets, interpret=interpret,
-    )
-    return _unsort(perm, acc_f), new_table
-
-
-# ---------------------------------------------------------------------------
-# Public ops
-# ---------------------------------------------------------------------------
-def keyhash2x32(hi, lo, *, block: int = 1024, interpret: bool | None = None):
-    """Batched 64-bit-equivalent key hash as (hi, lo) uint32 lanes."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    _count_dispatch()
-    hi = jnp.asarray(hi, U32)
-    lo = jnp.asarray(lo, U32)
-    hp, n = _pad_to(hi, block)
-    lp, _ = _pad_to(lo, block)
-    oh, ol = keyhash2x32_pallas(hp, lp, block=block, interpret=interpret)
-    return oh[:n], ol[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("n_slots", "block", "interpret"))
-def _shard_route_impl(hi, lo, slot_map, n_slots: int, block: int,
-                      interpret: bool):
-    _oh, ol = keyhash2x32_pallas(hi, lo, block=block, interpret=interpret)
-    slots = (ol % jnp.uint32(n_slots)).astype(jnp.int32)
-    return slot_map[slots]
-
-
-def shard_route(hi, lo, n_shards: int | None = None, *,
-                slot_map=None, n_slots: int = DEFAULT_N_SLOTS,
-                block: int = 1024,
-                interpret: bool | None = None) -> jnp.ndarray:
-    """Batched key -> shard placement by SLOT-TABLE GATHER: keyhash2x32 mix,
-    low lane mod ``n_slots`` picks a slot, ``slot_map[slot]`` names the
-    shard.  Must agree bit-for-bit with the pure-Python
-    ``repro.core.shard.SlotRouter`` (same fmix32 chain, same table) so
-    device-side routing and protocol-side placement never disagree — on any
-    slot map, including mid-migration ones.  Returns [N] int32 shard ids.
-
-    ``slot_map`` is a traced array input, NOT a static arg: editing it (a
-    live slot handover) never recompiles.  With only ``n_shards`` given, the
-    round-robin ``default_slot_map`` is used — the mod-N compatibility
-    placement.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    if slot_map is None:
-        if n_shards is None:
-            raise ValueError("shard_route needs n_shards or slot_map")
-        slot_map = default_slot_map(n_shards, n_slots)
-    slot_map = jnp.asarray(np.asarray(slot_map, np.int32))
-    n_slots = int(slot_map.shape[0])
-    _count_dispatch()
-    hi = jnp.asarray(hi, U32)
-    lo = jnp.asarray(lo, U32)
-    hp, n = _pad_to(hi, block)
-    lp, _ = _pad_to(lo, block)
-    out = _shard_route_impl(hp, lp, slot_map, n_slots, block, interpret)
-    return out[:n]
-
-
-def witness_record(table: WitnessTable, q_hi, q_lo, q_cls=None,
-                   *, interpret: bool | None = None,
-                   tile_sets: int = DEFAULT_TILE_SETS):
-    """Batched record RPCs against a device-side witness table, resolved by
-    the set-parallel kernel (order preserved per set; sets in parallel).
-
-    ``q_cls`` is the optional per-query merge-lattice op class
-    (repro.core.merge; default SET, which reproduces the classless any-hit
-    conflict rule).  Returns (accepted [B] int32, new_table).  Table buffers
-    are aliased in-program (no intermediate copy inside the dispatch);
-    rebind ``table`` to the returned table (see witness_record.py for the
-    exact contract).
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    _count_dispatch()
-    q_hi = np.asarray(q_hi, np.uint32)
-    q_lo = np.asarray(q_lo, np.uint32)
-    (B,) = q_hi.shape
-    q_cls = (np.zeros((B,), np.int32) if q_cls is None
-             else np.asarray(q_cls, np.int32))
-    q_hi, q_lo, q_cls, valid = _pad_valid(B, q_hi, q_lo, q_cls)
-    acc, new_table = _witness_record_impl(
-        table, q_hi, q_lo, jnp.asarray(q_cls), valid, interpret, tile_sets
-    )
-    return acc[:B], new_table
-
-
-def witness_record_seq(table: WitnessTable, q_hi, q_lo,
-                       *, interpret: bool | None = None):
-    """Pre-refactor sequential-kernel record path (whole batch = one ordered
-    fori_loop).  Kept for old-vs-new benchmarking and differential tests."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    _count_dispatch()
-    q_hi = jnp.asarray(q_hi, U32)
-    q_lo = jnp.asarray(q_lo, U32)
-    return witness_record_seq_pallas(table, q_hi, q_lo, interpret=interpret)
-
-
-def witness_gc(table: WitnessTable, g_hi, g_lo,
-               *, interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
-    _count_dispatch()
-    return witness_gc_pallas(
-        table, jnp.asarray(g_hi, U32), jnp.asarray(g_lo, U32),
-        interpret=interpret,
-    )
-
-
-def conflict_scan(w_hi, w_lo, w_valid, q_hi, q_lo, q_cls=None,
-                  *, block_b: int = 256, block_u: int = 512,
-                  interpret: bool | None = None):
-    """Commutativity check of B queries vs a U-entry unsynced window.
-
-    ``w_valid`` packs each window entry's merge-lattice class (0 invalid,
-    else 1 + class; legacy 0/1 callers get class SET) and ``q_cls`` is the
-    optional per-query class — same in-dispatch matrix consult as the
-    witness record kernels.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    _count_dispatch()
-    w_hi = jnp.asarray(w_hi, U32)
-    w_lo = jnp.asarray(w_lo, U32)
-    w_valid = jnp.asarray(w_valid, jnp.int32)
-    q_hi = jnp.asarray(q_hi, U32)
-    q_lo = jnp.asarray(q_lo, U32)
-    if q_cls is None:
-        q_cls = jnp.zeros(q_hi.shape, jnp.int32)
-    else:
-        q_cls = jnp.asarray(q_cls, jnp.int32)
-    whp, u = _pad_to(w_hi, block_u)
-    wlp, _ = _pad_to(w_lo, block_u)
-    wvp, _ = _pad_to(w_valid, block_u)      # padding is valid=0 => no hits
-    qhp, b = _pad_to(q_hi, block_b)
-    qlp, _ = _pad_to(q_lo, block_b)
-    qcp, _ = _pad_to(q_cls, block_b)
-    out = conflict_scan_pallas(
-        whp, wlp, wvp, qhp, qlp, qcp,
-        block_b=block_b, block_u=block_u, interpret=interpret,
-    )
-    return out[:b]
-
-
-# ---------------------------------------------------------------------------
-# Fused fast path: hash -> route -> record -> conflict scan, one dispatch
-# ---------------------------------------------------------------------------
-class FastPathResult(NamedTuple):
-    """Result of one fused fast-path batch (all [B], caller order)."""
-    accepted: jnp.ndarray    # witness accept bit per op
-    conflicts: jnp.ndarray   # master-window conflict bit per op
-    shard_ids: jnp.ndarray   # keyhash2x32 placement (int32)
-    q_hi: jnp.ndarray        # mixed keyhash lanes — callers extend their
-    q_lo: jnp.ndarray        # unsynced window with these on accept
-    table: WitnessTable      # updated witness table (donated buffers)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n_slots", "interpret", "tile_sets")
-)
-def _fastpath_impl(table, w_hi, w_lo, w_valid, k_hi, k_lo, k_cls, k_valid,
-                   slot_map, n_slots: int, interpret: bool, tile_sets: int):
-    # Hash: bit-exact with the keyhash2x32 Pallas kernel (same fmix32 chain);
-    # inlined here so XLA fuses it with the sort/segment prep.
-    qh, ql = ref_keyhash2x32(k_hi, k_lo)
-    # Slot-table routing: the gather is plain XLA fused around the single
-    # pallas_call; the map is a traced input, so a live slot handover (table
-    # edit) never recompiles this program.
-    slots = (ql % jnp.uint32(n_slots)).astype(jnp.int32)
-    shard_ids = slot_map[slots]
-    S, _W = table.occ.shape
-    qhi_f, qlo_f, sets_f, rstart, n_rounds, perm = _setpar_prep(
-        S, qh, ql, k_valid
-    )
-    acc_f, con_f, new_table = fastpath_record_scan_pallas(
-        table, qhi_f, qlo_f, sets_f, k_cls[perm], rstart, n_rounds,
-        w_hi, w_lo, w_valid, tile_sets=tile_sets, interpret=interpret,
-    )
-    return (_unsort(perm, acc_f), _unsort(perm, con_f), shard_ids,
-            qh, ql, new_table)
-
-
-def fastpath_batch(
-    table: WitnessTable, key_hi, key_lo, key_cls=None,
-    *, window_hi=None, window_lo=None, window_valid=None,
-    n_shards: int = 1, slot_map=None, n_slots: int = DEFAULT_N_SLOTS,
-    interpret: bool | None = None,
-    tile_sets: int = DEFAULT_TILE_SETS,
-) -> FastPathResult:
-    """One fused device dispatch for a whole update batch.
-
-    ``key_hi``/``key_lo`` are the RAW 64-bit keyhash lanes (types.keyhash
-    split into uint32 halves); the op mixes them (keyhash2x32), derives shard
-    placement by slot-table gather (``slot_map``, or the round-robin default
-    for ``n_shards``; the map is a traced input, so live slot handovers
-    never recompile), resolves witness accept/reject via the set-parallel
-    kernel, and checks commutativity against the master's unsynced window —
-    all in a single jitted program containing a single pallas_call.
-
-    ``key_cls`` is the optional per-op merge-lattice class (default SET);
-    it widens BOTH in-dispatch decisions — witness record and window scan —
-    with the same matrix as the Python path.  The window arguments are
-    MIXED lanes (as previously returned in ``FastPathResult.q_hi/q_lo``),
-    with ``window_valid`` packing the entry class (0 invalid, else
-    1 + class; plain 0/1 means class SET); omit them for an empty window.
-    Table buffers are donated; rebind to ``result.table``.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    if slot_map is None:
-        slot_map = default_slot_map(n_shards, n_slots)
-    slot_map = np.asarray(slot_map, np.int32)
-    n_slots = int(slot_map.shape[0])
-    _count_dispatch()
-    key_hi = np.asarray(key_hi, np.uint32)
-    key_lo = np.asarray(key_lo, np.uint32)
-    if window_hi is None or np.asarray(window_hi).shape[0] == 0:
-        if window_lo is not None and np.asarray(window_lo).shape[0] > 0:
-            raise ValueError("window_lo given without window_hi")
-        w_hi = np.zeros((1,), np.uint32)
-        w_lo = np.zeros((1,), np.uint32)
-        w_val = np.zeros((1,), np.int32)
-    else:
-        if window_lo is None:
-            raise ValueError("window_hi given without window_lo")
-        w_hi = np.asarray(window_hi, np.uint32)
-        w_lo = np.asarray(window_lo, np.uint32)
-        w_val = (np.ones(w_hi.shape, np.int32) if window_valid is None
-                 else np.asarray(window_valid, np.int32))
-    # Bucket-pad the batch and the window (host-side): the protocol layer
-    # produces arbitrary sizes per shard; padding keeps the jit cache to
-    # O(log B) entries.  Padded query lanes are masked out end to end;
-    # padded window lanes carry valid=0 and can never hit.
-    (B,) = key_hi.shape
-    key_cls = (np.zeros((B,), np.int32) if key_cls is None
-               else np.asarray(key_cls, np.int32))
-    key_hi, key_lo, key_cls, k_valid = _pad_valid(B, key_hi, key_lo, key_cls)
-    (U,) = w_hi.shape
-    pad_u = _bucket(U) - U
-    if pad_u:
-        w_hi = np.concatenate([w_hi, np.zeros((pad_u,), np.uint32)])
-        w_lo = np.concatenate([w_lo, np.zeros((pad_u,), np.uint32)])
-        w_val = np.concatenate([w_val, np.zeros((pad_u,), np.int32)])
-    acc, con, shard_ids, qh, ql, new_table = _fastpath_impl(
-        table, w_hi, w_lo, w_val, key_hi, key_lo, jnp.asarray(key_cls),
-        k_valid, jnp.asarray(slot_map), n_slots, interpret, tile_sets,
-    )
-    return FastPathResult(
-        acc[:B], con[:B], shard_ids[:B], qh[:B], ql[:B], new_table
-    )
-
-
-# ---------------------------------------------------------------------------
-# Transactional probe: all-or-nothing multi-key record in ONE dispatch
-# ---------------------------------------------------------------------------
-class TxnProbeResult(NamedTuple):
-    """Result of one all-or-nothing multi-key record (ONE dispatch)."""
-    accepted: bool           # the whole op accepted (all keys placed/hit)
-    hit: jnp.ndarray         # [K] same-key table hit per key (caller order)
-    q_hi: jnp.ndarray        # mixed keyhash lanes of the op's keys — callers
-    q_lo: jnp.ndarray        # gc with these, extend windows on accept
-    table: WitnessTable      # updated iff accepted; bit-identical otherwise
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _txn_probe_impl(table, k_hi, k_lo, own, valid, interpret: bool):
-    qh, ql = ref_keyhash2x32(k_hi, k_lo)    # fuses with the probe's jit
-    acc, hit, new_table = witness_record_txn_pallas(
-        table, qh, ql, own, valid, interpret=interpret
-    )
-    return acc, hit, qh, ql, new_table
-
-
-def txn_probe(table: WitnessTable, key_hi, key_lo, own=None,
-              *, interpret: bool | None = None) -> TxnProbeResult:
-    """All-or-nothing record of ONE multi-key op — a single device dispatch
-    on BOTH the accept and the reject path (the record-then-rollback scheme
-    this replaces paid a second gc dispatch on reject).
-
-    ``key_hi``/``key_lo`` are the RAW 64-bit keyhash lanes of the op's
-    (deduplicated) keys; ``own[k] = 1`` marks keys the caller knows are
-    already held under this op's rpc_id (idempotent retry), resolved from
-    the host mirror.  The kernel leaves the table bit-identical when the op
-    rejects, so callers can rebind ``result.table`` unconditionally.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    _count_dispatch()
-    key_hi = np.asarray(key_hi, np.uint32)
-    key_lo = np.asarray(key_lo, np.uint32)
-    (K,) = key_hi.shape
-    own_arr = (np.zeros((K,), np.int32) if own is None
-               else np.asarray(own, np.int32))
-    key_hi, key_lo, own_arr, valid = _pad_valid(K, key_hi, key_lo, own_arr)
-    acc, hit, qh, ql, new_table = _txn_probe_impl(
-        table, key_hi, key_lo, own_arr, valid, interpret
-    )
-    return TxnProbeResult(
-        bool(np.asarray(acc)[0]), hit[:K], qh[:K], ql[:K], new_table
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -750,9 +335,9 @@ def gang_gc(
     Entry lanes are RAW key lanes (split 64-bit key hashes, as the record
     ops take them) plus the recording rpc identity and target lane; a slot
     clears only on a full (key, rpc, lane) match, so a stale gc entry never
-    drops a newer same-key record.  ``aged_lanes`` is an [L] 0/1 mask of lanes whose
-    survivors age this round (§4.5); ``do_age=False`` is the rollback
-    variant.  Returns (cleared [G] numpy bit per entry, new table).
+    drops a newer same-key record.  ``aged_lanes`` is an [L] 0/1 mask of
+    lanes whose survivors age this round (§4.5); ``do_age=False`` skips the
+    aging.  Returns (cleared [G] numpy bit per entry, new table).
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -912,14 +497,10 @@ def gang_fastpath_batch(
 
 
 __all__ = [
-    "WitnessTable", "FastPathResult", "TxnProbeResult", "keyhash2x32",
-    "DEFAULT_N_SLOTS", "default_slot_map",
-    "shard_route", "witness_record", "witness_record_seq", "witness_gc",
-    "conflict_scan", "fastpath_batch", "txn_probe", "dispatch_count",
-    "reset_dispatch_count", "ref_keyhash2x32", "ref_witness_record",
-    "ref_witness_gc", "ref_conflict_scan", "ref_witness_record_txn",
+    "DEFAULT_N_SLOTS", "dispatch_count", "reset_dispatch_count",
     "GangTable", "GangRecordResult", "GangFastPathResult",
     "gang_record", "gang_record_groups", "gang_gc", "gang_fastpath_batch",
-    "np_keyhash2x32", "ref_gang_record", "ref_gang_gc", "gang_rows",
-    "matrix_rows", "conflict_matrix_np",
+    "record_fits", "ref_keyhash2x32", "np_keyhash2x32", "ref_conflict_scan",
+    "ref_gang_record", "ref_gang_gc", "gang_rows", "matrix_rows",
+    "conflict_matrix_np",
 ]

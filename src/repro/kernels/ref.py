@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the CURP Pallas kernels.
+"""Oracles for the witness-gang Pallas kernels, and the hashes they share.
 
 The protocol's hot spots (DESIGN.md §4) are integer data-structure ops, not
 GEMMs, so the TPU adaptation swaps 64-bit scalar code for 32-bit vector-lane
@@ -6,21 +6,20 @@ math (TPU VPU lanes are 32-bit):
 
   * keyhash2x32 — a 64-bit-equivalent key hash carried as (hi, lo) uint32
     lanes, built from two murmur3 fmix32 finalizers with cross-lane mixing.
-  * witness_record — batched set-associative record (§4.2): order-dependent
-    within a batch (earlier accepts occupy slots).
-  * conflict_scan — master-side commutativity check (§4.3): B incoming
-    keyhashes vs the U-entry unsynced window -> conflict bitmap.
-
-Semantics notes vs the Python Witness (repro.core.witness): the kernel path
-handles single-key records and treats any same-key hit as a conflict
-(duplicate retries are resolved by the Python layer); this matches how the
-device-side witness is used by CURP-Serve (one record per session key).
+    ``ref_keyhash2x32`` is the in-jit hash of every gang op and
+    ``np_keyhash2x32`` its bit-exact host mirror (``record_many``,
+    ``gc_many``, ``FusedBatchDriver``).
+  * ``ref_gang_record`` / ``ref_gang_gc`` — pure-Python transcriptions of
+    ``repro.core.witness.Witness`` over a ``GangTable``: the oracles the
+    record and gc kernels are tested against.
+  * ``ref_conflict_scan`` — master-side commutativity check (§4.3): B
+    queries vs a U-entry unsynced window -> conflict bitmap; the oracle of
+    ``gang_fastpath_batch``'s ring scan.
 """
 from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -88,166 +87,6 @@ def ref_keyhash2x32(hi: jnp.ndarray, lo: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.
     h2 = fmix32(hi ^ h1)
     h3 = fmix32(h1 + h2 * _MIX5 + _MIXC)
     return h2, h3
-
-
-class WitnessTable(NamedTuple):
-    """Device-side witness state: S sets x W ways of (hi, lo) keyhash slots.
-
-    ``occ`` packs the held op class: 0 = empty, 1 + class = occupied
-    (repro.core.merge; class SET == 0, so an all-SET table reads 0/1 exactly
-    as before the merge-lattice widening).
-    """
-    keys_hi: jnp.ndarray   # [S, W] uint32
-    keys_lo: jnp.ndarray   # [S, W] uint32
-    occ: jnp.ndarray       # [S, W] int32 (0 = empty, else 1 + op class)
-
-    @staticmethod
-    def empty(n_sets: int, n_ways: int) -> "WitnessTable":
-        assert n_sets & (n_sets - 1) == 0, "n_sets must be a power of two"
-        return WitnessTable(
-            keys_hi=jnp.zeros((n_sets, n_ways), U32),
-            keys_lo=jnp.zeros((n_sets, n_ways), U32),
-            occ=jnp.zeros((n_sets, n_ways), jnp.int32),
-        )
-
-
-def ref_witness_record(
-    table: WitnessTable, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-    q_cls: jnp.ndarray = None,
-) -> Tuple[jnp.ndarray, WitnessTable]:
-    """Sequential batched record.  Returns (accepted [B] int32, new table).
-
-    ``q_cls`` is the per-query merge-lattice op class (default SET): a
-    same-key hit conflicts only when the matrix says the classes conflict,
-    so e.g. INCR records stack in different ways of one set.
-    """
-    S, W = table.occ.shape
-    set_mask = jnp.uint32(S - 1)
-    if q_cls is None:
-        q_cls = jnp.zeros(q_hi.shape, jnp.int32)
-
-    def body(carry, q):
-        khi, klo, occ = carry
-        qhi, qlo, qc, mrow = q
-        s = (qlo & set_mask).astype(jnp.int32)
-        row_hi, row_lo, row_occ = khi[s], klo[s], occ[s]
-        wcls = jnp.maximum(row_occ - 1, 0)
-        conflict = jnp.any(
-            (row_occ > 0) & (row_hi == qhi) & (row_lo == qlo)
-            & (((mrow >> wcls) & 1) == 1)
-        )
-        free = row_occ == 0
-        has_free = jnp.any(free)
-        way = jnp.argmax(free)
-        acc = jnp.logical_and(~conflict, has_free)
-        sel = (jnp.arange(W) == way) & acc
-        khi = khi.at[s].set(jnp.where(sel, qhi, row_hi))
-        klo = klo.at[s].set(jnp.where(sel, qlo, row_lo))
-        occ = occ.at[s].set(jnp.where(sel, 1 + qc, row_occ))
-        return (khi, klo, occ), acc.astype(jnp.int32)
-
-    (khi, klo, occ), accepted = jax.lax.scan(
-        body, (table.keys_hi, table.keys_lo, table.occ),
-        (q_hi.astype(U32), q_lo.astype(U32),
-         q_cls.astype(jnp.int32), matrix_rows(q_cls)),
-    )
-    return accepted, WitnessTable(khi, klo, occ)
-
-
-def ref_witness_gc(
-    table: WitnessTable, g_hi: jnp.ndarray, g_lo: jnp.ndarray
-) -> WitnessTable:
-    """Clear every slot whose key matches a gc entry (vectorized: no order
-    dependence — clears are idempotent and commutative)."""
-    S, W = table.occ.shape
-    # [S, W, G] match cube; G is small (one gc batch).
-    m = (
-        (table.keys_hi[:, :, None] == g_hi[None, None, :].astype(U32))
-        & (table.keys_lo[:, :, None] == g_lo[None, None, :].astype(U32))
-        & (table.occ[:, :, None] > 0)
-    )
-    cleared = jnp.any(m, axis=-1)
-    return WitnessTable(
-        keys_hi=table.keys_hi,
-        keys_lo=table.keys_lo,
-        occ=jnp.where(cleared, 0, table.occ),
-    )
-
-
-def ref_witness_record_txn(
-    table: WitnessTable, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-    own: jnp.ndarray, valid: jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray, WitnessTable]:
-    """All-or-nothing transactional probe oracle: the K keys of ONE op.
-
-    Placement follows the (fixed) Python ``Witness.record`` semantics: the
-    conflict decision is made against the PRE-op table, but free ways are
-    RESERVED in key order — the k-th same-set inserter takes the set's
-    (rank+1)-th free way, and the op rejects as full when a set cannot seat
-    all of its inserters.  (The old oracle gave every key the set's FIRST
-    free way, so two same-set keys of one op aliased and the second write
-    clobbered the first out of the table.)
-
-    ``own[k] = 1`` marks a key already held under this op's rpc_id (client
-    retry, resolved host-side from the mirror): its table hit counts as
-    placed, not as a conflict.  ``valid[k] = 0`` marks padding lanes.
-
-    Returns (accepted [1] int32, hit [K] int32, new table); the table is
-    untouched unless the whole op accepted.
-    """
-    S, W = table.occ.shape
-    K = q_hi.shape[0]
-    set_mask = jnp.uint32(S - 1)
-    q_hi = q_hi.astype(U32)
-    q_lo = q_lo.astype(U32)
-    own = own.astype(jnp.int32)
-    valid = valid.astype(jnp.int32)
-    sets = (q_lo & set_mask).astype(jnp.int32)                 # [K]
-    row_hi = table.keys_hi[sets]                               # [K, W]
-    row_lo = table.keys_lo[sets]
-    row_occ = table.occ[sets]
-    hit = jnp.any(
-        (row_occ > 0) & (row_hi == q_hi[:, None]) & (row_lo == q_lo[:, None]),
-        axis=1,
-    )
-    free = row_occ == 0
-    # Way reservation: rank this key among the op's earlier same-set
-    # inserters; it seats iff the set still has a free way left after them,
-    # and takes the (rank+1)-th free way so the writes never alias.
-    claim = (valid == 1) & ~hit
-    earlier = jnp.arange(K)[None, :] < jnp.arange(K)[:, None]  # [K, K] j < k
-    rank = jnp.sum(
-        (sets[:, None] == sets[None, :]) & earlier & claim[None, :], axis=1
-    )
-    n_free = jnp.sum(free.astype(jnp.int32), axis=1)
-    seat = n_free > rank
-    cfree = jnp.cumsum(free.astype(jnp.int32), axis=1)
-    selw = free & (cfree == (rank + 1)[:, None])
-    way = jnp.argmax(selw, axis=1)                             # reserved way
-    ok = jnp.where(own == 1, hit | seat, ~hit & seat)
-    accepted = jnp.all(ok | (valid == 0))
-    # Keys already present (hit) keep their slot; everything else inserts at
-    # its reserved free way — own keys included, should the table have lost
-    # them (keeps table and host mirror convergent).
-    write = accepted & (valid == 1) & ~hit
-
-    def body(k, carry):
-        khi, klo, occ = carry
-        sel = (jnp.arange(W) == way[k]) & write[k]
-        s = sets[k]
-        khi = khi.at[s].set(jnp.where(sel, q_hi[k], khi[s]))
-        klo = klo.at[s].set(jnp.where(sel, q_lo[k], klo[s]))
-        occ = occ.at[s].set(jnp.where(sel, 1, occ[s]))
-        return khi, klo, occ
-
-    khi, klo, occ = jax.lax.fori_loop(
-        0, q_hi.shape[0], body, (table.keys_hi, table.keys_lo, table.occ)
-    )
-    return (
-        accepted.astype(jnp.int32).reshape((1,)),
-        (hit & (valid == 1)).astype(jnp.int32),
-        WitnessTable(khi, klo, occ),
-    )
 
 
 def ref_conflict_scan(
@@ -323,8 +162,8 @@ class GangTable(NamedTuple):
     sets instead of one padded 4-way row.  ``R`` is ``L*S`` rounded up to a
     whole 128-lane chunk (``gang_rows``); rows past ``L*S`` stay empty.
 
-    Beyond the key lanes of :class:`WitnessTable`, every slot carries the
-    recording op's RIFL identity (rpc_hi = client id, rpc_lo = seq) and a
+    Beside the key lanes and the packed class (``occ``), every slot carries
+    the recording op's RIFL identity (rpc_hi = client id, rpc_lo = seq) and a
     §4.5 gc-age counter, so duplicate-retry acceptance, stale-gc
     suppression, and garbage suspicion resolve in-kernel.
     """

@@ -1,43 +1,13 @@
-"""Pallas TPU kernels: the witness record (§4.2) and gc kernels.
+"""Pallas TPU kernels of the witness gang: record (§4.2) and gc (§4.5).
 
-Two families live here.
+``gang_record_pallas`` and ``gang_gc_pallas`` (below) back every
+``DeviceWitness`` and the fused cluster batch.  They compile as Mosaic
+kernels for the TPU; run on the CPU they take interpret mode.  A single-key
+op is a group of one key, so ``ops.gang_record``, ``ops.gang_record_groups``
+and ``ops.gang_fastpath_batch`` share the record kernel.
 
-* **The served gang kernels** (``gang_record_pallas``, ``gang_gc_pallas``;
-  bottom of the file) back every ``DeviceWitness`` and the fused cluster
-  batch.  They compile as Mosaic kernels for the TPU; see "Gang kernels"
-  below for their layout.
-* **Single-table comparison kernels** (the set-parallel
-  ``witness_record_setpar_pallas`` / ``fastpath_record_scan_pallas``, the
-  sequential ``witness_record_seq_pallas``, ``witness_record_txn_pallas``
-  and ``witness_gc_pallas``) serve old-vs-new benchmarks and differential
-  tests only.  They gather and scatter table rows by index vectors inside
-  the kernel, which runs in interpret mode but which Mosaic refuses.
-
-Set-parallel comparison kernel
-------------------------------
-The witness table is S sets x W ways of 2x32-bit keyhash slots.  Records are
-order-dependent *within one set* (an accepted record occupies a way that later
-same-key records must conflict with) but **commute across sets** — two records
-that probe different sets touch disjoint table rows and disjoint accept bits.
-The set-parallel kernel exploits exactly that independence:
-
-  1. A prep pass (repro.kernels.ops._setpar_prep, plain XLA so it fuses with
-     the hash) buckets the query batch by probed set ``lo & (S-1)``: a stable
-     sort by set id, then a stable sort by rank-within-set.  After the second
-     sort, "round" r (the r-th query of every set's run) is one contiguous,
-     set-ascending span of the reordered batch.
-  2. The kernel runs a grid over set-tiles (TILE_S rows of the table per grid
-     cell).  Each cell loops over rounds; one round loads a contiguous query
-     chunk (dynamic start, static size), masks it to this cell's sets, and
-     resolves up to TILE_S sets **simultaneously** — every set in the round
-     probes, conflict-checks, and fills its first free way in the same
-     vectorized step.
-  3. Accept bits are written round-chunk-contiguously into a [B] output that
-     all grid cells revisit (accumulate-on-revisit, same pattern as
-     conflict_scan); ops.py unsorts them back to caller order.
-
-Gang kernels: memory budget
----------------------------
+Memory budget
+-------------
 VMEM per grid cell (T = TILE_ROWS = 8192, W = 4): each
 plane block is [4, 8192] int32, 128 KiB, held as 256 KiB because VMEM pads
 4 sublanes to 8.  The record kernel keeps 6 input and 6 output blocks, each
@@ -68,38 +38,30 @@ repeats these compiles.)  The six planes are 6,291,456 B of each.
 
 Donation / aliasing contract
 ----------------------------
-Both kernels declare ``input_output_aliases`` for the table buffers
-(keys_hi/keys_lo/occ -> the corresponding outputs).  What that buys, and
-what it does not:
+The record kernel aliases all six planes to its outputs, the gc kernel
+``occ`` and ``age`` (``input_output_aliases``).  What that buys, and what
+it does not:
 
-  * WITHIN one jitted program the table is updated in place: the pallas_call
-    consumes its operand buffer instead of allocating + copying a second
-    [S, W] triple, and in the fused ``ops.fastpath_batch`` the table threads
-    prep -> kernel -> result with no intermediate copy.
+  * WITHIN one jitted program the planes are updated in place: the
+    pallas_call consumes its operand buffers instead of allocating and
+    copying a second set, and in ``ops.gang_fastpath_batch`` the table
+    threads prep -> kernel -> result with no intermediate copy.
   * ACROSS public-op calls the jax.jit boundary still owns the buffers:
-    without jit-level donation (``donate_argnums``) XLA materializes a fresh
-    output buffer per call, and we deliberately do not donate there — the
-    oracle/differential tests replay one table against several ops, and CPU
-    (where the kernels run in interpret mode) ignores jit donation anyway.
-    Cross-call in-place reuse is a TPU deployment follow-up (ROADMAP), wired
-    by donating the table argument at the caller's jit boundary.
+    without jit-level donation (``donate_argnums``) XLA materializes fresh
+    output buffers per call, and we deliberately do not donate there — the
+    oracle tests replay one table against several ops, and the CPU (where
+    the kernels run in interpret mode) ignores jit donation anyway.
 
 Op-class plane / merge-lattice consult (CRDT-CURP)
 --------------------------------------------------
 Occupancy packs the held op's merge-lattice class (repro.core.merge):
 ``occ == 0`` is empty, ``occ == 1 + class`` is occupied; class SET == 0, so
-all-SET tables keep the legacy 0/1 encoding bit-exactly.  Record queries
-carry a ``q_cls`` lane; a same-key hit conflicts only when
-``(CONFLICT_MATRIX[q_cls] >> (occ - 1)) & 1`` is set — the matrix is a
+all-SET tables keep a 0/1 encoding.  Each key carries its class; a
+same-key hit under a foreign rpc conflicts only when
+``(CONFLICT_MATRIX[cls] >> (occ - 1)) & 1`` is set — the matrix is a
 static 16-entry constant that inlines into the kernel as a where-sum
 (``ref.matrix_rows``), so the in-dispatch decision is bit-exact with the
-Python ``Witness.record`` lattice check.  README.md details the encoding
-and its VMEM cost (zero extra table bytes; one extra [B] query lane).
-
-The sequential reference kernel (`witness_record_seq_pallas`, the pre-refactor
-fori_loop design) is kept for the old-vs-new comparison in
-benchmarks/fig_fastpath.py and for differential testing; it predates the
-op-class plane (classless all-SET semantics, unchanged).
+Python ``Witness.record`` lattice check.
 """
 from __future__ import annotations
 
@@ -110,471 +72,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import U32, GangTable, WitnessTable, matrix_rows
-
-# Default number of table rows (sets) handled by one grid cell.  At the
-# paper's 1024x4 geometry one tile is the whole table (48 KiB — trivially
-# VMEM-resident), so the grid has a single cell; larger geometries split into
-# S/TILE_S cells.  Smaller tiles trade VMEM residency for redundant query
-# scans (every cell walks the full round sequence and masks to its sets), so
-# shrink the tile only when the table itself outgrows VMEM.
-DEFAULT_TILE_SETS = 1024
-
-
-# ---------------------------------------------------------------------------
-# Set-parallel record kernel (optionally fused with the conflict scan)
-# ---------------------------------------------------------------------------
-def _setpar_kernel_body(
-    tile_lo, r_blk, nrounds_ref, qhi_ref, qlo_ref, sets_ref, qcls_ref,
-    rstart_ref, khi_in, klo_in, occ_in, acc_ref, khi_ref, klo_ref, occ_ref,
-):
-    """Resolve every set's (short, ordered) query run for one table tile.
-
-    Queries arrive sorted by (rank-within-set, set): round r is a contiguous
-    chunk in which each set appears at most once, so one round is a fully
-    vectorized [r_blk]-wide probe/insert with no intra-round hazards.
-    """
-    TILE_S, W = khi_in.shape
-    B = qhi_ref.shape[0]
-    way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-    rstart = rstart_ref[...]                      # [B + 1] round offsets
-    n_rounds = nrounds_ref[0]
-
-    def round_body(r, carry):
-        khi, klo, occ = carry
-        start = rstart[r]
-        end = rstart[r + 1]
-        # Static-size window clamped into range; the valid mask trims it to
-        # the round's true [start, end) span.
-        base = jnp.minimum(start, B - r_blk)
-        win = pl.ds(base, r_blk)
-        qhi_c = qhi_ref[win]
-        qlo_c = qlo_ref[win]
-        sets_c = sets_ref[win]
-        qcls_c = qcls_ref[win]
-        pos = base + jax.lax.iota(jnp.int32, r_blk)
-        valid = (pos >= start) & (pos < end)
-        row = sets_c - tile_lo
-        in_tile = (row >= 0) & (row < TILE_S)
-        m = valid & in_tile
-        rowc = jnp.clip(row, 0, TILE_S - 1)
-        row_hi = khi[rowc]                        # [r_blk, W] gathers
-        row_lo = klo[rowc]
-        row_occ = occ[rowc]
-        # Merge-lattice consult: a same-key hit conflicts only when the
-        # matrix row of the query's class has the held class's bit set
-        # (occ packs 1 + class; all-SET tables reproduce the old any-hit
-        # conflict exactly).
-        mrow = matrix_rows(qcls_c)                # [r_blk] matrix rows
-        wcls = jnp.maximum(row_occ - 1, 0)
-        conflict = jnp.any(
-            (row_occ > 0)
-            & (row_hi == qhi_c[:, None])
-            & (row_lo == qlo_c[:, None])
-            & (((mrow[:, None] >> wcls) & 1) == 1),
-            axis=1,
-        )
-        free = row_occ == 0
-        has_free = jnp.any(free, axis=1)
-        way = jnp.argmax(free, axis=1)            # first free way per set
-        accq = m & ~conflict & has_free           # [r_blk]
-        sel = (way_iota == way[:, None]) & accq[:, None]
-        new_hi = jnp.where(sel, qhi_c[:, None], row_hi)
-        new_lo = jnp.where(sel, qlo_c[:, None], row_lo)
-        new_occ = jnp.where(sel, 1 + qcls_c[:, None], row_occ)
-        # Distinct sets within a round => distinct rows: scatter is race-free.
-        # Non-accepted lanes are routed out of range and dropped.
-        srow = jnp.where(accq, rowc, TILE_S)
-        khi = khi.at[srow].set(new_hi, mode="drop")
-        klo = klo.at[srow].set(new_lo, mode="drop")
-        occ = occ.at[srow].set(new_occ, mode="drop")
-        acc_ref[win] = jnp.where(m, accq.astype(jnp.int32), acc_ref[win])
-        return khi, klo, occ
-
-    khi, klo, occ = jax.lax.fori_loop(
-        0, n_rounds, round_body, (khi_in[...], klo_in[...], occ_in[...])
-    )
-    khi_ref[...] = khi
-    klo_ref[...] = klo
-    occ_ref[...] = occ
-
-
-def _make_record_kernel(r_blk: int, tile_s: int):
-    def kernel(nrounds_ref, qhi_ref, qlo_ref, sets_ref, qcls_ref, rstart_ref,
-               khi_in, klo_in, occ_in,
-               acc_ref, khi_ref, klo_ref, occ_ref):
-        g = pl.program_id(0)
-
-        @pl.when(g == 0)
-        def _init_acc():
-            # The [B] accept vector is revisited by every cell; cell 0 zeroes
-            # it, later cells only overwrite their own sets' positions.
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        _setpar_kernel_body(
-            g * tile_s, r_blk, nrounds_ref, qhi_ref, qlo_ref, sets_ref,
-            qcls_ref, rstart_ref, khi_in, klo_in, occ_in,
-            acc_ref, khi_ref, klo_ref, occ_ref,
-        )
-    return kernel
-
-
-def _make_fused_kernel(r_blk: int, tile_s: int):
-    """Record kernel fused with the §4.3 conflict scan: one pallas_call per
-    batch resolves witness accept bits AND master-window conflicts."""
-    def kernel(nrounds_ref, qhi_ref, qlo_ref, sets_ref, qcls_ref, rstart_ref,
-               whi_ref, wlo_ref, wval_ref,
-               khi_in, klo_in, occ_in,
-               acc_ref, con_ref, khi_ref, klo_ref, occ_ref):
-        g = pl.program_id(0)
-
-        @pl.when(g == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            # Conflict scan touches the whole (tiny) unsynced window, so a
-            # single cell computes it; the window stays VMEM-resident.
-            # wval packs the window entry's class (0 invalid, else
-            # 1 + class); the same matrix consult as the record path.
-            qhi = qhi_ref[...]
-            qlo = qlo_ref[...]
-            wval = wval_ref[...]
-            mrow = matrix_rows(qcls_ref[...])
-            wcls = jnp.maximum(wval - 1, 0)
-            eq = (
-                (whi_ref[...][None, :] == qhi[:, None])
-                & (wlo_ref[...][None, :] == qlo[:, None])
-                & (wval[None, :] > 0)
-                & (((mrow[:, None] >> wcls[None, :]) & 1) == 1)
-            )
-            con_ref[...] = jnp.any(eq, axis=1).astype(jnp.int32)
-
-        _setpar_kernel_body(
-            g * tile_s, r_blk, nrounds_ref, qhi_ref, qlo_ref, sets_ref,
-            qcls_ref, rstart_ref, khi_in, klo_in, occ_in,
-            acc_ref, khi_ref, klo_ref, occ_ref,
-        )
-    return kernel
-
-
-def _grid_and_specs(S: int, W: int, B: int, tile_s: int):
-    # A non-dividing tile would silently leave table rows uncovered (their
-    # queries would all "reject" and their output rows would be garbage).
-    assert S % tile_s == 0, f"tile_sets {tile_s} must divide n_sets {S}"
-    grid = (S // tile_s,)
-    full = lambda shape: pl.BlockSpec(shape, lambda g: tuple(0 for _ in shape))
-    tile = pl.BlockSpec((tile_s, W), lambda g: (g, 0))
-    return grid, full, tile
-
-
-@functools.partial(
-    jax.jit, static_argnames=("tile_sets", "interpret")
-)
-def witness_record_setpar_pallas(
-    table: WitnessTable,
-    qhi_f: jnp.ndarray, qlo_f: jnp.ndarray, sets_f: jnp.ndarray,
-    qcls_f: jnp.ndarray, round_start: jnp.ndarray, n_rounds: jnp.ndarray,
-    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool,
-):
-    """Set-parallel batched record over prep-sorted queries.
-
-    Inputs must come from ``ops._setpar_prep`` (sorted by (rank, set) with
-    round offsets); ``qcls_f`` is the per-query merge-lattice op class in
-    the same sorted order.  Returns (accepted-in-sorted-order [B], new
-    table).  The table inputs are aliased to the table outputs
-    (input_output_aliases); see the module docstring for the exact donation
-    contract.
-    """
-    S, W = table.occ.shape
-    (B,) = qhi_f.shape
-    tile_s = min(tile_sets, S)
-    r_blk = min(B, S)
-    grid, full, tile = _grid_and_specs(S, W, B, tile_s)
-    out = pl.pallas_call(
-        _make_record_kernel(r_blk, tile_s),
-        grid=grid,
-        in_specs=[
-            full((1,)), full((B,)), full((B,)), full((B,)), full((B,)),
-            full((B + 1,)),
-            tile, tile, tile,
-        ],
-        out_specs=[full((B,)), tile, tile, tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), jnp.int32),
-        ],
-        input_output_aliases={6: 1, 7: 2, 8: 3},
-        interpret=interpret,
-    )(n_rounds, qhi_f, qlo_f, sets_f, qcls_f.astype(jnp.int32), round_start,
-      table.keys_hi, table.keys_lo, table.occ)
-    acc, khi, klo, occ = out
-    return acc, WitnessTable(khi, klo, occ)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("tile_sets", "interpret")
-)
-def fastpath_record_scan_pallas(
-    table: WitnessTable,
-    qhi_f: jnp.ndarray, qlo_f: jnp.ndarray, sets_f: jnp.ndarray,
-    qcls_f: jnp.ndarray, round_start: jnp.ndarray, n_rounds: jnp.ndarray,
-    w_hi: jnp.ndarray, w_lo: jnp.ndarray, w_valid: jnp.ndarray,
-    *, tile_sets: int = DEFAULT_TILE_SETS, interpret: bool,
-):
-    """Fused fast-path kernel: set-parallel record + conflict scan in ONE
-    pallas_call.  Same prep contract as witness_record_setpar_pallas; the
-    window (w_hi/w_lo/w_valid) is the master's unsynced-op keyhash window,
-    with ``w_valid`` packing each entry's op class (0 invalid, else
-    1 + class) and ``qcls_f`` the per-query class, so the in-dispatch
-    commutativity decision consults the same merge lattice as the record.
-
-    Returns (accepted [B], conflicts [B], new table), accepted/conflicts in
-    sorted order.
-    """
-    S, W = table.occ.shape
-    (B,) = qhi_f.shape
-    (U,) = w_hi.shape
-    tile_s = min(tile_sets, S)
-    r_blk = min(B, S)
-    grid, full, tile = _grid_and_specs(S, W, B, tile_s)
-    out = pl.pallas_call(
-        _make_fused_kernel(r_blk, tile_s),
-        grid=grid,
-        in_specs=[
-            full((1,)), full((B,)), full((B,)), full((B,)), full((B,)),
-            full((B + 1,)),
-            full((U,)), full((U,)), full((U,)),
-            tile, tile, tile,
-        ],
-        out_specs=[full((B,)), full((B,)), tile, tile, tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), jnp.int32),
-        ],
-        input_output_aliases={9: 2, 10: 3, 11: 4},
-        interpret=interpret,
-    )(n_rounds, qhi_f, qlo_f, sets_f, qcls_f.astype(jnp.int32), round_start,
-      w_hi, w_lo, w_valid.astype(jnp.int32),
-      table.keys_hi, table.keys_lo, table.occ)
-    acc, con, khi, klo, occ = out
-    return acc, con, WitnessTable(khi, klo, occ)
-
-
-# ---------------------------------------------------------------------------
-# Sequential reference kernel (pre-refactor design, kept for old-vs-new
-# benchmarking and differential tests)
-# ---------------------------------------------------------------------------
-def _record_seq_kernel(qhi_ref, qlo_ref, khi_in, klo_in, occ_in,
-                       acc_ref, khi_ref, klo_ref, occ_ref):
-    S, W = khi_in.shape
-    set_mask = jnp.uint32(S - 1)
-    khi_ref[...] = khi_in[...]
-    klo_ref[...] = klo_in[...]
-    occ_ref[...] = occ_in[...]
-    B = qhi_ref.shape[0]
-    way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-
-    def body(b, _):
-        qhi = qhi_ref[pl.ds(b, 1)]                       # [1]
-        qlo = qlo_ref[pl.ds(b, 1)]
-        s = (qlo[0] & set_mask).astype(jnp.int32)
-        row = (pl.ds(s, 1), slice(None))
-        row_hi = khi_ref[row]                            # [1, W]
-        row_lo = klo_ref[row]
-        row_occ = occ_ref[row]
-        conflict = jnp.any(
-            (row_occ == 1) & (row_hi == qhi[0]) & (row_lo == qlo[0])
-        )
-        free = row_occ == 0
-        has_free = jnp.any(free)
-        way = jnp.argmax(free)           # first free way
-        acc = jnp.logical_and(~conflict, has_free)
-        sel = (way_iota == way) & acc
-        khi_ref[row] = jnp.where(sel, qhi[0], row_hi)
-        klo_ref[row] = jnp.where(sel, qlo[0], row_lo)
-        occ_ref[row] = jnp.where(sel, 1, row_occ)
-        acc_ref[pl.ds(b, 1)] = acc.astype(jnp.int32).reshape((1,))
-        return 0
-
-    jax.lax.fori_loop(0, B, body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def witness_record_seq_pallas(
-    table: WitnessTable, q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-    *, interpret: bool,
-):
-    """Pre-refactor sequential kernel: the whole batch is one ordered
-    fori_loop over a single grid cell.  O(B) serial steps — the throughput
-    ceiling fig_fastpath measures the set-parallel design against."""
-    S, W = table.occ.shape
-    (B,) = q_hi.shape
-    out = pl.pallas_call(
-        _record_seq_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q_hi.astype(U32), q_lo.astype(U32),
-      table.keys_hi, table.keys_lo, table.occ)
-    accepted, khi, klo, occ = out
-    return accepted, WitnessTable(khi, klo, occ)
-
-
-# ---------------------------------------------------------------------------
-# Transactional probe: all-or-nothing multi-key record, ONE dispatch
-# ---------------------------------------------------------------------------
-def _record_txn_kernel(qhi_ref, qlo_ref, own_ref, valid_ref,
-                       khi_in, klo_in, occ_in,
-                       acc_ref, hit_ref, khi_ref, klo_ref, occ_ref):
-    """All K keys of one op accept together or none do (§4.2 multi-object
-    updates, without the record-then-rollback second dispatch).
-
-    Decision pass (vectorized over K): every key probes the PRE-op table —
-    conflict (same-key hit under a foreign rpc, i.e. ``own == 0``) vetoes
-    the whole op, and each inserting key must SEAT: ranked among the op's
-    earlier same-set inserters, it claims the set's (rank+1)-th free way, so
-    two same-set keys of one op land in distinct ways (the old first-free
-    placement aliased them and the second write clobbered the first) and
-    the op rejects as full when a set cannot seat all of its keys.  Write
-    pass (tiny fori_loop over K, predicated on the op-level accept bit):
-    non-hit keys insert at their reserved way.
-    """
-    S, W = khi_in.shape
-    K = qhi_ref.shape[0]
-    set_mask = jnp.uint32(S - 1)
-    qhi = qhi_ref[...]
-    qlo = qlo_ref[...]
-    own = own_ref[...]
-    valid = valid_ref[...]
-    khi0 = khi_in[...]
-    klo0 = klo_in[...]
-    occ0 = occ_in[...]
-    sets = (qlo & set_mask).astype(jnp.int32)                  # [K]
-    row_hi = khi0[sets]                                        # [K, W]
-    row_lo = klo0[sets]
-    row_occ = occ0[sets]
-    hit = jnp.any(
-        (row_occ > 0) & (row_hi == qhi[:, None]) & (row_lo == qlo[:, None]),
-        axis=1,
-    )
-    free = row_occ == 0
-    claim = (valid == 1) & ~hit
-    k_iota = jax.lax.iota(jnp.int32, K)
-    earlier = k_iota[None, :] < k_iota[:, None]                # [K, K] j < k
-    rank = jnp.sum(
-        ((sets[:, None] == sets[None, :]) & earlier
-         & claim[None, :]).astype(jnp.int32),
-        axis=1,
-    )
-    n_free = jnp.sum(free.astype(jnp.int32), axis=1)
-    seat = n_free > rank
-    cfree = jnp.cumsum(free.astype(jnp.int32), axis=1)
-    selw = free & (cfree == (rank + 1)[:, None])
-    way = jnp.argmax(selw, axis=1)                             # reserved way
-    ok = jnp.where(own == 1, hit | seat, ~hit & seat)
-    accepted = jnp.all(ok | (valid == 0))
-    write = accepted & (valid == 1) & ~hit
-    acc_ref[...] = accepted.astype(jnp.int32).reshape((1,))
-    hit_ref[...] = (hit & (valid == 1)).astype(jnp.int32)
-    way_iota = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-
-    khi_ref[...] = khi0
-    klo_ref[...] = klo0
-    occ_ref[...] = occ0
-
-    def body(k, _):
-        s = sets[k]
-        sel = (way_iota == way[k]) & write[k]                  # [1, W]
-        row = (pl.ds(s, 1), slice(None))
-        khi_ref[row] = jnp.where(sel, qhi[k], khi_ref[row])
-        klo_ref[row] = jnp.where(sel, qlo[k], klo_ref[row])
-        occ_ref[row] = jnp.where(sel, 1, occ_ref[row])
-        return 0
-
-    jax.lax.fori_loop(0, qhi.shape[0], body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def witness_record_txn_pallas(
-    table: WitnessTable,
-    q_hi: jnp.ndarray, q_lo: jnp.ndarray,
-    own: jnp.ndarray, valid: jnp.ndarray,
-    *, interpret: bool,
-):
-    """One-dispatch all-or-nothing record of one op's K (mixed-lane) keys.
-
-    Returns (accepted [1], hit [K], new table): the table outputs alias the
-    inputs (same donation contract as the other record kernels) and are
-    bit-identical to the inputs when the op rejects — no rollback dispatch
-    ever needed.  ``own`` marks keys held under this op's own rpc_id
-    (idempotent retry hits, resolved host-side); ``valid`` masks padding.
-    """
-    S, W = table.occ.shape
-    (K,) = q_hi.shape
-    out = pl.pallas_call(
-        _record_txn_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((K,), jnp.int32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), U32),
-            jax.ShapeDtypeStruct((S, W), jnp.int32),
-        ],
-        input_output_aliases={4: 2, 5: 3, 6: 4},
-        interpret=interpret,
-    )(q_hi.astype(U32), q_lo.astype(U32),
-      own.astype(jnp.int32), valid.astype(jnp.int32),
-      table.keys_hi, table.keys_lo, table.occ)
-    acc, hit, khi, klo, occ = out
-    return acc, hit, WitnessTable(khi, klo, occ)
-
-
-# ---------------------------------------------------------------------------
-# GC kernel (order-independent), with the same donation contract
-# ---------------------------------------------------------------------------
-def _gc_kernel(ghi_ref, glo_ref, khi_in, klo_in, occ_in, occ_ref):
-    # occ[s,w] = 0 wherever (hi, lo) matches any gc entry.  G is one gc batch
-    # (<= a sync batch), so the [S, W, G] compare cube stays tiny.
-    khi = khi_in[...]
-    klo = klo_in[...]
-    occ = occ_in[...]
-    ghi = ghi_ref[...]
-    glo = glo_ref[...]
-    m = (
-        (khi[:, :, None] == ghi[None, None, :])
-        & (klo[:, :, None] == glo[None, None, :])
-        & (occ[:, :, None] > 0)
-    )
-    occ_ref[...] = jnp.where(jnp.any(m, axis=-1), 0, occ)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def witness_gc_pallas(
-    table: WitnessTable, g_hi: jnp.ndarray, g_lo: jnp.ndarray,
-    *, interpret: bool,
-):
-    """Clear synced entries.  The occupancy buffer is aliased in-program
-    (input_output_aliases: occ in -> occ out), so the dispatch mutates one
-    [S, W] occupancy buffer instead of copying it (module docstring has the
-    full donation contract)."""
-    S, W = table.occ.shape
-    occ = pl.pallas_call(
-        _gc_kernel,
-        out_shape=jax.ShapeDtypeStruct((S, W), jnp.int32),
-        input_output_aliases={4: 0},
-        interpret=interpret,
-    )(g_hi.astype(U32), g_lo.astype(U32),
-      table.keys_hi, table.keys_lo, table.occ)
-    return WitnessTable(table.keys_hi, table.keys_lo, occ)
-
+from .ref import U32, GangTable, matrix_rows
 
 # ---------------------------------------------------------------------------
 # Gang kernels: stacked lanes + kernel-held RIFL identity and gc-age state
@@ -882,9 +380,9 @@ def gang_gc_pallas(
     a full match, so stale entries never drop a newer same-key record.
     Entries apply in index order per row (a repeated entry clears once).
     Survivors in rows flagged by ``aged_rows`` ([R] 0/1) age by one round
-    (cleared / empty slots reset to 0); ``do_age=False`` is the rollback
-    variant.  Returns (cleared bit per entry [G], new gang table); occ and
-    age alias their outputs, key/rpc lanes are untouched.
+    (cleared / empty slots reset to 0); ``do_age=False`` skips the aging.
+    Returns (cleared bit per entry [G], new gang table); occ and age alias
+    their outputs, key/rpc lanes are untouched.
 
     SMEM holds 5 words per entry plus the cleared bit: at most G = 43008
     entries fit on v5e; more raise ValueError (see ``check_smem``).

@@ -188,7 +188,7 @@ class BatchedWorkload:
     Each call to ``batch`` yields ``batch_size`` ops drawn from a uniform
     keyspace; ``conflict_frac`` of them re-touch a small hot keyset so a
     tunable share of the batch exercises the witness conflict path (the
-    adversarial case for set-parallel records).  Ops are created through the
+    adversarial case for batched records).  Ops are created through the
     session's routing constructors, so each op carries an rpc_id from its
     owning shard's RIFL space.
     """

@@ -1,15 +1,11 @@
-"""Fast-path pipeline tests: set-parallel kernel parity on adversarial
-batches, the fused fastpath_batch op, buffer-donation round-trips, and the
-batched client path (update_batch / commit_batch) on both witness backends.
-
-Property tests go through the _hyp shim (skips cleanly without hypothesis);
-each has a deterministic companion so the invariants stay covered either way.
+"""Device-witness and batched-client-path tests: ``DeviceWitness`` against
+the Python witness, kernel-held RIFL/gc state, the stacked record and gc
+dispatches, and the batched client path (update_batch / commit_batch) on both
+witness backends, fused and per-shard.  The kernels themselves are tested
+against their oracles in test_kernels.py.
 """
-import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from _hyp import given, settings, st
 
 from repro.core import (
     DeviceWitness,
@@ -18,371 +14,11 @@ from repro.core import (
     WitnessGeometry,
 )
 from repro.core.types import RecordStatus
-from repro.kernels import (
-    WitnessTable,
-    dispatch_count,
-    fastpath_batch,
-    ref_conflict_scan,
-    ref_keyhash2x32,
-    ref_witness_record,
-    reset_dispatch_count,
-    witness_gc,
-    witness_record,
-    witness_record_seq,
-)
+from repro.kernels import dispatch_count, reset_dispatch_count
 
 
 def rng(seed=0):
     return np.random.default_rng(seed)
-
-
-def assert_tables_equal(a: WitnessTable, b: WitnessTable):
-    np.testing.assert_array_equal(np.asarray(a.occ), np.asarray(b.occ))
-    np.testing.assert_array_equal(np.asarray(a.keys_hi), np.asarray(b.keys_hi))
-    np.testing.assert_array_equal(np.asarray(a.keys_lo), np.asarray(b.keys_lo))
-
-
-class TestSetParallelParity:
-    """The set-parallel kernel is bit-exact with ref_witness_record."""
-
-    @pytest.mark.parametrize("sets,ways,batch,kspan,span", [
-        (16, 2, 200, 4, 8),          # duplicate keys, tiny keyspace
-        (16, 4, 300, 6, 4),          # capacity-full sets
-        (64, 4, 512, 2**32 - 1, 64),  # every set overcommitted
-        (1024, 4, 1000, 2**32 - 1, 2**32 - 1),
-        (128, 2, 127, 3, 3),         # odd batch (bucket-padding path)
-    ])
-    def test_collision_heavy_matches_oracle(self, sets, ways, batch,
-                                            kspan, span):
-        r = rng(sets + batch)
-        t = WitnessTable.empty(sets, ways)
-        qh = r.integers(0, kspan, batch).astype(np.uint32)
-        ql = r.integers(0, span, batch).astype(np.uint32)
-        acc_k, t_k = witness_record(t, qh, ql)
-        acc_r, t_r = ref_witness_record(t, jnp.asarray(qh), jnp.asarray(ql))
-        np.testing.assert_array_equal(np.asarray(acc_k), np.asarray(acc_r))
-        assert_tables_equal(t_k, t_r)
-        # ... and with the pre-refactor sequential kernel.
-        acc_s, t_s = witness_record_seq(t, qh, ql)
-        np.testing.assert_array_equal(np.asarray(acc_s), np.asarray(acc_r))
-        assert_tables_equal(t_s, t_r)
-
-    def test_duplicate_keys_single_batch(self):
-        """Same key B times in one batch: exactly one accept (the first)."""
-        t = WitnessTable.empty(16, 4)
-        qh = np.full(9, 7, np.uint32)
-        ql = np.full(9, 3, np.uint32)
-        acc, t2 = witness_record(t, qh, ql)
-        assert np.asarray(acc).tolist() == [1] + [0] * 8
-        assert int(np.asarray(t2.occ).sum()) == 1
-
-    def test_full_set_capacity_rejects(self):
-        """W+k distinct keys probing one set: exactly W accepts, in order."""
-        t = WitnessTable.empty(16, 4)
-        S = 16
-        qh = np.arange(7, dtype=np.uint32)           # distinct keys
-        ql = np.full(7, 5, np.uint32)                # same set (5 & 15)
-        acc, t2 = witness_record(t, qh, ql)
-        assert np.asarray(acc).tolist() == [1, 1, 1, 1, 0, 0, 0]
-        assert int(np.asarray(t2.occ)[5].sum()) == 4
-
-    def test_cross_set_permutation_invariance(self):
-        """Permuting ops of OTHER sets never changes an op's accept bit —
-        the set-level independence the kernel parallelizes over."""
-        r = rng(3)
-        S, B = 16, 240
-        t = WitnessTable.empty(S, 4)
-        qh = r.integers(0, 6, B).astype(np.uint32)
-        ql = r.integers(0, 64, B).astype(np.uint32)
-        acc0, t0 = witness_record(t, qh, ql)
-        sets = ql & (S - 1)
-        # Stable-sort by set id: reorders across sets, preserves order within.
-        perm = np.argsort(sets, kind="stable")
-        acc1, t1 = witness_record(t, qh[perm], ql[perm])
-        np.testing.assert_array_equal(np.asarray(acc0)[perm],
-                                      np.asarray(acc1))
-        assert_tables_equal(t0, t1)
-
-    @pytest.mark.parametrize("tile_sets,sets", [(64, 256), (32, 128)])
-    def test_multi_cell_grid_matches_oracle(self, tile_sets, sets):
-        """Grids with several set-tiles (tile_sets < n_sets): the per-tile
-        masking + accumulate-on-revisit accept vector must stay bit-exact."""
-        r = rng(tile_sets + sets)
-        t = WitnessTable.empty(sets, 4)
-        qh = r.integers(0, 16, 600).astype(np.uint32)
-        ql = r.integers(0, sets * 5, 600).astype(np.uint32)
-        acc_k, t_k = witness_record(t, qh, ql, tile_sets=tile_sets)
-        acc_r, t_r = ref_witness_record(t, jnp.asarray(qh), jnp.asarray(ql))
-        np.testing.assert_array_equal(np.asarray(acc_k), np.asarray(acc_r))
-        assert_tables_equal(t_k, t_r)
-
-    def test_non_dividing_tile_rejected(self):
-        t = WitnessTable.empty(256, 4)
-        with pytest.raises(AssertionError):
-            witness_record(t, np.zeros(4, np.uint32), np.zeros(4, np.uint32),
-                           tile_sets=96)
-
-    @settings(deadline=None, max_examples=25)
-    @given(seed=st.integers(0, 10_000), sets=st.sampled_from([16, 64, 256]),
-           ways=st.sampled_from([2, 4, 8]), batch=st.integers(1, 300),
-           kspan=st.sampled_from([2, 5, 2**32 - 1]))
-    def test_property_matches_oracle(self, seed, sets, ways, batch, kspan):
-        r = rng(seed)
-        t = WitnessTable.empty(sets, ways)
-        qh = r.integers(0, kspan, batch).astype(np.uint32)
-        ql = r.integers(0, max(2, sets * 3), batch).astype(np.uint32)
-        acc_k, t_k = witness_record(t, qh, ql)
-        acc_r, t_r = ref_witness_record(t, jnp.asarray(qh), jnp.asarray(ql))
-        np.testing.assert_array_equal(np.asarray(acc_k), np.asarray(acc_r))
-        assert_tables_equal(t_k, t_r)
-
-    @settings(deadline=None, max_examples=15)
-    @given(seed=st.integers(0, 10_000))
-    def test_property_permutation_invariance(self, seed):
-        r = rng(seed)
-        S, B = 32, 100
-        t = WitnessTable.empty(S, 2)
-        qh = r.integers(0, 4, B).astype(np.uint32)
-        ql = r.integers(0, 128, B).astype(np.uint32)
-        acc0, _ = witness_record(t, qh, ql)
-        perm = np.argsort(ql & (S - 1), kind="stable")
-        acc1, _ = witness_record(t, qh[perm], ql[perm])
-        np.testing.assert_array_equal(np.asarray(acc0)[perm],
-                                      np.asarray(acc1))
-
-
-class TestGcDonationRoundTrip:
-    def test_record_gc_record_no_stale_occupancy(self):
-        """record -> gc -> record round-trips: gc leaves no stale occupancy
-        and a full re-record of the same keys is accepted again."""
-        r = rng(9)
-        t = WitnessTable.empty(64, 4)
-        qh = r.integers(0, 2**32, 120).astype(np.uint32)
-        ql = np.arange(120, dtype=np.uint32)       # distinct sets mod 64? no:
-        acc1, t = witness_record(t, qh, ql)        # 2 rounds over 64 sets
-        occupied = int(np.asarray(t.occ).sum())
-        assert occupied == int(np.asarray(acc1).sum()) > 0
-        t = witness_gc(t, qh, ql)
-        assert int(np.asarray(t.occ).sum()) == 0   # no stale occupancy
-        acc2, t = witness_record(t, qh, ql)
-        np.testing.assert_array_equal(np.asarray(acc2), np.asarray(acc1))
-
-    def test_gc_then_accept_chain_reuses_table(self):
-        """Functional chain that rebinds the table each call (the donation
-        pattern): many record/gc cycles stay self-consistent."""
-        t = WitnessTable.empty(16, 2)
-        qh = np.array([5, 6, 7], np.uint32)
-        ql = np.array([1, 2, 3], np.uint32)
-        for _ in range(5):
-            acc, t = witness_record(t, qh, ql)
-            assert np.asarray(acc).tolist() == [1, 1, 1]
-            t = witness_gc(t, qh, ql)
-        assert int(np.asarray(t.occ).sum()) == 0
-
-
-class TestFusedFastPath:
-    def test_single_dispatch_per_batch(self):
-        t = WitnessTable.empty(64, 4)
-        r = rng(1)
-        khi = r.integers(0, 2**32, 33).astype(np.uint32)
-        klo = r.integers(0, 2**32, 33).astype(np.uint32)
-        fastpath_batch(t, khi, klo)            # warm
-        reset_dispatch_count()
-        fastpath_batch(t, khi, klo)
-        assert dispatch_count() == 1
-        reset_dispatch_count()
-
-    def test_matches_unfused_pipeline(self):
-        """fastpath_batch == keyhash2x32 -> record -> conflict_scan, bit for
-        bit, including shard routing."""
-        r = rng(5)
-        t = WitnessTable.empty(128, 4)
-        khi = r.integers(0, 2**32, 70).astype(np.uint32)
-        klo = r.integers(0, 2**32, 70).astype(np.uint32)
-        res = fastpath_batch(t, khi, klo, n_shards=4)
-        qh, ql = ref_keyhash2x32(jnp.asarray(khi), jnp.asarray(klo))
-        acc_r, t_r = ref_witness_record(t, qh, ql)
-        np.testing.assert_array_equal(np.asarray(res.accepted),
-                                      np.asarray(acc_r))
-        assert_tables_equal(res.table, t_r)
-        np.testing.assert_array_equal(
-            np.asarray(res.shard_ids),
-            np.asarray((ql % jnp.uint32(4)).astype(jnp.int32)))
-        # Window conflicts against previously recorded mixed lanes.
-        wv = np.ones(10, np.int32)
-        res2 = fastpath_batch(res.table, khi[:20], klo[:20],
-                              window_hi=res.q_hi[:10],
-                              window_lo=res.q_lo[:10], window_valid=wv)
-        con_r = ref_conflict_scan(res.q_hi[:10], res.q_lo[:10],
-                                  jnp.asarray(wv), qh[:20], ql[:20])
-        np.testing.assert_array_equal(np.asarray(res2.conflicts),
-                                      np.asarray(con_r))
-
-    def test_window_valid_defaults_to_all_live(self):
-        """window_valid omitted => every window entry counts; partial window
-        specs fail loudly instead of deep in jnp."""
-        r = rng(8)
-        t = WitnessTable.empty(64, 4)
-        khi = r.integers(0, 2**32, 12).astype(np.uint32)
-        klo = r.integers(0, 2**32, 12).astype(np.uint32)
-        res = fastpath_batch(t, khi, klo)
-        res2 = fastpath_batch(res.table, khi[:6], klo[:6],
-                              window_hi=res.q_hi[:4], window_lo=res.q_lo[:4])
-        con_r = ref_conflict_scan(
-            res.q_hi[:4], res.q_lo[:4], jnp.ones(4, jnp.int32),
-            res.q_hi[:6], res.q_lo[:6])
-        np.testing.assert_array_equal(np.asarray(res2.conflicts),
-                                      np.asarray(con_r))
-        with pytest.raises(ValueError):
-            fastpath_batch(t, khi, klo, window_hi=res.q_hi[:4])
-        with pytest.raises(ValueError):
-            fastpath_batch(t, khi, klo, window_lo=res.q_lo[:4])
-
-    def test_shard_route_matches_key_router(self):
-        from repro.core.shard import KeyRouter
-        from repro.core.types import keyhash
-
-        keys = [f"s{i}" for i in range(64)]
-        khs = [keyhash(k) for k in keys]
-        hi = np.array([(h >> 32) & 0xFFFFFFFF for h in khs], np.uint32)
-        lo = np.array([h & 0xFFFFFFFF for h in khs], np.uint32)
-        res = fastpath_batch(WitnessTable.empty(64, 4), hi, lo, n_shards=3)
-        router = KeyRouter(3)
-        np.testing.assert_array_equal(
-            np.asarray(res.shard_ids),
-            np.array([router.shard_of(k) for k in keys]))
-
-
-class TestTxnProbe:
-    """All-or-nothing multi-key record: one dispatch on accept AND reject."""
-
-    def _oracle(self, table, hi, lo, own=None):
-        from repro.kernels import ref_witness_record_txn
-        from repro.kernels.ops import _pad_valid
-
-        (K,) = np.asarray(hi).shape
-        qh, ql = ref_keyhash2x32(jnp.asarray(hi, jnp.uint32),
-                                 jnp.asarray(lo, jnp.uint32))
-        own = np.zeros(K, np.int32) if own is None else np.asarray(own)
-        qhp, qlp, ownp, valid = _pad_valid(K, np.asarray(qh), np.asarray(ql),
-                                           own)
-        return ref_witness_record_txn(
-            table, jnp.asarray(qhp), jnp.asarray(qlp), jnp.asarray(ownp),
-            jnp.asarray(valid))
-
-    def test_accept_and_reject_single_dispatch(self):
-        from repro.kernels import txn_probe
-
-        t = WitnessTable.empty(16, 2)
-        hi = np.array([1, 2, 3], np.uint32)
-        lo = np.array([1, 2, 3], np.uint32)
-        txn_probe(t, hi, lo)            # warm the jit cache
-        reset_dispatch_count()
-        res = txn_probe(t, hi, lo)
-        assert res.accepted and dispatch_count() == 1
-        reset_dispatch_count()
-        # Conflict: same keys again (different op) — rejects, still 1 call.
-        res2 = txn_probe(res.table, hi, lo)
-        assert not res2.accepted and dispatch_count() == 1
-        reset_dispatch_count()
-
-    def test_reject_leaves_table_bit_identical(self):
-        from repro.kernels import txn_probe
-
-        r = rng(4)
-        t = WitnessTable.empty(16, 2)
-        res = txn_probe(t, np.array([7], np.uint32), np.array([7], np.uint32))
-        t = res.table
-        # Op with one fresh key and one conflicting key: must reject and
-        # leave the table untouched (no partial insert, no rollback).
-        res2 = txn_probe(t, np.array([5, 7], np.uint32),
-                         np.array([5, 7], np.uint32))
-        assert not res2.accepted
-        assert_tables_equal(res2.table, t)
-
-    @pytest.mark.parametrize("sets,ways,kspan", [
-        (8, 2, 4), (16, 4, 6), (64, 4, 3),
-    ])
-    def test_matches_oracle_collision_heavy(self, sets, ways, kspan):
-        from repro.kernels import txn_probe
-
-        r = rng(sets + ways)
-        table = WitnessTable.empty(sets, ways)
-        oracle = WitnessTable.empty(sets, ways)
-        for i in range(80):
-            K = int(r.integers(1, 7))
-            hi = r.integers(0, kspan, K).astype(np.uint32)
-            lo = r.integers(0, kspan, K).astype(np.uint32)
-            res = txn_probe(table, hi, lo)
-            acc_r, hit_r, oracle = self._oracle(oracle, hi, lo)
-            assert res.accepted == bool(np.asarray(acc_r)[0]), i
-            np.testing.assert_array_equal(np.asarray(res.hit),
-                                          np.asarray(hit_r)[:K])
-            table = res.table
-            assert_tables_equal(table, oracle)
-
-    def test_own_bit_makes_retry_idempotent(self):
-        from repro.kernels import txn_probe
-
-        t = WitnessTable.empty(16, 4)
-        hi = np.array([3, 4], np.uint32)
-        lo = np.array([3, 4], np.uint32)
-        res = txn_probe(t, hi, lo)
-        assert res.accepted
-        # Same op retried without own bits: same-key hits -> conflict.
-        res2 = txn_probe(res.table, hi, lo)
-        assert not res2.accepted
-        # With own bits (the caller knows these are its keys): accepted,
-        # table unchanged (keys already placed).
-        res3 = txn_probe(res.table, hi, lo, own=np.array([1, 1], np.int32))
-        assert res3.accepted
-        assert np.asarray(res3.hit).tolist() == [1, 1]
-        assert_tables_equal(res3.table, res.table)
-
-    def test_capacity_reject_all_or_nothing(self):
-        from repro.kernels import txn_probe
-
-        t = WitnessTable.empty(1, 2)    # one set, two ways
-        # Fill both ways with two separate single-key ops (keys of ONE op
-        # compute placement against the pre-op state — Python Witness
-        # semantics — so one 2-key op would land in a single way).
-        for k in (1, 2):
-            res = txn_probe(t, np.array([k], np.uint32),
-                            np.array([k], np.uint32))
-            assert res.accepted
-            t = res.table
-        assert int(np.asarray(t.occ).sum()) == 2
-        res2 = txn_probe(t, np.array([9, 10], np.uint32),
-                         np.array([9, 10], np.uint32))
-        assert not res2.accepted        # capacity: whole op rejected
-        assert_tables_equal(res2.table, t)
-
-    def test_device_witness_multikey_one_dispatch_no_rollback(self):
-        """DeviceWitness multi-key records go through the probe: 1 kernel
-        dispatch whether the op accepts or rejects (the old path paid 2 on
-        reject), with statuses identical to the rollback implementation."""
-        from repro.core import DeviceWitness
-        from repro.core.types import Op, OpType
-
-        def fresh():
-            w = DeviceWitness(64, 4)
-            w.start(1)
-            w.record(1, (7,), (1, 1), Op(OpType.SET, ("x",), (0,), (1, 1)))
-            return w
-
-        reject_op = Op(OpType.MSET, ("a", "b"), (1, 2), (2, 1))
-        w = fresh()
-        reset_dispatch_count()
-        st = w._record_keys((5, 7), reject_op.rpc_id, reject_op)
-        assert dispatch_count() == 1
-        w2 = fresh()
-        reset_dispatch_count()
-        st2 = w2._record_keys_rollback((5, 7), reject_op.rpc_id, reject_op)
-        assert dispatch_count() == 2
-        assert st == st2
-        # Mirror and stats agree with the Python reference on the reject.
-        assert w.stats["rejects_conflict"] == 1
-        assert w.occupancy == w2.occupancy == 1
 
 
 class TestDeviceWitness:

@@ -36,13 +36,12 @@ from repro.core.types import Op, OpType
 from repro.core.witness import RecordStatus, Witness
 from repro.kernels import (
     GangTable,
-    WitnessTable,
     conflict_matrix_np,
     gang_record_groups,
     matrix_rows,
-    ref_witness_record,
-    witness_record,
 )
+
+from _gang import accepted, assert_tables_equal, oracle, record
 
 
 def _sessions(n=4):
@@ -187,14 +186,12 @@ def _kernel_matches_oracle(classes):
     pick = rng.integers(0, 6, size=128)
     q_hi, q_lo = base_hi[pick], base_lo[pick]
     q_cls = rng.choice(np.array(classes, dtype=np.int32), size=128)
-    table = WitnessTable.empty(32, 16)
-    acc_ref, t_ref = ref_witness_record(table, q_hi, q_lo, q_cls)
-    acc_dev, t_dev = witness_record(table, q_hi, q_lo, q_cls)
-    assert np.array_equal(np.asarray(acc_ref), np.asarray(acc_dev))
-    for name in ("keys_hi", "keys_lo", "occ"):
-        assert np.array_equal(np.asarray(getattr(t_ref, name)),
-                              np.asarray(getattr(t_dev, name))), name
-    acc = np.asarray(acc_ref)
+    table = GangTable.empty(32, 16)
+    rsn_ref, t_ref = oracle(table, 32, q_hi, q_lo, q_cls)
+    rsn_dev, t_dev = record(table, 32, q_hi, q_lo, q_cls)
+    assert np.array_equal(rsn_ref, rsn_dev)
+    assert_tables_equal(t_ref, t_dev)
+    acc = accepted(rsn_ref)
     assert 0 < int(acc.sum()) < len(acc)
 
 
@@ -210,31 +207,28 @@ def test_record_kernel_consults_the_predicate_for_every_class_pair():
     k_lo = rng.integers(0, 2 ** 32, size=len(pairs), dtype=np.uint32)
     q_hi, q_lo = np.repeat(k_hi, 2), np.repeat(k_lo, 2)
     q_cls = np.array(pairs, dtype=np.int32).reshape(-1)
-    table = WitnessTable.empty(64, 8)
-    acc_dev, _ = witness_record(table, q_hi, q_lo, q_cls)
-    acc_ref, _ = ref_witness_record(table, q_hi, q_lo, q_cls)
-    acc = np.asarray(acc_dev).reshape(-1, 2)
-    assert np.array_equal(np.asarray(acc_ref).reshape(-1, 2), acc)
+    table = GangTable.empty(64, 8)
+    rsn_dev, _ = record(table, 64, q_hi, q_lo, q_cls)
+    rsn_ref, _ = oracle(table, 64, q_hi, q_lo, q_cls)
+    acc = accepted(rsn_dev).reshape(-1, 2)
+    assert np.array_equal(accepted(rsn_ref).reshape(-1, 2), acc)
     assert acc[:, 0].all()
     assert [bool(x) for x in acc[:, 1]] == [not conflicts(a, b)
                                             for a, b in pairs]
 
 
 def test_all_set_batch_keeps_legacy_occ_encoding():
-    """CLS_SET == 0, so a classless (all-SET) table must stay bit-identical
-    to the pre-widening 0/1 occupancy encoding."""
+    """CLS_SET == 0, so a classless (all-SET) gang must hold the same
+    planes as one recorded without classes, with a 0/1 occupancy plane."""
     rng = np.random.default_rng(9)
     q_hi = rng.integers(0, 2 ** 32, size=64, dtype=np.uint32)
     q_lo = rng.integers(0, 2 ** 32, size=64, dtype=np.uint32)
-    table = WitnessTable.empty(32, 4)
-    _, t_cls = witness_record(table, q_hi, q_lo,
-                              np.zeros(64, np.int32))
-    _, t_legacy = witness_record(table, q_hi, q_lo)  # q_cls defaulted
+    table = GangTable.empty(32, 4)
+    _, t_cls = record(table, 32, q_hi, q_lo, np.zeros(64, np.int32))
+    _, t_legacy = record(table, 32, q_hi, q_lo)      # key_cls defaulted
     occ = np.asarray(t_cls.occ)
     assert set(np.unique(occ)) <= {0, 1}
-    for name in ("keys_hi", "keys_lo", "occ"):
-        assert np.array_equal(np.asarray(getattr(t_cls, name)),
-                              np.asarray(getattr(t_legacy, name))), name
+    assert_tables_equal(t_cls, t_legacy)
 
 
 # ----------------------------------------- Witness <-> DeviceWitness parity ----

@@ -1,10 +1,12 @@
 """Live-reconfiguration tests (repro.core.migration): SlotRouter <->
-shard_route parity on random slot maps, online slot handover under traffic,
-donor/receiver crashes mid-handover, the §3.6 fences, duplicate RIFL retries
-across a slot move, hot-shard auto-split, and the serving store's live
-session migration."""
+device slot-gather parity on random slot maps, online slot handover under
+traffic, donor/receiver crashes mid-handover, the §3.6 fences, duplicate RIFL
+retries across a slot move, hot-shard auto-split, and the serving store's
+live session migration."""
 import numpy as np
 import pytest
+
+from _gang import raw_lanes, route
 
 from repro.core import (
     ShardedCluster,
@@ -50,37 +52,28 @@ class TestSlotRouter:
         )
 
     def test_parity_with_pallas_on_random_slot_maps(self):
-        """Satellite: SlotRouter <-> kernels.shard_route bit-exact on random
-        slot maps (the table-gather contract)."""
-        from repro.kernels import shard_route
-
+        """SlotRouter <-> the device's slot gather (gang_fastpath_batch)
+        bit-exact on random slot maps (the table-gather contract)."""
         rng = np.random.default_rng(11)
         keys = [f"user{i}" for i in range(300)] + list(range(64))
-        khs = [keyhash(k) for k in keys]
-        hi = np.array([(h >> 32) & 0xFFFFFFFF for h in khs], np.uint32)
-        lo = np.array([h & 0xFFFFFFFF for h in khs], np.uint32)
+        hi, lo = raw_lanes([keyhash(k) for k in keys])
         for n_slots in (64, 256):
             for n_shards in (2, 3, 5):
                 slot_map = rng.integers(0, n_shards, n_slots)
                 router = SlotRouter(list(slot_map), n_shards=n_shards)
-                dev = np.asarray(shard_route(hi, lo, slot_map=slot_map))
+                dev = route(hi, lo, slot_map)
                 py = np.array([router.shard_of(k) for k in keys])
                 np.testing.assert_array_equal(dev, py)
 
     def test_fastpath_batch_routes_by_slot_map(self):
-        from repro.kernels import WitnessTable, fastpath_batch
-
+        """The fused batch places each op by the slot map it is given."""
         rng = np.random.default_rng(3)
         keys = [f"fk{i}" for i in range(100)]
-        khs = [keyhash(k) for k in keys]
-        hi = np.array([(h >> 32) & 0xFFFFFFFF for h in khs], np.uint32)
-        lo = np.array([h & 0xFFFFFFFF for h in khs], np.uint32)
+        hi, lo = raw_lanes([keyhash(k) for k in keys])
         slot_map = rng.integers(0, 3, 64)
         router = SlotRouter(list(slot_map), n_shards=3)
-        res = fastpath_batch(WitnessTable.empty(64, 4), hi, lo,
-                             slot_map=slot_map)
         np.testing.assert_array_equal(
-            np.asarray(res.shard_ids),
+            route(hi, lo, slot_map),
             np.array([router.shard_of(k) for k in keys]),
         )
 

@@ -35,17 +35,20 @@ def test_witness_capacity_figure11_shape():
     """Appendix B.1: 4-way associativity massively outlasts direct-mapped."""
     import numpy as np
 
-    from repro.kernels import WitnessTable, witness_record
+    from repro.kernels import GangTable, gang_record
 
     def inserts_to_first_reject(ways: int, slots: int = 256, seed: int = 0):
         rng = np.random.default_rng(seed)
-        t = WitnessTable.empty(slots // ways, ways)
-        qh = rng.integers(0, 2**32, slots * 4, dtype=np.uint32)
-        ql = rng.integers(0, 2**32, slots * 4, dtype=np.uint32)
-        acc, _ = witness_record(t, qh, ql)
-        acc = np.asarray(acc)
-        rejects = np.where(acc == 0)[0]
-        return int(rejects[0]) if len(rejects) else len(acc)
+        n_sets = slots // ways
+        t = GangTable.empty(n_sets, ways)
+        n = slots * 4
+        qh = rng.integers(0, 2**32, n, dtype=np.uint32)
+        ql = rng.integers(0, 2**32, n, dtype=np.uint32)
+        rsn, _qh, _ql, _t = gang_record(
+            t, n_sets, qh, ql, np.zeros(n, np.int32), np.zeros(n, np.uint32),
+            np.arange(1, n + 1, dtype=np.uint32))
+        rejects = np.where(np.asarray(rsn) > 2)[0]
+        return int(rejects[0]) if len(rejects) else n
 
     direct = statistics.mean(
         inserts_to_first_reject(1, seed=s) for s in range(5)
