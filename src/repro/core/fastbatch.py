@@ -250,7 +250,7 @@ class FusedBatchDriver:
 
         # Route every op (redirects raise SlotMoving before any side effect,
         # matching ShardedCluster._group_for's contract).
-        slots = [cluster.router.slot_of(op.keys[0]) for op in ops]
+        slots = [cluster.router.slots_of_op(op)[0] for op in ops]
         for s in slots:
             cluster.migration.check_slots({s})
         shard_ids = [cluster.router.slot_map[s] for s in slots]

@@ -13,7 +13,7 @@ that owns actual RPC delivery.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .backup import LogEntry
 from .merge import conflicts
@@ -68,7 +68,9 @@ class Master:
         self._unsynced_keyhash: Dict[int, Dict[int, int]] = {}
         self.sync_in_progress: Optional[PendingSync] = None
         self.want_sync: bool = False          # sync requested (batch full / conflict)
-        self.owned_partition = None           # optional key filter (migration §3.6)
+        # Optional ownership filter (migration §3.6): op -> whether this
+        # master owns every key of it.
+        self.owned_partition: Optional[Callable[[Op], bool]] = None
         # RIFL completion records that arrived WITH migrated data (§3.6 slot
         # handover, RAMCloud-style per-object RIFL): keyed by (rpc_id,
         # key_hashes) so a moved op's retry dedups here while this master's
@@ -162,9 +164,7 @@ class Master:
             # does not map here YET (the map flips only after the transfer
             # is durable), so it must bypass the ownership filter.
             return True
-        if self.owned_partition is None:
-            return True
-        return all(self.owned_partition(k) for k in op.keys)
+        return self.owned_partition is None or self.owned_partition(op)
 
     # --------------------------------------------------------------- updates
     def handle_update(

@@ -104,28 +104,31 @@ def field_subkey(key, field) -> str:
 def op_hash_classes(op) -> List[Tuple[int, int]]:
     """Expand an op into the ``(key_hash, op_class)`` pairs the lattice
     reasons over.  Single source of truth — ``Op.hash_classes()`` memoizes
-    this, and every witness/master/kernel layer consumes those pairs."""
+    this, and every witness/master/kernel layer consumes those pairs.  The
+    hashes of ``op.keys`` are the op's memoized ``key_hashes()``; only an
+    HMSET's FIELD sub-keys are hashed here."""
     from .types import OpType, keyhash
 
     t = op.op_type
+    khs = op.key_hashes()
     if t == OpType.SET:
-        return [(keyhash(k), CLS_SET) for k in op.keys]
+        return [(kh, CLS_SET) for kh in khs]
     if t == OpType.DEL:
-        return [(keyhash(k), CLS_DEL) for k in op.keys]
+        return [(kh, CLS_DEL) for kh in khs]
     if t == OpType.INCR:
-        return [(keyhash(k), CLS_INCR) for k in op.keys]
+        return [(kh, CLS_INCR) for kh in khs]
     if t == OpType.SADD:
-        return [(keyhash(k), CLS_SADD) for k in op.keys]
+        return [(kh, CLS_SADD) for kh in khs]
     if t == OpType.APPEND:
-        return [(keyhash(k), CLS_APPEND) for k in op.keys]
+        return [(kh, CLS_APPEND) for kh in khs]
     if t == OpType.MAX:
-        return [(keyhash(k), CLS_MAX) for k in op.keys]
+        return [(kh, CLS_MAX) for kh in khs]
     if t == OpType.MSET:
-        return [(keyhash(k), CLS_SET) for k in op.keys]
+        return [(kh, CLS_SET) for kh in khs]
     if t == OpType.HMSET:
         k = op.keys[0]
         fields = op.args[0] if op.args else ()
-        pairs = [(keyhash(k), CLS_HMSET)]
+        pairs = [(khs[0], CLS_HMSET)]
         pairs.extend(
             (keyhash(field_subkey(k, f)), CLS_FIELD) for f, _v in fields
         )
@@ -133,12 +136,13 @@ def op_hash_classes(op) -> List[Tuple[int, int]]:
     if t in (OpType.TXN, OpType.TXN_PREPARE, OpType.TXN_COMMIT,
              OpType.TXN_ABORT) and len(op.args) > 1 \
             and op.args[1] is not None:
+        # A procedure leg's keys are its declared keys, in order.
         decl = op.args[0].part_on(op.args[1]).decl
         if decl:
-            return [(keyhash(k), c) for k, c in decl]
+            return [(kh, c) for kh, (_k, c) in zip(khs, decl)]
     # Reads, NOOP, other TXN legs, migration ops: conservative — OTHER
     # conflicts with every class, reproducing the un-widened CURP check.
-    return [(keyhash(k), CLS_OTHER) for k in op.keys]
+    return [(kh, CLS_OTHER) for kh in khs]
 
 
 def key_classes(op) -> Tuple[int, ...]:

@@ -223,7 +223,7 @@ class SlotMigration:
             if op.op_type in (OpType.MIGRATE_IN, OpType.MIGRATE_OUT):
                 continue
             if not op.keys or not all(
-                router.slot_of(k) in slot_set for k in op.keys
+                s in slot_set for s in router.slots_of_op(op)
             ):
                 continue
             rec = donor.master.rifl.check_duplicate(op.rpc_id)

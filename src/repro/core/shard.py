@@ -71,7 +71,7 @@ from .txn import (
     single_shard_op,
 )
 from .types import (ClusterConfig, ExecResult, GcResp, Op, OpType,
-                    RecordStatus, WitnessMode, keyhash)
+                    RecordStatus, WitnessMode, keyhash, share_key_hashes)
 from .witness import Witness
 
 _M32 = 0xFFFFFFFF
@@ -125,6 +125,26 @@ def _tag_hash(tag: str) -> int:
     return keyhash(tag)
 
 
+def _lane(kh64: int) -> int:
+    """The routing lane of a 64-bit route hash: the low keyhash2x32 output
+    lane, which ``n_slots`` reduces to a slot."""
+    return mix2x32((kh64 >> 32) & _M32, kh64 & _M32)[1]
+
+
+def route_lanes(op: Op) -> Tuple[int, ...]:
+    """The routing lane of each of ``op.keys``, computed once in the op's
+    life: from the op's memoized key hash, or from its hash tag's.  A lane
+    depends on the key alone; the slot -> shard step is not part of it."""
+    lanes = op.__dict__.get("_rls")
+    if lanes is None:
+        tags = [hash_tag(k) for k in op.keys]
+        khs = op.key_hashes() if None in tags else ()
+        lanes = tuple(_lane(khs[i] if t is None else _tag_hash(t))
+                      for i, t in enumerate(tags))
+        object.__setattr__(op, "_rls", lanes)
+    return lanes
+
+
 class SlotRouter:
     """Deterministic key -> shard placement shared by Python and Pallas.
 
@@ -138,6 +158,13 @@ class SlotRouter:
     from the same table.  A key with a hash tag (``hash_tag``) hashes only
     its tag; the device placement hashes whole keys, so the fused batch
     path declines tagged keys.
+
+    An op is routed from its keys' lanes (``route_lanes``), which the op
+    computes once from its memoized key hashes; ``slots_of_op`` and
+    ``owned_by`` decide exactly as ``slot_of`` and ``shard_of`` do per key.
+    The slot -> shard step always reads the live ``slot_map``, so a flip
+    (``assign``) changes every later answer at once, for ops built before
+    it too.
     """
 
     def __init__(self, slot_map: Sequence[int],
@@ -159,8 +186,7 @@ class SlotRouter:
 
     # ------------------------------------------------------------ placement
     def slot_of_hash(self, kh64: int) -> int:
-        _, h3 = mix2x32((kh64 >> 32) & _M32, kh64 & _M32)
-        return h3 % self.n_slots
+        return _lane(kh64) % self.n_slots
 
     def slot_of(self, key: Any) -> int:
         tag = hash_tag(key)
@@ -172,6 +198,17 @@ class SlotRouter:
 
     def shard_of(self, key: Any) -> int:
         return self.slot_map[self.slot_of(key)]
+
+    def slots_of_op(self, op: Op) -> Tuple[int, ...]:
+        """``slot_of`` of each of ``op.keys``, from the op's lanes."""
+        n = self.n_slots
+        return tuple(lane % n for lane in route_lanes(op))
+
+    def owned_by(self, op: Op, shard_id: int) -> bool:
+        """Whether ``shard_id`` owns every key of ``op`` under the live
+        map."""
+        m, n = self.slot_map, self.n_slots
+        return all(m[lane % n] == shard_id for lane in route_lanes(op))
 
     def slots_of_shard(self, shard_id: int) -> List[int]:
         return [s for s, owner in enumerate(self.slot_map)
@@ -304,7 +341,7 @@ class ShardGroup:
         # are ignored), and the retired flag a drained-and-removed shard
         # carries.
         self.slot_ops: Dict[int, int] = {}
-        self.owned_filter: Optional[Callable[[Any], bool]] = None
+        self.owned_filter: Optional[Callable[[Op], bool]] = None
         self.retired = False
 
     def _new_witness(self):
@@ -777,34 +814,36 @@ class ShardedClientSession:
         this before re-issuing fresh, so the ack frontier keeps moving."""
         self._ids.abandon(rpc_id)
 
-    # convenience constructors (the route only decides WHERE the op goes;
-    # the identity comes from the shared space)
-    def _sub(self, key) -> ClientSession:
-        return self.session_for(self.router.shard_of(key))
+    # convenience constructors: the identity comes from the shared space,
+    # and routing the new op fills its key-hash and routing-lane memo, which
+    # the cluster's routing, ownership and witness steps then read
+    def _routed(self, op: Op) -> Op:
+        route_lanes(op)
+        return op
 
     def op_set(self, key, value) -> Op:
-        return self._sub(key).op_set(key, value)
+        return self._routed(self._ids.op_set(key, value))
 
     def op_get(self, key) -> Op:
-        return self._sub(key).op_get(key)
+        return self._routed(self._ids.op_get(key))
 
     def op_incr(self, key, delta: int = 1) -> Op:
-        return self._sub(key).op_incr(key, delta)
+        return self._routed(self._ids.op_incr(key, delta))
 
     def op_hmset(self, key, fields) -> Op:
-        return self._sub(key).op_hmset(key, fields)
+        return self._routed(self._ids.op_hmset(key, fields))
 
     def op_del(self, key) -> Op:
-        return self._sub(key).op_del(key)
+        return self._routed(self._ids.op_del(key))
 
     def op_sadd(self, key, member) -> Op:
-        return self._sub(key).op_sadd(key, member)
+        return self._routed(self._ids.op_sadd(key, member))
 
     def op_append(self, key, chunk) -> Op:
-        return self._sub(key).op_append(key, chunk)
+        return self._routed(self._ids.op_append(key, chunk))
 
     def op_max(self, key, n) -> Op:
-        return self._sub(key).op_max(key, n)
+        return self._routed(self._ids.op_max(key, n))
 
     def mset_parts(self, kvs,
                    prev: Optional[Dict[int, Op]] = None) -> Dict[int, Op]:
@@ -829,7 +868,8 @@ class ShardedClientSession:
                    for k, v in zip(sub.keys, sub.args)}
             assert want == got, "mset retry must carry the same kvs"
             for sub in prev.values():
-                owners = {self.router.shard_of(k) for k in sub.keys}
+                owners = {self.router.slot_map[s]
+                          for s in self.router.slots_of_op(sub)}
                 if len(owners) != 1:
                     raise ValueError(
                         "mset retry invalidated by a live migration: leg "
@@ -875,8 +915,8 @@ class ShardedClientSession:
         decide_rpc), whatever identities the parts carried."""
         self._txn_seq += 1
         return TxnSpec(txn_id=(self.client_id, self._txn_seq), parts=tuple(
-            replace(p, prepare_rpc=self._ids.next_rpc_id(),
-                    decide_rpc=self._ids.next_rpc_id())
+            share_key_hashes(replace(p, prepare_rpc=self._ids.next_rpc_id(),
+                                     decide_rpc=self._ids.next_rpc_id()), p)
             for p in parts))
 
     def op_txn(self, spec: TxnSpec) -> Op:
@@ -1004,8 +1044,8 @@ class ShardedCluster:
         for g in self.shards:
             if g.retired:
                 continue
-            flt = (lambda key, sid=g.shard_id:
-                   self.router.shard_of(key) == sid)
+            flt = (lambda op, sid=g.shard_id:
+                   self.router.owned_by(op, sid))
             g.owned_filter = flt
             g.master.owned_partition = flt
 
@@ -1022,7 +1062,7 @@ class ShardedCluster:
     def _group_for(self, op: Op) -> ShardGroup:
         """Route an op: redirect if any touched slot is mid-handover, feed
         the per-slot load counters, and require a single owning shard."""
-        slots = {self.router.slot_of(k) for k in op.keys}
+        slots = set(self.router.slots_of_op(op))
         self.migration.check_slots(slots)
         sids = {self.router.slot_map[s] for s in slots}
         if len(sids) != 1:
@@ -1354,8 +1394,8 @@ class ShardedCluster:
         # frontier keeps moving; replayed ``parts`` identities are live and
         # stay reserved.
         try:
-            self.migration.check_keys(k for sub in parts.values()
-                                      for k in sub.keys)
+            self.migration.check_slots(s for sub in parts.values()
+                                       for s in self.router.slots_of_op(sub))
         except SlotMoving:
             if fresh:
                 for sub in parts.values():
@@ -1376,7 +1416,8 @@ class ShardedCluster:
         # necessarily today's shard; see mset_parts).
         owners: Dict[int, ShardGroup] = {}
         for leg_id, sub_op in parts.items():
-            sids = {self.router.shard_of(k) for k in sub_op.keys}
+            sids = {self.router.slot_map[s]
+                    for s in self.router.slots_of_op(sub_op)}
             assert len(sids) == 1, "validated in mset_parts"
             owners[leg_id] = self.shards[sids.pop()]
         # Round 1 (parallel in a real deployment): speculative execute + record
@@ -1385,8 +1426,7 @@ class ShardedCluster:
         decisions: Dict[int, Decision] = {}
         for leg_id, sub_op in parts.items():
             group = owners[leg_id]
-            for k in sub_op.keys:
-                s = self.router.slot_of(k)
+            for s in self.router.slots_of_op(sub_op):
                 group.slot_ops[s] = group.slot_ops.get(s, 0) + 1
             attempt = group.attempt_update(sub_op, session.acks(), now)
             attempts[leg_id] = attempt
@@ -1575,7 +1615,7 @@ class ShardedCluster:
         for dst, slots in sorted(by_dst.items()):
             reports.extend(self.migrate_slots(slots, dst))
         victim.retired = True
-        victim.owned_filter = lambda key: False
+        victim.owned_filter = lambda op: False
         victim.master.owned_partition = victim.owned_filter
         self.n_shards -= 1
         return reports

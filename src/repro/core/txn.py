@@ -61,7 +61,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .telemetry import get_registry
-from .types import Op, OpType, RpcId
+from .types import Op, OpType, RpcId, share_key_hashes
 
 
 class TxnStatus(enum.Enum):
@@ -185,9 +185,19 @@ class TxnSpec:
 # ---------------------------------------------------------------------------
 # Leg op constructors (the only places TXN_* ops are built)
 # ---------------------------------------------------------------------------
+def _leg(part: TxnPart, op: Op) -> Op:
+    """``op``, a leg over ``part``'s keys, sharing one key-hash memo with
+    the part: every leg of a part, and of its reissues under fresh
+    identities, hashes the part's keys once."""
+    share_key_hashes(op, part)
+    op.key_hashes()
+    share_key_hashes(part, op)
+    return op
+
+
 def prepare_op(spec: TxnSpec, part: TxnPart) -> Op:
-    return Op(OpType.TXN_PREPARE, part.keys, (spec, part.shard_id),
-              part.prepare_rpc)
+    return _leg(part, Op(OpType.TXN_PREPARE, part.keys, (spec, part.shard_id),
+                         part.prepare_rpc))
 
 
 def commit_op(spec: TxnSpec, part: TxnPart, forwarded: Tuple = ()) -> Op:
@@ -195,12 +205,13 @@ def commit_op(spec: TxnSpec, part: TxnPart, forwarded: Tuple = ()) -> Op:
     needs from other legs."""
     args = (spec, part.shard_id, forwarded) if part.proc is not None \
         else (spec, part.shard_id)
-    return Op(OpType.TXN_COMMIT, part.keys, args, part.decide_rpc)
+    return _leg(part, Op(OpType.TXN_COMMIT, part.keys, args,
+                         part.decide_rpc))
 
 
 def abort_op(spec: TxnSpec, part: TxnPart) -> Op:
-    return Op(OpType.TXN_ABORT, part.keys, (spec, part.shard_id),
-              part.decide_rpc)
+    return _leg(part, Op(OpType.TXN_ABORT, part.keys, (spec, part.shard_id),
+                         part.decide_rpc))
 
 
 def single_shard_op(spec: TxnSpec) -> Op:
@@ -208,7 +219,8 @@ def single_shard_op(spec: TxnSpec) -> Op:
     its only shard, under the prepare identity (a retry that got promoted to
     2PC, or vice versa, can never double-apply)."""
     (part,) = spec.parts
-    return Op(OpType.TXN, part.keys, (spec, part.shard_id), part.prepare_rpc)
+    return _leg(part, Op(OpType.TXN, part.keys, (spec, part.shard_id),
+                         part.prepare_rpc))
 
 
 @dataclass

@@ -14,6 +14,8 @@ import enum
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence, Tuple
 
+from . import telemetry
+
 MASK64 = (1 << 64) - 1
 
 # RPC identity per RIFL: (client_id, per-client monotonically increasing seq).
@@ -29,10 +31,15 @@ def splitmix64(x: int) -> int:
     return (z ^ (z >> 31)) & MASK64
 
 
+# FNV-1a passes over a key's bytes; an op's keys take one each in its life.
+_HASHED = telemetry.registry().counter("keys.hashed")
+
+
 def keyhash(key: Any) -> int:
     """64-bit primary-key hash used for all commutativity checks."""
     if isinstance(key, int):
         return splitmix64(key)
+    _HASHED.inc()
     if isinstance(key, str):
         key = key.encode()
     h = 0xCBF29CE484222325  # FNV-1a over the bytes, then splitmix finish.
@@ -97,8 +104,12 @@ class Op:
         return self.op_type in UPDATE_OPS
 
     def key_hashes(self) -> Tuple[int, ...]:
-        # Memoized: the hot paths (witness records, window checks, gc entry
-        # building) re-ask several times per op; keys are frozen.
+        """The 64-bit ``keyhash`` of each of ``keys``, computed once in the
+        op's life (keys are frozen) and read by every later consumer: the
+        pairs of ``hash_classes``, the routing lanes
+        (``repro.core.shard.route_lanes``), witness records, gc entries and
+        migrated RIFL records.  A transaction leg takes its part's memo
+        (``share_key_hashes``)."""
         khs = self.__dict__.get("_khs")
         if khs is None:
             khs = tuple(keyhash(k) for k in self.keys)
@@ -110,8 +121,9 @@ class Op:
 
         This is the commutativity identity of the op: what witnesses record,
         masters refcount in the unsynced window, and gc entries enumerate.
-        ``key_hashes()`` stays the ROUTING identity (one hash per key);
-        HMSET's derived per-field FIELD pairs appear only here."""
+        Its pairs for ``keys`` are built from ``key_hashes()``, so the key
+        hashes are computed once for both memos; only the keys this op
+        derives (HMSET's per-field FIELD sub-keys) are hashed here."""
         hcs = self.__dict__.get("_hcs")
         if hcs is None:
             from .merge import op_hash_classes   # lazy: merge imports types
@@ -119,6 +131,16 @@ class Op:
             hcs = tuple(op_hash_classes(self))
             object.__setattr__(self, "_hcs", hcs)
         return hcs
+
+
+def share_key_hashes(dst: Any, src: Any) -> Any:
+    """Give ``dst`` the key hashes ``src`` has memoized, if it has: both are
+    ops or transaction parts (``repro.core.txn.TxnPart``) over the same keys
+    in the same order.  Returns ``dst``."""
+    khs = src.__dict__.get("_khs")
+    if khs is not None:
+        object.__setattr__(dst, "_khs", khs)
+    return dst
 
 
 class RecordStatus(enum.Enum):

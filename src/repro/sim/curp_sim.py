@@ -850,7 +850,7 @@ class SimCluster:
         self.clients: List[SimClient] = []
         self.completions: List[float] = []
         self.recovery_report: Optional[dict] = None
-        # Optional key-ownership filter installed on every master this
+        # Optional ownership filter installed on every master this
         # cluster creates (incl. post-recovery ones); the sharded wrapper
         # uses it for timed slot migration (NOT_OWNER on frozen slots).
         self.owned_filter = None
@@ -876,8 +876,9 @@ class SimCluster:
         self.completions.append(t)
 
     def set_owned_filter(self, fn) -> None:
-        """Install a key-ownership predicate on the current AND every future
-        master core (timed migration: frozen/moved slots draw NOT_OWNER)."""
+        """Install an ownership predicate (op -> whether the master owns
+        every key of it) on the current AND every future master core (timed
+        migration: frozen/moved slots draw NOT_OWNER)."""
         self.owned_filter = fn
         self.master_node.core.owned_partition = fn
 
@@ -1128,10 +1129,10 @@ class ShardedSimCluster:
                 s.set_owned_filter(self._make_owned_filter(i))
 
     def _make_owned_filter(self, shard_id: int):
-        def owns(key) -> bool:
-            slot = self.router.slot_of(key)
-            return self.router.slot_map[slot] == shard_id \
-                and slot not in self._frozen
+        def owns(op: Op) -> bool:
+            return all(self.router.slot_map[slot] == shard_id
+                       and slot not in self._frozen
+                       for slot in self.router.slots_of_op(op))
         return owns
 
     # -- timed slot migration (freeze -> transfer -> flip) ---------------------
@@ -1185,7 +1186,7 @@ class ShardedSimCluster:
                 if op.op_type in (OpType.MIGRATE_IN, OpType.MIGRATE_OUT):
                     continue
                 if not op.keys or not all(
-                        self.router.slot_of(k) == slot for k in op.keys):
+                        s == slot for s in self.router.slots_of_op(op)):
                     continue
                 rec = d_core.rifl.check_duplicate(op.rpc_id)
                 if rec is None:
@@ -1233,7 +1234,7 @@ class ShardedSimCluster:
         self.sim.after(transfer_us, transfer)
 
     def route(self, op: Op) -> SimCluster:
-        sids = {self.router.shard_of(k) for k in op.keys}
+        sids = {self.router.slot_map[s] for s in self.router.slots_of_op(op)}
         if len(sids) != 1:
             # Mirror ShardedCluster._group_for: the sim models per-shard
             # placement, so a cross-shard op must fail loudly, not land
@@ -1590,7 +1591,7 @@ class OpenLoopDriver(Node):
     def _shard_of(self, op: Op) -> int:
         if self._router is None:
             return 0
-        return self._slot_map[self._router.slot_of(op.keys[0])]
+        return self._slot_map[self._router.slots_of_op(op)[0]]
 
     def _target(self, shard_idx: int):
         shards = getattr(self.cluster, "shards", None)

@@ -66,7 +66,8 @@ def test_cluster_migration_filtering():
     c.update(cl, cl.op_set("mine", 1))
     # master gives up ownership of keys starting with "theirs"
     c.sync_now()
-    c.master.owned_partition = lambda k: not str(k).startswith("theirs")
+    c.master.owned_partition = lambda op: not any(
+        str(k).startswith("theirs") for k in op.keys)
     op = cl.op_set("theirs:x", 5)
     verdict, res = c.master.handle_update(
         op, c.config.fetch(0).witness_list_version, (), 0.0
